@@ -27,11 +27,3 @@ echo "== tests =="
 # ring stats) as a build artifact next to the graftlint report
 RAFT_TPU_METRICS_SNAPSHOT="$PWD/ci/metrics_snapshot.json" \
     python -m pytest tests/ -q "$@"
-
-echo "== bench regression gate =="
-# graftscope v2: replay the pinned small-config bench and diff it (plus
-# the metrics snapshot's modeled-throughput columns) against the
-# committed baseline with tolerance bands — exits nonzero on a
-# throughput/latency/recompile regression. Re-baseline deliberately:
-#   python ci/bench_compare.py --run --update
-python ci/bench_compare.py --run --snapshot ci/metrics_snapshot.json

@@ -13,7 +13,8 @@ import pathlib
 import re
 
 MODULES = [
-    "raft_tpu.core.resources", "raft_tpu.core.executor",
+    "raft_tpu.core.resources", "raft_tpu.core.chips",
+    "raft_tpu.core.executor",
     "raft_tpu.core.bitset", "raft_tpu.core.logger",
     "raft_tpu.core.tracing", "raft_tpu.core.interruptible",
     "raft_tpu.core.serialize", "raft_tpu.core.operators",

@@ -1,13 +1,11 @@
 """R3 — collective discipline.
 
 Every mesh program in this repo goes through the
-``raft_tpu.comms.comms`` veneer: it is where the jax 0.4.x/0.5.x/0.6+
-compat shims live (``shard_map`` check_vma/check_rep, ``axis_size``,
-``mark_varying``), where wire-dtype policy is applied, and where the
-collective-payload accounting hooks. A raw ``jax.lax`` collective (or a
-direct ``jax.experimental.shard_map`` import) outside the veneer
-bypasses all three — it works on the jax version it was written
-against and silently breaks on the next one.
+``raft_tpu.comms.comms`` veneer: it is the one spelling of
+``shard_map``, ``axis_size`` and ``mark_varying``, where wire-dtype
+policy is applied, and where the collective-payload accounting hooks.
+A raw ``jax.lax`` collective (or a direct ``shard_map`` reference)
+outside the veneer bypasses the wire policy and the accounting.
 
 Checks:
 
@@ -132,8 +130,8 @@ def check_collectives(project: Project) -> Iterable[Finding]:
                     out.append(Finding(
                         "R3", f.rel, node.lineno,
                         f"raw {nm} outside the comms veneer — route it "
-                        "through raft_tpu.comms.comms so the version "
-                        "shims and payload accounting apply"))
+                        "through raft_tpu.comms.comms so the wire "
+                        "policy and payload accounting apply"))
             if isinstance(node, ast.Call):
                 nm = astutil.call_name(node) or ""
                 if nm == "getattr" and len(node.args) >= 2 \
@@ -144,17 +142,15 @@ def check_collectives(project: Project) -> Iterable[Finding]:
                     out.append(Finding(
                         "R3", f.rel, node.lineno,
                         f"getattr(jax.lax, {node.args[1].value!r}) "
-                        "feature probe outside the comms veneer — the "
-                        "compat shim for this collective belongs in "
-                        "raft_tpu.comms.comms"))
+                        "feature probe outside the comms veneer — "
+                        "collectives belong in raft_tpu.comms.comms"))
             # direct shard_map access
             if isinstance(node, ast.ImportFrom) and node.module \
                     and "shard_map" in node.module:
                 out.append(Finding(
                     "R3", f.rel, node.lineno,
                     "direct jax.experimental.shard_map import — use "
-                    "raft_tpu.comms.comms.shard_map (check_vma/"
-                    "check_rep compat)"))
+                    "raft_tpu.comms.comms.shard_map"))
             if isinstance(node, ast.Attribute) \
                     and astutil.dotted(node) == "jax.shard_map":
                 out.append(Finding(

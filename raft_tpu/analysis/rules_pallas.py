@@ -5,10 +5,8 @@ linting share one traversal.
 R4 enforces what ``resolve_scan_engine`` assumes when it promises a
 kernel will compile:
 
-- every ``pallas_call`` must set ``compiler_params`` via the
-  ``_COMPILER_PARAMS`` compat alias (the pltpu.CompilerParams ↔
-  TPUCompilerParams rename shim) with an explicit
-  ``vmem_limit_bytes`` — an unbounded kernel is sized by Mosaic's
+- every ``pallas_call`` must set ``compiler_params`` as
+  ``pltpu.CompilerParams`` with an explicit ``vmem_limit_bytes`` — an unbounded kernel is sized by Mosaic's
   default and dies on the first big shape;
 - when every BlockSpec / scratch shape folds to constants, the summed
   VMEM footprint (double-buffered blocks + scratch) must fit the
@@ -164,23 +162,17 @@ def check_pallas_budget(project: Project) -> Iterable[Finding]:
                 out.append(Finding(
                     "R4", f.rel, call.lineno,
                     "pallas_call without compiler_params — pass "
-                    "_COMPILER_PARAMS(vmem_limit_bytes=...) so the "
-                    "kernel states its VMEM budget"))
+                    "pltpu.CompilerParams(vmem_limit_bytes=...) so "
+                    "the kernel states its VMEM budget"))
             else:
                 cp_name = (astutil.call_name(cp) or "") if isinstance(
                     cp, ast.Call) else ""
                 leaf = cp_name.split(".")[-1]
-                if leaf in ("CompilerParams", "TPUCompilerParams"):
+                if leaf != "CompilerParams":
                     out.append(Finding(
                         "R4", f.rel, cp.lineno,
-                        f"direct pltpu.{leaf} — use the "
-                        "_COMPILER_PARAMS compat alias (the jax 0.5 "
-                        "rename shim in ops.fused_topk)"))
-                elif leaf != "_COMPILER_PARAMS":
-                    out.append(Finding(
-                        "R4", f.rel, cp.lineno,
-                        "compiler_params is not built via the "
-                        "_COMPILER_PARAMS compat alias"))
+                        "compiler_params is not built via "
+                        "pltpu.CompilerParams"))
                 if isinstance(cp, ast.Call):
                     vl = _kw(cp, "vmem_limit_bytes")
                     if vl is None:

@@ -19,6 +19,7 @@ import threading
 import numpy as np
 
 from raft_tpu.distance.types import DistanceType
+from raft_tpu.io.binfile import native_stale
 
 _REPO_ROOT = pathlib.Path(__file__).resolve().parent.parent.parent
 _NATIVE_DIR = _REPO_ROOT / "native"
@@ -36,24 +37,14 @@ _METRIC_CODES = {
 }
 
 
-def _so_stale() -> bool:
-    """Missing, or older than the sources that produce it — a stale
-    library lacks newer symbols (hnsw_dim/…). Decided by mtime BEFORE
-    dlopen: rebuilding after a dlopen would truncate a mapped file."""
-    if not _SO_PATH.exists():
-        return True
-    so_m = _SO_PATH.stat().st_mtime
-    return any(src.exists() and src.stat().st_mtime > so_m
-               for src in (_NATIVE_DIR / "hnsw.cpp",
-                           _NATIVE_DIR / "Makefile"))
-
-
 def _load():
     global _lib, _build_attempted
     with _lib_lock:
         if _lib is not None:
             return _lib
-        if _so_stale() and not _build_attempted:
+        if (native_stale(_SO_PATH, _NATIVE_DIR / "hnsw.cpp",
+                         _NATIVE_DIR / "Makefile")
+                and not _build_attempted):
             _build_attempted = True
             try:
                 subprocess.run(["make", "-s"], cwd=_NATIVE_DIR, check=True,

@@ -102,28 +102,16 @@ class Op(enum.Enum):
 
 
 def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-portable ``shard_map`` — THE spelling every mesh program
-    in this repo goes through: jax >= 0.6 exposes ``jax.shard_map``
-    (validity-checking flag named ``check_vma``); 0.4.x/0.5.x ship it
-    as ``jax.experimental.shard_map.shard_map`` (``check_rep``). The
-    compat alias plays the same role as ``ops.fused_topk``'s
-    ``_COMPILER_PARAMS`` rename shim does for Pallas."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
+    """``jax.shard_map`` — THE spelling every mesh program in this repo
+    goes through, so the collective accounting and the R3 lint rule
+    have one place to look."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def axis_size(axis: str) -> int:
-    """Static mesh-axis size inside a mapped program. jax >= 0.6 has
-    ``jax.lax.axis_size``; earlier versions statically fold
-    ``psum(1, axis)`` — the classic idiom."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis)
-    return jax.lax.psum(1, axis)
+    """Static mesh-axis size inside a mapped program."""
+    return jax.lax.axis_size(axis)
 
 
 # ---------------------------------------------------------------------------
@@ -497,20 +485,8 @@ def device_sendrecv(x, perm: Sequence[tuple], axis: str = "data"):
 
 
 def mark_varying(x, axis: str = "data"):
-    """Mark a value device-varying for shard_map's validity check —
-    the version shim for the pvary → pcast migration: jax 0.7+ spells
-    it ``pcast(..., to="varying")``, 0.6 has ``pvary``, and 0.4.x/0.5.x
-    have neither and need no marking (their shard_map runs these
-    programs with ``check_rep=False``). Like :func:`shard_map` and
-    :func:`axis_size`, this is THE spelling mesh programs use — a raw
-    feature probe at a call site would re-fork on every jax bump."""
-    pcast = getattr(jax.lax, "pcast", None)
-    if pcast is not None:
-        return pcast(x, axis, to="varying")
-    pvary = getattr(jax.lax, "pvary", None)
-    if pvary is not None:
-        return pvary(x, axis)
-    return x
+    """Mark a value device-varying for shard_map's validity check."""
+    return jax.lax.pcast(x, axis, to="varying")
 
 
 def barrier(axis: str = "data"):

@@ -17,9 +17,9 @@ peak-throughput recipe already cited in ``matrix/select_k.py``:
   and cached; the steady-state hot path calls the compiled executable
   directly — no tracing, no dispatch-cache lookup, no recompiles.
   :meth:`warmup` builds the executables from abstract shapes before
-  traffic arrives, and a persistent compilation cache directory
-  (``Resources.compilation_cache_dir``) makes that warmup survive
-  process restarts.
+  traffic arrives, and the persistent compilation cache
+  (:func:`~raft_tpu.core.resources.init_compile_cache`) makes that
+  warmup survive process restarts.
 - **Donated top-k state**: the running (k-best values, ids) buffers are
   owned by the executor and donated to each call, so the scan state
   reuses one HBM allocation across calls instead of re-allocating (and
@@ -291,6 +291,14 @@ def _module_name(compiled, fallback: str) -> str:
     return f"jit_{fallback}"
 
 
+def _plan_engine(plan: "_Plan") -> str:
+    """The engine a plan resolved to — ``"pallas"`` when a Pallas
+    kernel serves it."""
+    if plan.key[0] == "bf_fused":
+        return "pallas"
+    return plan.static.get("scan_engine", plan.static.get("engine", "xla"))
+
+
 def _sds(x) -> Optional[jax.ShapeDtypeStruct]:
     # None passes through: optional plan operands (e.g. the BQ
     # rerank plane of a codes-only index) are empty pytree args
@@ -355,8 +363,7 @@ class SearchExecutor:
         d, i = ex.search(index, queries, 10)        # never traces again
 
     Constructor args:
-      res: shared :class:`Resources` (placement, workspace budget, and
-        the persistent ``compilation_cache_dir``).
+      res: shared :class:`Resources` (placement, workspace budget).
       min_bucket/max_bucket: power-of-two bucket ladder bounds. Batches
         larger than ``max_bucket`` are tiled at ``max_bucket`` with the
         ragged tail padded into the bucket (all tiles dispatched before
@@ -1379,7 +1386,8 @@ class SearchExecutor:
         cost["hlo_module"] = _module_name(
             compiled, f"rt_{plan.key[0]}_{digest}")
         info = {"family": plan.key[0], "bucket": bucket, "k": k,
-                "compile_seconds": dt, **cost}
+                "engine": _plan_engine(plan), "compile_seconds": dt,
+                **cost}
         payload_model = None
         if plan.payload is not None:
             family, model_fn = plan.payload
@@ -1406,12 +1414,23 @@ class SearchExecutor:
         return ent
 
     def executable_costs(self) -> dict:
-        """``{digest: {family, bucket, k, flops, bytes_accessed,
-        peak_hbm_bytes, ...}}`` for every cached executable — the JSON
-        view of the ``serving.executable.*`` gauges (one scrape shows
-        which programs are resident and what each costs per call)."""
+        """``{digest: {family, bucket, k, engine, flops,
+        bytes_accessed, peak_hbm_bytes, ...}}`` for every cached
+        executable — the JSON view of the ``serving.executable.*``
+        gauges (one scrape shows which programs are resident, which
+        engine each resolved to, and what each costs per call)."""
         with self._lock:
             return {d: dict(info) for d, info in self._cost_table.items()}
+
+    def executable_text(self, digest: str) -> str:
+        """Optimized HLO text of the cached executable ``digest`` (as
+        keyed in :meth:`executable_costs`) — e.g. to check that a
+        kernel (``tpu_custom_call``) is in the program."""
+        with self._lock:
+            for ent in self._cache.values():
+                if ent.digest == digest:
+                    return ent.compiled.as_text()
+        raise KeyError(f"no cached executable {digest!r}")
 
     def attach_memwatch(self, ledger) -> None:
         """Wire a graftledger :class:`~raft_tpu.core.memwatch
@@ -1918,6 +1937,7 @@ class SearchExecutor:
         hot_data, cold_data, hot_map, cold_map = index.tier_arrays()
         engine = resolve_tier_engine(params.scan_engine,
                                      hot_data=hot_data,
+                                     cold_data=cold_data,
                                      filter_words=fw, k=k)
         static = {"n_probes": n_probes, "k": k, "metric": index.metric,
                   "coarse_algo": params.coarse_algo,

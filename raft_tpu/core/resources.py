@@ -35,31 +35,29 @@ def _default_device() -> jax.Device:
     return jax.devices()[0]
 
 
-def apply_compilation_cache(path: str) -> None:
-    """Point XLA's persistent compilation cache at ``path`` (created if
-    missing) and drop the min-compile-time threshold so every serving
-    executable is persisted.
+# the one in-checkout cache location (gitignored): a fixed path, since
+# the path is part of the cache key — a moving directory never hits
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
-    This is the process-restart half of the serving path's cold-start
-    story: ``SearchExecutor.warmup`` pays tracing + XLA compile once,
-    the artifacts land in ``path``, and the next process's warmup is a
-    cache *load* instead of a compile. Safe to call repeatedly."""
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
-    try:
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    except AttributeError:  # renamed across jax versions; dir alone suffices
-        pass
-    # jax memoizes "no cache configured" at the first compile; if any
-    # compile already ran (e.g. another handle's PRNG init), the new
-    # dir would be silently ignored without this reset
-    try:
-        from jax._src import compilation_cache
 
-        if compilation_cache._cache_initialized:  # noqa: SLF001
-            compilation_cache.reset_cache()
-    except Exception:  # pragma: no cover - private API moved
-        pass
+def init_compile_cache() -> str:
+    """Turn on XLA's persistent compilation cache for this process and
+    return its directory — the ONE helper every entry point calls
+    before its first compile, so a restarted process's warmup loads
+    executables instead of compiling them.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is the cache (jax reads
+    it itself; nothing here overrides it). Otherwise the cache lives
+    at :data:`COMPILE_CACHE_DIR` inside the checkout. Every executable
+    is persisted, however quick its compile."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = COMPILE_CACHE_DIR
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path
 
 
 @dataclasses.dataclass
@@ -83,10 +81,9 @@ class Resources:
       workspace_limit_bytes: soft budget that batching heuristics use when
         deciding tile sizes (analog of the workspace memory resource,
         ``core/device_resources.hpp`` workspace accessors).
-      compilation_cache_dir: when set, XLA's persistent compilation
-        cache is pointed here (see :func:`apply_compilation_cache`) so
-        AOT warmup done by ``SearchExecutor`` survives process
-        restarts. Defaults to the ``RAFT_TPU_COMPILE_CACHE`` env var.
+
+    The persistent compilation cache is process-wide, not per handle:
+    entry points turn it on with :func:`init_compile_cache`.
     """
 
     device: Optional[jax.Device] = None
@@ -95,17 +92,9 @@ class Resources:
     matmul_precision: str = "highest"
     workspace_limit_bytes: int = 2 * 1024**3
     comms: Optional[Any] = None
-    compilation_cache_dir: Optional[str] = None
 
     def __post_init__(self):
         self._lock = threading.Lock()
-        if self.compilation_cache_dir is None:
-            self.compilation_cache_dir = (
-                os.environ.get("RAFT_TPU_COMPILE_CACHE") or None)
-        if self.compilation_cache_dir:
-            # before the PRNG-key compile below, so even the process's
-            # very first executable lands in the persistent cache
-            apply_compilation_cache(self.compilation_cache_dir)
         self._key = jax.random.key(self.seed)
         self._subcomms: dict[str, Any] = {}
 
@@ -201,9 +190,6 @@ class ResourcesManager:
 
     def set_workspace_limit_bytes(self, n: int) -> None:
         self._defaults["workspace_limit_bytes"] = n
-
-    def set_compilation_cache_dir(self, path: str) -> None:
-        self._defaults["compilation_cache_dir"] = path
 
     def get_device_resources(
         self, device: "Optional[jax.Device | int]" = None

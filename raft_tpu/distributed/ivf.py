@@ -530,28 +530,36 @@ def build(
 
     with tracing.range("raft_tpu.distributed.ivf_flat.build"):
         # single-chip build (global quantizer + packed lists), then deal
-        index = ivf_flat_mod.build(res, params, dataset)
+        return shard_index(comms, ivf_flat_mod.build(res, params, dataset))
 
-        # blocked layout wants shard-contiguous rows: stream the deal
-        # per shard block per the shared layout policy
-        sizes = np.asarray(jax.device_get(index.list_sizes))
-        perm = deal_order(sizes, r)
-        admit_deal((index.centers, index.data, index.data_norms,
-                    index.indices, index.list_sizes), r,
-                   "distributed.ivf_flat.build.deal")
 
-        def place(a):
-            return place_dealt(a, perm, comms)
+def shard_index(comms: Comms, index) -> DistributedIvfFlat:
+    """Deal a built single-chip :class:`~raft_tpu.neighbors.ivf_flat
+    .IvfFlatIndex` over ``comms``' mesh axis — the placement half of
+    :func:`build`. Its ``n_lists`` must divide by the axis size. The
+    blocked layout wants shard-contiguous rows: the deal streams per
+    shard block per the shared layout policy."""
+    expect(index.n_lists % comms.size == 0,
+           f"n_lists {index.n_lists} must divide by the mesh axis size "
+           f"{comms.size}")
+    sizes = np.asarray(jax.device_get(index.list_sizes))
+    perm = deal_order(sizes, comms.size)
+    admit_deal((index.centers, index.data, index.data_norms,
+                index.indices, index.list_sizes), comms.size,
+               "distributed.ivf_flat.build.deal")
 
-        return DistributedIvfFlat(
-            comms=comms,
-            centers=place(index.centers),
-            data=place(index.data),
-            data_norms=place(index.data_norms),
-            indices=place(index.indices),
-            list_sizes=place(index.list_sizes),
-            metric=index.metric,
-        )
+    def place(a):
+        return place_dealt(a, perm, comms)
+
+    return DistributedIvfFlat(
+        comms=comms,
+        centers=place(index.centers),
+        data=place(index.data),
+        data_norms=place(index.data_norms),
+        indices=place(index.indices),
+        list_sizes=place(index.list_sizes),
+        metric=index.metric,
+    )
 
 
 def _dist_search_fn(queries, centers, data, data_norms, indices,

@@ -44,18 +44,33 @@ def _dtype_for(path: str):
     return np.dtype(_SUFFIX_DTYPES[suffix])
 
 
+def native_stale(so_path: pathlib.Path, *sources: pathlib.Path) -> bool:
+    """Missing, or older than a source that produces it (the ``.so``
+    is gitignored, so a checkout may carry a stale one). Decided by
+    mtime BEFORE dlopen: rebuilding after a dlopen would truncate a
+    mapped file."""
+    if not so_path.exists():
+        return True
+    so_m = so_path.stat().st_mtime
+    return any(src.exists() and src.stat().st_mtime > so_m
+               for src in sources)
+
+
 def _load_native():
-    """Load (building if needed) the native IO library; None if impossible."""
+    """Load (rebuilding if missing or stale) the native IO library;
+    None if impossible."""
     global _lib, _build_attempted
     with _lib_lock:
         if _lib is not None:
             return _lib
-        if not _SO_PATH.exists() and not _build_attempted:
+        if (native_stale(_SO_PATH, _NATIVE_DIR / "io.cpp",
+                         _NATIVE_DIR / "Makefile")
+                and not _build_attempted):
             _build_attempted = True
             try:
                 subprocess.run(
-                    ["make", "-s"], cwd=_NATIVE_DIR, check=True,
-                    capture_output=True, timeout=120,
+                    ["make", "-s", _SO_PATH.name], cwd=_NATIVE_DIR,
+                    check=True, capture_output=True, timeout=120,
                 )
             except (OSError, subprocess.SubprocessError):
                 return None

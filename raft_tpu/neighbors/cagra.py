@@ -718,13 +718,13 @@ def _resolve_bq_traversal(params: CagraSearchParams, index: CagraIndex,
                "bq_traversal='on' needs an index built with bq_bits >= 1")
         return False
     if use_kernel:
-        from raft_tpu.ops.fused_topk import _default_vmem_mb
+        from raft_tpu.core.chips import vmem_budget_mb
 
         # same rule the kernel wrapper enforces: the plane is
         # VMEM-resident in both dataset modes and must leave the ~8 MB
         # scratch headroom (the dataset then places around it)
         fits = (4 * index.bq_records.size
-                <= (_default_vmem_mb() - 8) * 1024 * 1024)
+                <= (vmem_budget_mb() - 8) * 1024 * 1024)
         if mode == "on":
             expect(fits, "bq_traversal='on': the BQ record plane "
                    "exceeds the kernel VMEM budget")

@@ -204,6 +204,11 @@ def make_rotation_matrix(key, dim_ext: int, dim: int, force_random: bool):
     return qmat[:dim, :].T                  # (dim_ext, dim), R R^T = I on range
 
 
+# codebook-training rows per codeword (reference
+# ``ivf_pq::index_params::max_train_points_per_pq_code``)
+_MAX_TRAIN_POINTS_PER_PQ_CODE = 256
+
+
 @partial(jax.jit, static_argnames=("n_centers", "n_iters"))
 def _vmapped_lloyd(trainsets, key, n_centers: int, n_iters: int):
     """Fixed-iteration Lloyd EM vmapped over leading axis — trains all
@@ -369,6 +374,14 @@ def build(
         book_size = 1 << params.pq_bits
         key = jax.random.fold_in(jax.random.key(res.seed), 11)
         if params.codebook_kind == CodebookKind.PER_SUBSPACE:
+            # at most _MAX_TRAIN_POINTS_PER_PQ_CODE rows per codeword
+            # (the reference's max_train_points_per_pq_code): the
+            # (pq_dim, rows, pq_len) trainset tiles its short pq_len
+            # axis to 128 lanes on TPU, so the whole 1M-row trainset
+            # would not fit HBM
+            cap = _MAX_TRAIN_POINTS_PER_PQ_CODE * book_size
+            if rot.shape[0] > cap:
+                rot = rot[::rot.shape[0] // cap][:cap]
             sub = jnp.moveaxis(rot.reshape(-1, pq_dim, pq_len), 1, 0)
             codebooks = _vmapped_lloyd(sub, key, book_size, 25)
         else:

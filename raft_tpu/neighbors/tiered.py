@@ -7,9 +7,9 @@ of users, corpus ≫ HBM"). :class:`TieredIvf` splits an
 :class:`~raft_tpu.neighbors.ivf_flat.IvfFlatIndex`'s lists into an
 HBM-resident **hot tier** (fixed slot capacity, sized against
 graftledger's live headroom via :func:`resolve_hot_slots`) and a
-host-memory **cold tier** (committed via :func:`host_put` — honest
-fallback to device placement on backends without memory kinds, i.e.
-the CPU tier-1 environment), and serves the probed-list union in one
+host-memory **cold tier** (committed via :func:`host_put` on a TPU;
+on the CPU tier-1 backend it stays in the one memory pool there is),
+and serves the probed-list union in one
 pass through :mod:`raft_tpu.ops.tier_scan`: hot blocks ride the
 existing scalar-prefetched BlockSpec pipeline, cold blocks stream
 through a double-buffered manual-DMA pipeline from the host operand.
@@ -312,35 +312,21 @@ class TieredIvfBq(_TieredPlanes):
 
 
 def host_put(x) -> Tuple[jax.Array, bool]:
-    """Commit ``x`` to host memory (``pinned_host``) when the backend
-    supports memory kinds; returns ``(array, host_resident)``. The
-    fallback is HONEST: on backends without a host memory space (the
-    CPU tier-1 environment, where host and device memory are the same
-    pool anyway) the array stays on the default device and the flag
-    says so — nothing pretends bytes left HBM that didn't."""
+    """Commit ``x`` to host memory (``pinned_host``) on a TPU; returns
+    ``(array, host_resident)``. Elsewhere the array stays committed to
+    the default device and the flag says so: the CPU backend's host
+    and device memory are one pool, and XLA:CPU implements no moves
+    between memory kinds inside a program, so a host-committed plane
+    could not be read there. Committed placement (explicit sharding):
+    the cold plane presents the same committed-ness from its first
+    epoch that the ``out_shardings``-pinned swap output carries ever
+    after — an uncommitted first generation would re-specialize the
+    swap program once."""
     x = jnp.asarray(x)
-    dev = x.devices().pop() if hasattr(x, "devices") \
-        else jax.devices()[0]
-    try:
-        kinds = tuple(m.kind for m in dev.addressable_memories())
-    except Exception:  # noqa: BLE001 — no memories API at all
-        kinds = ()
-    if "pinned_host" not in kinds:
-        # honest fallback, taken ONLY when the backend exposes no
-        # pinned-host memory space (the CPU tier-1 environment, whose
-        # single memory is already host RAM). COMMITTED placement
-        # (explicit sharding): the cold plane must present the same
-        # committed-ness from its first epoch that the
-        # out_shardings-pinned swap output carries ever after — an
-        # uncommitted first generation would re-specialize the swap
-        # program once, breaking the warm-one-epoch zero-recompile
-        # discipline.
+    dev = x.devices().pop()
+    if dev.platform != "tpu":
         return jax.device_put(
             x, jax.sharding.SingleDeviceSharding(dev)), False
-    # the backend DOES support pinned host memory: a failure here is
-    # a real allocation problem (host RAM pressure, allocator error)
-    # and must stay loud — swallowing it would silently park the
-    # whole cold tier in the HBM it exists to vacate
     sharding = jax.sharding.SingleDeviceSharding(
         dev, memory_kind="pinned_host")
     return jax.device_put(x, sharding), True
@@ -445,7 +431,16 @@ def build_tiered(index: IvfFlatIndex, *, hot_slots=None,
     )
 
 
-_gather_blocks = jax.jit(lambda a, rows: jnp.take(a, rows, axis=0))
+@jax.jit
+def _gather_blocks(plane, rows):
+    """``plane[rows]`` in device memory. Block by block: a
+    host-committed plane may only be sliced, and XLA turns each slice
+    + move into one host-to-device copy of exactly that block."""
+    return jax.lax.map(
+        lambda r: jax.device_put(
+            jax.lax.dynamic_index_in_dim(plane, r, 0, False),
+            jax.memory.Space.Device),
+        rows)
 
 
 def _split_lists(n_lists: int, h: int, probe_counts):
@@ -640,7 +635,7 @@ def _promote_mix_fn(staged_plane, cold_plane, st_rows, cg, hit):
     that also skips the miss rows' neighbors on-chip is the ROADMAP
     follow-on."""
     a = jnp.take(staged_plane, jnp.maximum(st_rows, 0), axis=0)
-    b = jnp.take(cold_plane, cg, axis=0)
+    b = _gather_blocks(cold_plane, cg)
     shape = (hit.shape[0],) + (1,) * (a.ndim - 1)
     return jnp.where(jnp.reshape(hit, shape), a, b)
 

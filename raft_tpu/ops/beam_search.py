@@ -52,12 +52,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from raft_tpu.core.chips import vmem_budget_mb
 from raft_tpu.core.validation import expect
 from raft_tpu.distance.types import DistanceType
 from raft_tpu.ops.bq_scan import _block_estimate, bq_record_geometry
-from raft_tpu.ops.fused_topk import _COMPILER_PARAMS
 from raft_tpu.neighbors._exact import dedup_candidate_mask
-from raft_tpu.ops.fused_topk import _default_vmem_mb, _extract_topk
+from raft_tpu.ops.fused_topk import _extract_topk
 
 _SUPPORTED = (DistanceType.L2Expanded, DistanceType.L2SqrtExpanded,
               DistanceType.InnerProduct)
@@ -72,7 +72,7 @@ def beam_search_fits(n: int, dim: int, itemsize: int,
     charges co-resident planes (the BQ record plane) to the same
     budget."""
     if vmem_mb <= 0:
-        vmem_mb = _default_vmem_mb()
+        vmem_mb = vmem_budget_mb()
     return n * dim * itemsize + extra_bytes <= (vmem_mb - 8) * 1024 * 1024
 
 
@@ -416,7 +416,7 @@ def beam_search(queries, dataset, graph, seeds, k: int, L: int, w: int,
            "beam_search: seeds must be (q, m*w*deg)")
     expect(k <= L, "beam_search: k must be <= itopk L")
     if vmem_mb <= 0:
-        vmem_mb = _default_vmem_mb()
+        vmem_mb = vmem_budget_mb()
 
     use_bq = bq_records is not None
     plane_bytes = 0
@@ -548,7 +548,7 @@ def beam_search(queries, dataset, graph, seeds, k: int, L: int, w: int,
             jax.ShapeDtypeStruct((qp, k), jnp.float32),
             jax.ShapeDtypeStruct((qp, k), jnp.int32),
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=vmem_mb * 1024 * 1024),
         interpret=interpret,

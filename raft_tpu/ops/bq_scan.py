@@ -50,18 +50,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from raft_tpu.core.chips import vmem_budget_mb
 from raft_tpu.core.validation import expect
 from raft_tpu.distance.types import DistanceType
 from raft_tpu.neighbors.ivf_bq import estimator_margin
-from raft_tpu.ops.fused_topk import (
-    _COMPILER_PARAMS,
-    _default_vmem_mb,
-    _extract_topk,
-)
+from raft_tpu.ops.fused_topk import _extract_topk
 from raft_tpu.ops.ivf_scan import (
     _PALLAS_MAX_K,
     SCAN_ENGINES,
     _merge_smallest_id,
+    degrade,
     unique_lists,
 )
 
@@ -109,11 +107,11 @@ def resolve_bq_engine(engine: str, *, data=None, filter_words=None,
     if engine != "pallas":
         return engine
     if filter_words is not None and getattr(filter_words, "ndim", 1) == 2:
-        return "xla"
+        return degrade("bq_scan", "per-query filter words")
     if k is not None and k > _PALLAS_MAX_K:
-        return "xla"
+        return degrade("bq_scan", f"k > {_PALLAS_MAX_K}")
     if data.dtype != jnp.float32:
-        return "xla"
+        return degrade("bq_scan", f"{data.dtype} rerank vectors")
     m_pad = -(-data.shape[1] // 8) * 8
     d_pad = -(-data.shape[2] // 128) * 128
     de_pad = -(-max(dim_ext, 1) // 128) * 128
@@ -123,9 +121,9 @@ def resolve_bq_engine(engine: str, *, data=None, filter_words=None,
         # compiled Mosaic would force a whole-tensor jnp.pad per call —
         # a full HBM read+write dwarfing the scan. Interpret mode (CPU
         # CI) keeps the pad path so any test shape is coverable.
-        return "xla"
+        return degrade("bq_scan", "list layout not tile-aligned")
     if vmem_mb <= 0:
-        vmem_mb = _default_vmem_mb()
+        vmem_mb = vmem_budget_mb()
     # THE kernel's own budget arithmetic (shared helper): the
     # double-buffered code/correction blocks + the raw-vector scratch
     # + margin must leave room for at least one minimal (8-row) query
@@ -137,7 +135,7 @@ def resolve_bq_engine(engine: str, *, data=None, filter_words=None,
         m_pad, d_pad, de_pad, p_pad, bits * max(dim_ext, 32) // 32,
         bits, k or _PALLAS_MAX_K)
     if fixed + 8 * per_q > vmem_mb << 20:
-        return "xla"
+        return degrade("bq_scan", "list block exceeds the VMEM budget")
     return engine
 
 
@@ -521,11 +519,11 @@ def _bq_scan_kernel(u_ref, probes_ref, qrot_ref, qf_ref, crot_ref,
     # estimate the whole tile against the packed sign words —
     # XOR+popcount on int32 lanes, 1/32nd the bytes of the vectors
     est, margin = _block_estimate(
-        qrot_ref[:], crot_ref[:], rn_ref[:], ew_ref[:],
+        qrot_ref[:], crot_ref[0], rn_ref[0], ew_ref[0],
         jnp.transpose(cf_ref[0]), codes_ref[0], dim_ext=dim_ext,
         bits=bits, query_bits=query_bits, epsilon=epsilon,
         ip_metric=ip_metric)
-    ids = ids_ref[:]                      # (1, m) — -1 marks pad/filtered
+    ids = ids_ref[0]                      # (1, m) — -1 marks pad/filtered
     probed = jnp.any(probes_ref[:] == lid, axis=1, keepdims=True)
     probed = jnp.logical_and(probed, lid < n_lists)
     est = jnp.where((ids >= 0) & probed, est, jnp.inf)
@@ -554,7 +552,7 @@ def _bq_scan_kernel(u_ref, probes_ref, qrot_ref, qf_ref, crot_ref,
             exact = -ipx
         else:
             qn = jnp.sum(jnp.square(qt), axis=1, keepdims=True)
-            exact = jnp.maximum(qn + xn_ref[:] - 2.0 * ipx, 0.0)
+            exact = jnp.maximum(qn + xn_ref[0] - 2.0 * ipx, 0.0)
         exact = jnp.where(cand, exact, jnp.inf)
         cat_d = jnp.concatenate([bestd[:], exact], axis=1)
         cat_i = jnp.concatenate(
@@ -581,7 +579,7 @@ def _bq_scan_pallas(qf, qrot, centers_rot, codes, rnorm, cfac, errw,
     bits = cfac.shape[2]
     ip_metric = metric == DistanceType.InnerProduct
     if vmem_mb <= 0:
-        vmem_mb = _default_vmem_mb()
+        vmem_mb = vmem_budget_mb()
 
     uniq = unique_lists(probes, n_lists)
     n_steps = uniq.shape[0]
@@ -594,10 +592,18 @@ def _bq_scan_pallas(qf, qrot, centers_rot, codes, rnorm, cfac, errw,
            or getattr(filter_words, "ndim", 1) == 1,
            "the fused BQ Pallas engine supports shared (1-D) filters "
            "only — use engine='xla' for per-query filter words")
-    ids_g = jnp.take(indices, jnp.minimum(uniq, n_lists - 1), axis=0)
+    # the small per-list planes (ids, norms, corrections, rotated
+    # center) are gathered per unique list, like the id plane always
+    # was; the heavy code/correction/vector planes stream by list id
+    uc = jnp.minimum(uniq, n_lists - 1)
+    ids_g = jnp.take(indices, uc, axis=0)
     if filter_words is not None:
         fbits = test_filter(filter_words, ids_g)
         ids_g = jnp.where(fbits & (ids_g >= 0), ids_g, -1)
+    rn_g = jnp.take(rnorm, uc, axis=0)
+    ew_g = jnp.take(errw, uc, axis=0)
+    xn_g = jnp.take(data_norms, uc, axis=0)
+    crot_g = jnp.take(centers_rot, uc, axis=0)
 
     # lane/sublane alignment; all no-ops on aligned serving layouts
     # (padded_extent rounds max_list_size to 8; resolve_bq_engine
@@ -608,18 +614,21 @@ def _bq_scan_pallas(qf, qrot, centers_rot, codes, rnorm, cfac, errw,
     de_pad = -(-dim_ext // 128) * 128
     if m_pad != m:
         codes = jnp.pad(codes, ((0, 0), (0, m_pad - m), (0, 0)))
-        rnorm = jnp.pad(rnorm, ((0, 0), (0, m_pad - m)))
         cfac = jnp.pad(cfac, ((0, 0), (0, m_pad - m), (0, 0)))
-        errw = jnp.pad(errw, ((0, 0), (0, m_pad - m)))
-        data_norms = jnp.pad(data_norms, ((0, 0), (0, m_pad - m)),
-                             constant_values=jnp.inf)
+        rn_g = jnp.pad(rn_g, ((0, 0), (0, m_pad - m)))
+        ew_g = jnp.pad(ew_g, ((0, 0), (0, m_pad - m)))
+        xn_g = jnp.pad(xn_g, ((0, 0), (0, m_pad - m)),
+                       constant_values=jnp.inf)
         ids_g = jnp.pad(ids_g, ((0, 0), (0, m_pad - m)),
                         constant_values=-1)
     if m_pad != m or d_pad != d:
         data = jnp.pad(data, ((0, 0), (0, m_pad - m), (0, d_pad - d)))
-    crot = centers_rot
     if de_pad != dim_ext:
-        crot = jnp.pad(crot, ((0, 0), (0, de_pad - dim_ext)))
+        crot_g = jnp.pad(crot_g, ((0, 0), (0, de_pad - dim_ext)))
+    # a unit middle axis: Mosaic wants a block's last two dims divisible
+    # by (8, 128) or equal to the array's, and one list row is (1, m)
+    rn_g, ew_g, xn_g, ids_g, crot_g = (
+        a[:, None, :] for a in (rn_g, ew_g, xn_g, ids_g, crot_g))
     p = probes.shape[1]
     p_pad = -(-p // 128) * 128
 
@@ -655,28 +664,24 @@ def _bq_scan_pallas(qf, qrot, centers_rot, codes, rnorm, cfac, errw,
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((q_tile, d_pad), lambda i, j, u: (i, 0),
                          memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, 1, de_pad), lambda i, j, u: (j, 0, 0),
+                         memory_space=pltpu.VMEM),
             # the scalar-prefetched dynamic index maps: step j streams
             # list u[j]'s codes/corrections; the sentinel clamps to a
             # real list and is masked by the membership predicate
-            pl.BlockSpec((1, de_pad),
-                         lambda i, j, u: (jnp.minimum(u[j], clamp), 0),
-                         memory_space=pltpu.VMEM),
             pl.BlockSpec((1, m_pad, words),
                          lambda i, j, u: (jnp.minimum(u[j], clamp), 0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, m_pad),
-                         lambda i, j, u: (jnp.minimum(u[j], clamp), 0),
+            pl.BlockSpec((1, 1, m_pad), lambda i, j, u: (j, 0, 0),
                          memory_space=pltpu.VMEM),
             pl.BlockSpec((1, m_pad, bits),
                          lambda i, j, u: (jnp.minimum(u[j], clamp), 0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, m_pad),
-                         lambda i, j, u: (jnp.minimum(u[j], clamp), 0),
+            pl.BlockSpec((1, 1, m_pad), lambda i, j, u: (j, 0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, m_pad),
-                         lambda i, j, u: (jnp.minimum(u[j], clamp), 0),
+            pl.BlockSpec((1, 1, m_pad), lambda i, j, u: (j, 0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, m_pad), lambda i, j, u: (j, 0),
+            pl.BlockSpec((1, 1, m_pad), lambda i, j, u: (j, 0, 0),
                          memory_space=pltpu.VMEM),
             # the raw-vector plane stays in HBM: the kernel DMAs one
             # list block into VMEM scratch only when the prune left
@@ -704,9 +709,9 @@ def _bq_scan_pallas(qf, qrot, centers_rot, codes, rnorm, cfac, errw,
             jax.ShapeDtypeStruct((q_pad, k), jnp.float32),
             jax.ShapeDtypeStruct((q_pad, k), jnp.int32),
         ),
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_mb << 20),
         interpret=interpret,
-    )(uniq, probes_p, qr, qs, crot, codes, rnorm, cfac, errw,
-      data_norms, ids_g, data)
+    )(uniq, probes_p, qr, qs, crot_g, codes, rn_g, cfac, ew_g,
+      xn_g, ids_g, data)
     return outd[:q], outi[:q]

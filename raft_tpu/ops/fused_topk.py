@@ -27,13 +27,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from raft_tpu.core.chips import vmem_budget_mb
 from raft_tpu.core.validation import expect
 from raft_tpu.distance.types import DistanceType
-
-# jax renamed TPUCompilerParams -> CompilerParams (jax 0.5); accept both
-# so the kernels load on either side of the rename
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
 
 _SUPPORTED_METRICS = (
     DistanceType.L2Expanded,
@@ -127,29 +123,6 @@ def _knn_kernel(q_ref, qn_ref, x_ref, xn_ref, outd_ref, outi_ref,
         outi_ref[:] = besti[:]
 
 
-def _default_vmem_mb() -> int:
-    """Per-kernel Mosaic VMEM budget (MB) — resolved OUTSIDE jit so the
-    env var is honored per call, not frozen into the first trace.
-
-    The default is derived from the attached device generation: v4+
-    parts carry 128 MB of physical VMEM per core (64 MB budget leaves
-    headroom, measured safe on v5e), while v2/v3 and unrecognized
-    kinds fall back to a conservative 16 MB so Mosaic compiles where a
-    64 MB request would be rejected. ``RAFT_TPU_VMEM_MB`` overrides."""
-    import os
-
-    env = os.environ.get("RAFT_TPU_VMEM_MB")
-    if env:
-        return int(env)
-    try:
-        kind = jax.local_devices()[0].device_kind.lower()
-    except Exception:
-        return 16
-    if any(g in kind for g in ("v4", "v5", "v6", "v7")):
-        return 64
-    return 16
-
-
 def fused_knn(
     queries,
     dataset,
@@ -175,18 +148,17 @@ def fused_knn(
     per-call HBM traffic is then exactly one dataset stream.
 
     ``tile=0`` auto-sizes database blocks to the VMEM budget
-    (``vmem_mb``, default from ``RAFT_TPU_VMEM_MB`` or 64). Measured on
+    (``vmem_mb``, default :func:`~raft_tpu.core.chips.vmem_budget_mb`). Measured on
     v5e the stream is per-grid-step bound (~16 us/step) far below the
     HBM roofline, so the right tile is the largest that fits — fewer,
     bigger DMAs — not a fixed 8k.
 
     ``passes > 1`` repeats the full dataset stream that many times in
     ONE dispatch (the grid wraps around) — a benchmarking aid: per-pass
-    time from the slope between two pass counts cancels the dispatch
-    overhead that floors single-dispatch timing on relayed backends.
-    Results are identical to passes=1."""
+    time from the slope between two pass counts cancels the per-call
+    dispatch overhead. Results are identical to passes=1."""
     if vmem_mb <= 0:
-        vmem_mb = _default_vmem_mb()
+        vmem_mb = vmem_budget_mb()
     return _fused_knn_impl(queries, dataset, k, metric,
                            dataset_norms=dataset_norms, tile=tile,
                            vmem_mb=vmem_mb, passes=passes,
@@ -280,7 +252,7 @@ def _fused_knn_impl(
             pltpu.VMEM((qp, k), jnp.float32),
             pltpu.VMEM((qp, k), jnp.int32),
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_mb * 1024 * 1024),
         interpret=interpret,
     )(qs, qn, xs, xn)
@@ -336,7 +308,7 @@ def select_k_tiles(
     of being frozen into the first trace."""
     return _select_k_tiles_impl(values, k, select_min, tile=tile,
                                 interpret=interpret,
-                                vmem_mb=_default_vmem_mb())
+                                vmem_mb=vmem_budget_mb())
 
 
 @functools.partial(jax.jit,
@@ -383,7 +355,7 @@ def _select_k_tiles_impl(
             pltpu.VMEM((bp, k), jnp.float32),
             pltpu.VMEM((bp, k), jnp.int32),
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_mb << 20),
         interpret=interpret,
     )(vs)
@@ -422,12 +394,12 @@ def stream_read_sum(x, tile: int = 0, vmem_mb: int = 0,
     d % 128 == 0), where the input streams in place.
 
     ``tile=0`` auto-sizes blocks to the VMEM budget (``vmem_mb``,
-    default ``RAFT_TPU_VMEM_MB`` or 64): the stream is per-grid-step
+    default :func:`~raft_tpu.core.chips.vmem_budget_mb`): the stream is per-grid-step
     bound (~16 us/step on v5e) well below the HBM roofline, so the
     probe uses the biggest block that fits — a small-block probe
     measures step overhead, not bandwidth."""
     if vmem_mb <= 0:
-        vmem_mb = _default_vmem_mb()
+        vmem_mb = vmem_budget_mb()
     return _stream_read_impl(x, tile, vmem_mb, interpret)
 
 
@@ -462,7 +434,7 @@ def _stream_read_impl(x, tile: int, vmem_mb: int, interpret: bool):
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((1, dpad), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, dpad), jnp.float32)],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_mb * 1024 * 1024),
         interpret=interpret,
     )(x)[:, :d]

@@ -70,18 +70,31 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from raft_tpu.core.chips import vmem_budget_mb
+from raft_tpu.core.logger import logger
 from raft_tpu.core.validation import expect
 from raft_tpu.distance.types import DistanceType
-from raft_tpu.ops.fused_topk import (
-    _COMPILER_PARAMS,
-    _default_vmem_mb,
-    _extract_topk,
-)
+from raft_tpu.ops.fused_topk import _extract_topk
 
 SCAN_ENGINES = ("auto", "pallas", "xla", "rank")
 
 # the merge network unrolls k rounds; past this the XLA merge wins
 _PALLAS_MAX_K = 128
+
+
+@functools.lru_cache(maxsize=None)
+def _warn_degrade(family: str, reason: str) -> None:
+    logger.warning("%s: the pallas engine cannot serve this search (%s);"
+                   " serving it with the xla engine", family, reason)
+
+
+def degrade(family: str, reason: str) -> str:
+    """Resolve a Pallas request to ``"xla"`` — out loud: the first
+    degrade per (family, reason) logs a warning, so a run can tell
+    which engine served it (the executor's cost table also records
+    the resolved engine of every executable)."""
+    _warn_degrade(family, reason)
+    return "xla"
 
 
 def resolve_scan_engine(engine: str, *, data=None, filter_words=None,
@@ -103,12 +116,12 @@ def resolve_scan_engine(engine: str, *, data=None, filter_words=None,
     if engine != "pallas":
         return engine
     if filter_words is not None and getattr(filter_words, "ndim", 1) == 2:
-        return "xla"
+        return degrade("ivf_scan", "per-query filter words")
     if k is not None and k > _PALLAS_MAX_K:
-        return "xla"
+        return degrade("ivf_scan", f"k > {_PALLAS_MAX_K}")
     if data is not None:
         if data.dtype not in (jnp.float32, jnp.bfloat16):
-            return "xla"
+            return degrade("ivf_scan", f"{data.dtype} storage")
         itemsize = 2 if data.dtype == jnp.bfloat16 else 4
         sub = 16 if itemsize == 2 else 8
         m_pad = -(-data.shape[1] // sub) * sub
@@ -122,9 +135,9 @@ def resolve_scan_engine(engine: str, *, data=None, filter_words=None,
         # test shape.
         if jax.default_backend() == "tpu" and (
                 m_pad != data.shape[1] or d_pad != data.shape[2]):
-            return "xla"
+            return degrade("ivf_scan", "list layout not tile-aligned")
         if vmem_mb <= 0:
-            vmem_mb = _default_vmem_mb()
+            vmem_mb = vmem_budget_mb()
         # mirror _scan_pallas's budget: the list block + margin fixed
         # cost must leave room for at least one minimal (8-row) query
         # tile — otherwise the kernel's q_tile floor would overshoot
@@ -134,7 +147,7 @@ def resolve_scan_engine(engine: str, *, data=None, filter_words=None,
         fixed = 3 * m_pad * (d_pad * itemsize + 8) + (2 << 20)
         per_q = 4 * (d_pad + 256) + 24 * m_pad + 16 * (k or _PALLAS_MAX_K)
         if fixed + 8 * per_q > vmem_mb << 20:
-            return "xla"
+            return degrade("ivf_scan", "list block exceeds the VMEM budget")
     return engine
 
 
@@ -385,8 +398,8 @@ def _ivf_scan_kernel(u_ref, probes_ref, q_ref, x_ref, xn_ref, ids_ref,
         preferred_element_type=jnp.float32,
     )                                     # (q_tile, m)
     # min-space distances; IP negates back at the final step
-    dist = -ip if ip_metric else xn_ref[:] - 2.0 * ip
-    ids = ids_ref[:]                      # (1, m) — -1 marks pad/filtered
+    dist = -ip if ip_metric else xn_ref[0] - 2.0 * ip
+    ids = ids_ref[0]                      # (1, m) — -1 marks pad/filtered
     # membership predicate: which tile rows actually probed this list.
     # The lid < n_lists guard kills sentinel steps outright, including
     # the case where probe slots carry the sentinel value themselves
@@ -423,18 +436,21 @@ def _scan_pallas(qf, data, data_norms, indices, probes, filter_words, *,
     n_lists, m, _ = data.shape
     ip_metric = metric == DistanceType.InnerProduct
     if vmem_mb <= 0:
-        vmem_mb = _default_vmem_mb()
+        vmem_mb = vmem_budget_mb()
     itemsize = 2 if data.dtype == jnp.bfloat16 else 4
     sub = 16 if itemsize == 2 else 8
 
     uniq = unique_lists(probes, n_lists)
     n_steps = uniq.shape[0]
 
-    # gathered id planes, one per unique list (4 B/slot — 1/32 of the
-    # d=128 data stream); a shared bitset filter folds in here: a
-    # filtered slot becomes id -1, i.e. padding, so the kernel needs no
-    # per-element word gathers (Mosaic lowers those to the scalar core)
-    ids_g = jnp.take(indices, jnp.minimum(uniq, n_lists - 1), axis=0)
+    # gathered id and norm planes, one row per unique list (4 B/slot
+    # each — 1/32 of the d=128 data stream); a shared bitset filter
+    # folds in here: a filtered slot becomes id -1, i.e. padding, so
+    # the kernel needs no per-element word gathers (Mosaic lowers
+    # those to the scalar core)
+    uc = jnp.minimum(uniq, n_lists - 1)
+    ids_g = jnp.take(indices, uc, axis=0)
+    xn_g = jnp.take(data_norms, uc, axis=0)
     if filter_words is not None:
         bits = test_filter(filter_words, ids_g)
         ids_g = jnp.where(bits & (ids_g >= 0), ids_g, -1)
@@ -445,9 +461,13 @@ def _scan_pallas(qf, data, data_norms, indices, probes, filter_words, *,
     d_pad = -(-d // 128) * 128
     if m_pad != m or d_pad != d:
         data = jnp.pad(data, ((0, 0), (0, m_pad - m), (0, d_pad - d)))
-        data_norms = jnp.pad(data_norms, ((0, 0), (0, m_pad - m)))
+        xn_g = jnp.pad(xn_g, ((0, 0), (0, m_pad - m)))
         ids_g = jnp.pad(ids_g, ((0, 0), (0, m_pad - m)),
                         constant_values=-1)
+    # a unit middle axis: Mosaic wants a block's last two dims divisible
+    # by (8, 128) or equal to the array's, and one list row is (1, m)
+    xn_g = xn_g[:, None, :]
+    ids_g = ids_g[:, None, :]
     p = probes.shape[1]
     p_pad = -(-p // 128) * 128
 
@@ -485,10 +505,9 @@ def _scan_pallas(qf, data, data_norms, indices, probes, filter_words, *,
             pl.BlockSpec((1, m_pad, d_pad),
                          lambda i, j, u: (jnp.minimum(u[j], clamp), 0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, m_pad),
-                         lambda i, j, u: (jnp.minimum(u[j], clamp), 0),
+            pl.BlockSpec((1, 1, m_pad), lambda i, j, u: (j, 0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, m_pad), lambda i, j, u: (j, 0),
+            pl.BlockSpec((1, 1, m_pad), lambda i, j, u: (j, 0, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_specs=(
@@ -509,8 +528,8 @@ def _scan_pallas(qf, data, data_norms, indices, probes, filter_words, *,
             jax.ShapeDtypeStruct((q_pad, k), jnp.float32),
             jax.ShapeDtypeStruct((q_pad, k), jnp.int32),
         ),
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_mb << 20),
         interpret=interpret,
-    )(uniq, probes_p, qs, data, data_norms, ids_g)
+    )(uniq, probes_p, qs, data, xn_g, ids_g)
     return outd[:q], outi[:q]

@@ -48,24 +48,23 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from raft_tpu.core.chips import vmem_budget_mb
 from raft_tpu.core.validation import expect
 from raft_tpu.distance.types import DistanceType
-from raft_tpu.ops.fused_topk import (
-    _COMPILER_PARAMS,
-    _default_vmem_mb,
-    _extract_topk,
-)
+from raft_tpu.ops.fused_topk import _extract_topk
 from raft_tpu.ops.ivf_scan import (
     _PALLAS_MAX_K,
     _merge_smallest_id,
+    degrade,
     unique_lists,
 )
 
 TIER_ENGINES = ("auto", "pallas", "xla")
 
 
-def resolve_tier_engine(engine: str, *, hot_data=None, filter_words=None,
-                        k=None, vmem_mb: int = 0) -> str:
+def resolve_tier_engine(engine: str, *, hot_data=None, cold_data=None,
+                        filter_words=None, k=None,
+                        vmem_mb: int = 0) -> str:
     """Resolve a tiered ``scan_engine`` param to a concrete engine.
 
     ``auto`` is the dual-source Pallas kernel on TPU and the tiered
@@ -74,8 +73,11 @@ def resolve_tier_engine(engine: str, *, hot_data=None, filter_words=None,
     id-fold trick needs one shared id plane), non-f32 storage (the
     tiered path is f32-only — the cold DMA scratch and the hot block
     must agree on layout), ``k`` past the unrolled-merge budget,
-    compiled-mode layout misalignment, or a VMEM budget the hot block
-    + the double-buffered cold scratch cannot fit."""
+    compiled-mode layout misalignment, a VMEM budget the hot block
+    + the double-buffered cold scratch cannot fit, or a cold plane
+    committed to host memory (Mosaic refuses a host-memory operand:
+    ``tests/test_tpu_compile.py`` compiles the kernel with the cold
+    plane in HBM only)."""
     expect(engine in TIER_ENGINES,
            f"tiered scan_engine must be one of {TIER_ENGINES}, got "
            f"{engine!r}")
@@ -84,12 +86,15 @@ def resolve_tier_engine(engine: str, *, hot_data=None, filter_words=None,
     if engine != "pallas":
         return engine
     if filter_words is not None and getattr(filter_words, "ndim", 1) == 2:
-        return "xla"
+        return degrade("tier_scan", "per-query filter words")
     if k is not None and k > _PALLAS_MAX_K:
-        return "xla"
+        return degrade("tier_scan", f"k > {_PALLAS_MAX_K}")
+    if cold_data is not None and getattr(
+            cold_data.sharding, "memory_kind", None) == "pinned_host":
+        return degrade("tier_scan", "cold plane in host memory")
     if hot_data is not None:
         if hot_data.dtype != jnp.float32:
-            return "xla"
+            return degrade("tier_scan", f"{hot_data.dtype} storage")
         m_pad = -(-hot_data.shape[1] // 8) * 8
         d_pad = -(-hot_data.shape[2] // 128) * 128
         if jax.default_backend() == "tpu" and (
@@ -97,13 +102,14 @@ def resolve_tier_engine(engine: str, *, hot_data=None, filter_words=None,
             # compiled Mosaic would force a whole-tensor jnp.pad per
             # call; interpret mode (CPU CI) keeps the pad path so any
             # test shape is coverable — same contract as ivf_scan
-            return "xla"
+            return degrade("tier_scan", "list layout not tile-aligned")
         if vmem_mb <= 0:
-            vmem_mb = _default_vmem_mb()
+            vmem_mb = vmem_budget_mb()
         fixed, per_q = _tier_vmem_plan(m_pad, d_pad,
                                        k or _PALLAS_MAX_K)
         if fixed + 8 * per_q > vmem_mb << 20:
-            return "xla"
+            return degrade("tier_scan",
+                           "list block exceeds the VMEM budget")
     return engine
 
 
@@ -136,6 +142,8 @@ def resolve_tier_bq_engine(engine: str) -> str:
     expect(engine in ("auto", "pallas", "xla"),
            "tiered BQ scan_engine must be 'auto', 'pallas' or 'xla' "
            f"— got {engine!r}")
+    if engine == "pallas":
+        return degrade("tier_bq_scan", "no dual-source BQ kernel")
     return "xla"
 
 
@@ -158,11 +166,15 @@ def tier_block_select(hot_plane, cold_plane, hs, cs):
     the selected values are the stored rows either way, so everything
     downstream is bit-identical to the un-tiered scan. Shared by the
     tiered flat XLA engine and the graftcast PQ/BQ cold engines
-    (LUT union scan / XOR+popcount estimate)."""
+    (LUT union scan / XOR+popcount estimate). The cold block moves to
+    device memory explicitly: a host-committed plane may only be
+    sliced, and XLA turns slice + move into one host-to-device copy
+    of exactly that block."""
     return jax.lax.cond(
         cs >= 0,
-        lambda: jax.lax.dynamic_index_in_dim(
+        lambda: jax.device_put(jax.lax.dynamic_index_in_dim(
             cold_plane, jnp.maximum(cs, 0), 0, False),
+            jax.memory.Space.Device),
         lambda: jax.lax.dynamic_index_in_dim(
             hot_plane, jnp.maximum(hs, 0), 0, False),
     )
@@ -389,8 +401,8 @@ def _tier_scan_kernel(u_ref, hf_ref, cf_ref, cs_ref, probes_ref, q_ref,
         precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )                                     # (q_tile, m)
-    dist = -ip if ip_metric else xn_ref[:] - 2.0 * ip
-    ids = ids_ref[:]                      # (1, m) — -1 marks pad/filtered
+    dist = -ip if ip_metric else xn_ref[0] - 2.0 * ip
+    ids = ids_ref[0]                      # (1, m) — -1 marks pad/filtered
     probed = jnp.any(probes_ref[:] == lid, axis=1, keepdims=True)
     probed = jnp.logical_and(probed, lid < n_lists)
     dist = jnp.where((ids >= 0) & probed, dist, jnp.inf)
@@ -424,7 +436,7 @@ def _tier_scan_pallas(qf, hot_data, cold_data, hot_slot_map,
     m = hot_data.shape[1]
     ip_metric = metric == DistanceType.InnerProduct
     if vmem_mb <= 0:
-        vmem_mb = _default_vmem_mb()
+        vmem_mb = vmem_budget_mb()
     expect(hot_data.dtype == jnp.float32
            and cold_data.dtype == jnp.float32,
            "the tiered Pallas engine is f32-only — use engine='xla' "
@@ -442,7 +454,9 @@ def _tier_scan_pallas(qf, hot_data, cold_data, hot_slot_map,
     # gathered id planes + shared-filter fold, exactly like ivf_scan
     # (the id/norm planes are fully resident, so the fold never
     # touches the cold tier)
-    ids_g = jnp.take(indices, jnp.minimum(uniq, n_lists - 1), axis=0)
+    uc = jnp.minimum(uniq, n_lists - 1)
+    ids_g = jnp.take(indices, uc, axis=0)
+    xn_g = jnp.take(data_norms, uc, axis=0)
     if filter_words is not None:
         bits = test_filter(filter_words, ids_g)
         ids_g = jnp.where(bits & (ids_g >= 0), ids_g, -1)
@@ -457,10 +471,14 @@ def _tier_scan_pallas(qf, hot_data, cold_data, hot_slot_map,
                            ((0, 0), (0, m_pad - m), (0, d_pad - d)))
         cold_data = jnp.pad(cold_data,
                             ((0, 0), (0, m_pad - m), (0, d_pad - d)))
-        data_norms = jnp.pad(data_norms, ((0, 0), (0, m_pad - m)),
-                             constant_values=jnp.inf)
+        xn_g = jnp.pad(xn_g, ((0, 0), (0, m_pad - m)),
+                       constant_values=jnp.inf)
         ids_g = jnp.pad(ids_g, ((0, 0), (0, m_pad - m)),
                         constant_values=-1)
+    # a unit middle axis: Mosaic wants a block's last two dims divisible
+    # by (8, 128) or equal to the array's, and one list row is (1, m)
+    xn_g = xn_g[:, None, :]
+    ids_g = ids_g[:, None, :]
     p = probes.shape[1]
     p_pad = -(-p // 128) * 128
 
@@ -495,12 +513,11 @@ def _tier_scan_pallas(qf, hot_data, cold_data, hot_slot_map,
                          lambda i, j, u, hf, cf, cs: (
                              jnp.minimum(hf[j], hot_clamp), 0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, m_pad),
-                         lambda i, j, u, hf, cf, cs: (
-                             jnp.minimum(u[j], n_lists - 1), 0),
+            pl.BlockSpec((1, 1, m_pad),
+                         lambda i, j, u, hf, cf, cs: (j, 0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, m_pad),
-                         lambda i, j, u, hf, cf, cs: (j, 0),
+            pl.BlockSpec((1, 1, m_pad),
+                         lambda i, j, u, hf, cf, cs: (j, 0, 0),
                          memory_space=pltpu.VMEM),
             # the cold tier stays put (host memory on TPU): the
             # kernel DMAs one list block at a time into the
@@ -530,9 +547,9 @@ def _tier_scan_pallas(qf, hot_data, cold_data, hot_slot_map,
             jax.ShapeDtypeStruct((q_pad, k), jnp.float32),
             jax.ShapeDtypeStruct((q_pad, k), jnp.int32),
         ),
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_mb << 20),
         interpret=interpret,
     )(uniq, hot_fetch, cold_fetch, cold_seq, probes_p, qs, hot_data,
-      data_norms, ids_g, cold_data)
+      xn_g, ids_g, cold_data)
     return outd[:q], outi[:q]

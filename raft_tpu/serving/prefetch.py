@@ -150,8 +150,10 @@ def _stage_row_fn(staged_plane, cold_plane, cold_slot, row):
     HBM while staging); slot and row are traced scalars, so one
     compiled program per plane geometry serves every prefetch — the
     zero-compile discipline the acceptance gate measures."""
-    block = jax.lax.dynamic_index_in_dim(cold_plane, cold_slot, 0,
-                                         keepdims=False)
+    block = jax.device_put(
+        jax.lax.dynamic_index_in_dim(cold_plane, cold_slot, 0,
+                                     keepdims=False),
+        jax.memory.Space.Device)
     return jax.lax.dynamic_update_index_in_dim(staged_plane, block,
                                                row, 0)
 

@@ -20,11 +20,6 @@ Usage: python scripts/tpu_scale_build.py [--rows 100000000] [--dim 96]
 import argparse
 import json
 import os
-
-os.environ.setdefault(
-    "JAX_COMPILATION_CACHE_DIR",
-    os.path.join(os.path.dirname(os.path.abspath(__file__)), "..",
-                 "results", "jaxcache"))
 import time
 
 import numpy as np
@@ -102,6 +97,10 @@ def main():
         args.rows = min(args.rows, 2_000_000)
 
     import jax
+
+    from raft_tpu.core.resources import init_compile_cache
+
+    init_compile_cache()
     pq_dim = args.pq_dim or args.dim // 2
     emit("config", backend=jax.default_backend(), rows=args.rows,
          dim=args.dim, pq_dim=pq_dim, pq_bits=args.pq_bits)

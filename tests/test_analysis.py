@@ -186,9 +186,6 @@ import jax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_COMPILER_PARAMS = pltpu.CompilerParams
-
-
 def kernel(x_ref, o_ref):
     o_ref[:] = x_ref[:]
 
@@ -202,7 +199,7 @@ def run(x, interpret=False):
         in_specs=[pl.BlockSpec((rows, cols), lambda i: (0, i))],
         out_specs=pl.BlockSpec((rows, cols), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((rows, cols), x.dtype),
-        compiler_params=_COMPILER_PARAMS(vmem_limit_bytes=64 << 20),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=64 << 20),
         interpret=interpret,
     )(x)
 '''
@@ -210,9 +207,6 @@ R4_CONFORMING = '''\
 import jax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-_COMPILER_PARAMS = pltpu.CompilerParams
-
 
 def kernel(x_ref, o_ref):
     o_ref[:] = x_ref[:]
@@ -227,7 +221,7 @@ def run(x, n, interpret=False):
         in_specs=[pl.BlockSpec((8, 512), lambda i: (0, i))],
         out_specs=pl.BlockSpec((8, 512), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((8, 512), x.dtype),
-        compiler_params=_COMPILER_PARAMS(vmem_limit_bytes=64 << 20),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=64 << 20),
         interpret=interpret,
     )(x)
 '''
@@ -236,9 +230,6 @@ R4_SYMBOLIC_VIOLATING = '''\
 import jax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-_COMPILER_PARAMS = pltpu.CompilerParams
-
 
 def kernel(x_ref, o_ref):
     o_ref[:] = x_ref[:]
@@ -254,7 +245,7 @@ def run(x, interpret=False):
         in_specs=[pl.BlockSpec((rows, cols), lambda i: (0, i))],
         out_specs=pl.BlockSpec((rows, cols), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((rows, cols), x.dtype),
-        compiler_params=_COMPILER_PARAMS(vmem_limit_bytes=64 << 20),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=64 << 20),
         interpret=interpret,
     )(x)
 '''
@@ -262,9 +253,6 @@ R4_SYMBOLIC_CONFORMING = '''\
 import jax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-_COMPILER_PARAMS = pltpu.CompilerParams
-
 
 def kernel(x_ref, o_ref):
     o_ref[:] = x_ref[:]
@@ -280,7 +268,7 @@ def run(x, interpret=False):
         in_specs=[pl.BlockSpec((rows, cols), lambda i: (0, i))],
         out_specs=pl.BlockSpec((rows, cols), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((rows, cols), x.dtype),
-        compiler_params=_COMPILER_PARAMS(vmem_limit_bytes=64 << 20),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=64 << 20),
         interpret=interpret,
     )(x)
 '''

@@ -245,3 +245,30 @@ class TestInterop:
         x = np.random.default_rng(0).standard_normal((8, 4)).astype(np.float32)
         out = pairwise_distance(None, x, jnp.asarray(x))
         assert np.allclose(np.asarray(out).diagonal(), 0.0, atol=1e-4)
+
+
+class TestChipTable:
+    """One per-device_kind table: a TPU it does not know is an error,
+    never a default; off the TPU only the interpret budget applies."""
+
+    def test_unknown_tpu_kind_raises(self):
+        import types
+
+        from raft_tpu.core.chips import chip_spec
+
+        dev = types.SimpleNamespace(platform="tpu", device_kind="TPU v99")
+        with pytest.raises(ValueError, match="unknown TPU device_kind"):
+            chip_spec(dev)
+        v5e = types.SimpleNamespace(platform="tpu",
+                                    device_kind="TPU v5 lite")
+        assert chip_spec(v5e).hbm_bytes_per_s == 819e9
+        with pytest.raises(ValueError, match="platform"):
+            chip_spec(jax.devices()[0])
+
+    def test_vmem_budget_off_tpu(self, monkeypatch):
+        from raft_tpu.core.chips import INTERPRET_VMEM_MB, vmem_budget_mb
+
+        monkeypatch.delenv("RAFT_TPU_VMEM_MB", raising=False)
+        assert vmem_budget_mb() == INTERPRET_VMEM_MB
+        monkeypatch.setenv("RAFT_TPU_VMEM_MB", "8")
+        assert vmem_budget_mb() == 8

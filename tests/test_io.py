@@ -83,6 +83,24 @@ class TestBinFile:
             np.testing.assert_array_equal(ds.read(n_threads=8), data)
 
 
+def test_native_stale_by_mtime(tmp_path):
+    """The gitignored .so rebuilds when missing or older than a
+    source — never loaded stale."""
+    import os
+
+    from raft_tpu.io.binfile import native_stale
+
+    so, src = tmp_path / "lib.so", tmp_path / "io.cpp"
+    src.write_text("src")
+    assert native_stale(so, src)
+    so.write_text("so")
+    os.utime(so, (100, 100))
+    os.utime(src, (200, 200))
+    assert native_stale(so, src)
+    os.utime(so, (300, 300))
+    assert not native_stale(so, src, tmp_path / "absent.cpp")
+
+
 class TestPipeline:
     """Native prefetch pipeline + streaming IVF build."""
 

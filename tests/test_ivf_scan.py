@@ -204,11 +204,13 @@ class TestResolveEngine:
         assert resolve_scan_engine("rank") == "rank"
         assert resolve_scan_engine("xla") == "xla"
 
-    def test_pallas_precondition_fallbacks(self):
+    def test_pallas_precondition_fallbacks(self, caplog):
         import jax.numpy as jnp
 
-        from raft_tpu.ops.ivf_scan import resolve_scan_engine
+        from raft_tpu.ops.ivf_scan import _warn_degrade, resolve_scan_engine
 
+        # a degrade is never silent: the first per reason logs
+        _warn_degrade.cache_clear()
         data = jnp.zeros((4, 8, 16), jnp.float32)
         assert resolve_scan_engine("pallas", data=data) == "pallas"
         # 2-D per-query filter words
@@ -226,6 +228,9 @@ class TestResolveEngine:
         # a single list block that cannot fit VMEM
         big = jnp.zeros((2, 65536, 256), jnp.float32)
         assert resolve_scan_engine("pallas", data=big, vmem_mb=16) == "xla"
+        warned = [r.getMessage() for r in caplog.records
+                  if "serving it with the xla engine" in r.getMessage()]
+        assert len(warned) == 4, warned
 
     def test_rejects_unknown_engine(self):
         from raft_tpu.core.validation import RaftError

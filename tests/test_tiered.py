@@ -589,7 +589,7 @@ class TestMultiTileKernel:
         m_pad = -(-t.max_list_size // 8) * 8
         d_pad = -(-t.dim // 128) * 128
         fixed, per_q = _tier_vmem_plan(m_pad, d_pad, 10)
-        vmem_mb = -(-int(fixed + 48 * per_q) // (1 << 20))
+        vmem_mb = int(fixed + 96 * per_q) >> 20
         budget = (vmem_mb << 20) - fixed
         q_tile = min(max(8, (budget // per_q) // 8 * 8), 192)
         assert 192 // q_tile >= 2, "budget did not force multiple tiles"

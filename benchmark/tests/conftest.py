@@ -1,0 +1,32 @@
+"""The benchmark's own tests run on the CPU at tiny sizes."""
+
+import os
+import sys
+
+import pytest
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+
+@pytest.fixture(autouse=True)
+def _cpu_compile_cache(tmp_path_factory, monkeypatch):
+    """CPU executables stay out of the checkout's cache, which the
+    chip's runs use."""
+    from benchmark import run
+
+    monkeypatch.setattr(run, "CACHE_DIR",
+                        str(tmp_path_factory.getbasetemp() / "jax_cache"))
+
+
+@pytest.fixture(autouse=True)
+def _any_device(monkeypatch):
+    """The harness's look for a chip is skipped: the rest of a run is
+    driven on the CPU's devices."""
+    import jax
+
+    from benchmark import run
+
+    monkeypatch.setattr(run, "require_chips", lambda chips: jax.devices())

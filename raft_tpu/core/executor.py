@@ -94,7 +94,11 @@ device ops whose programs XLA caches per distinct batch size — the
 *search* program itself never recompiles, and once a batch size has
 been seen, repeats are entirely compile-free. (The ragged path has no
 such per-shape micro-programs at all: packing is host-side numpy in,
-one batched fetch out.)
+one batched fetch out.) Each such program is counted in
+``serving.execute.eager_programs``, and every dispatch's host stages
+(prepare, enqueue, slice) are :class:`~raft_tpu.core.tracing.host_span`
+spans: profiler annotations beside the device ops, and latency
+histograms.
 """
 
 from __future__ import annotations
@@ -115,6 +119,78 @@ import numpy as np
 from raft_tpu.core import tracing
 from raft_tpu.core.resources import Resources, ensure_resources
 from raft_tpu.core.validation import expect
+
+# The host stages of one dispatch, back to back: prepare (block concat,
+# bucket, plan, pad, placement, the wait for the lock, up to the
+# enqueue), enqueue (the AOT executable's call, argument transfer
+# included) and slice (the bucket's output cut into each block's
+# result). Each is a span of that name and a latency histogram;
+# serving.metrics re-exports them beside the batcher's stages.
+STAGE_PREFIX = "serving.executor."
+PREPARE_SPAN = STAGE_PREFIX + "prepare"
+PREPARE = STAGE_PREFIX + "prepare_seconds"
+ENQUEUE_SPAN = STAGE_PREFIX + "enqueue"
+ENQUEUE = STAGE_PREFIX + "enqueue_seconds"
+SLICE_SPAN = STAGE_PREFIX + "slice"
+SLICE = STAGE_PREFIX + "slice_seconds"
+# eager device programs a dispatch launches beside its compiled
+# executable: partial slices and copies of device arrays, device-side
+# concatenations, pads and casts, jnp.asarray of host values (implicit
+# transfers of numpy arguments into the compiled call are not counted)
+EAGER_PROGRAMS = "serving.execute.eager_programs"
+
+
+def _count_eager(n: int) -> None:
+    if n:
+        tracing.inc_counter(EAGER_PROGRAMS, n)
+
+
+def _take_rows(x, start: int, stop: int):
+    """``(x[start:stop], eager programs it launched)``: a partial slice
+    of a device array is one program; a whole one is the array itself,
+    and a numpy slice stays on the host."""
+    partial = start > 0 or stop < x.shape[0]
+    return x[start:stop], int(partial and not isinstance(x, np.ndarray))
+
+
+def _concat_blocks(blocks):
+    """The blocks as one batch: numpy blocks join on the host, device
+    blocks in one eager concatenation."""
+    if len(blocks) == 1:
+        return blocks[0]
+    if all(isinstance(b, np.ndarray) for b in blocks):
+        return np.concatenate(blocks)
+    _count_eager(1)
+    return jnp.concatenate([jnp.asarray(b) for b in blocks])
+
+
+def _split_rows(d, i, sizes):
+    """A batch's ``(d, i)`` results cut into consecutive per-block row
+    ranges of ``sizes``."""
+    out, start, n = [], 0, 0
+    for m in sizes:
+        dm, nd = _take_rows(d, start, start + m)
+        im, ni = _take_rows(i, start, start + m)
+        out.append((dm, im))
+        n += nd + ni
+        start += m
+    _count_eager(n)
+    return out
+
+
+def _slice_outputs(out_d, out_i, q: int, sizes, copy: bool = False):
+    """The slice stage: a bucket's outputs cut to the ``q`` real rows
+    (``copy``: copied whole instead, for a full bucket whose outputs
+    alias donated state), then into per-block results."""
+    with tracing.host_span(SLICE_SPAN, hist=SLICE):
+        if copy:
+            _count_eager(2)
+            d, i = jnp.copy(out_d), jnp.copy(out_i)
+        else:
+            (d, nd), (i, ni) = (_take_rows(out_d, 0, q),
+                                _take_rows(out_i, 0, q))
+            _count_eager(nd + ni)
+        return _split_rows(d, i, sizes)
 
 
 def _fused_entry_fn(queries, dataset, norms, *, k: int, metric):
@@ -523,27 +599,9 @@ class SearchExecutor:
         ``mesh_trace`` on) — the serving batcher passes its members'
         ids so mesh stragglers attribute back to requests."""
         expect(len(np.shape(queries)) == 2, "queries must be (q, dim)")
-        q = int(np.shape(queries)[0])
-        if q == 0:
-            return (jnp.zeros((0, k), jnp.float32),
-                    jnp.zeros((0, k), jnp.int32))
         fw = self._resolve_filter(sample_filter)
-        max_b = self.buckets[-1]
-        if q <= max_b:
-            return self._run(index, queries, k, params, fw, kw,
-                             trace_ids=trace_ids)
-        # tile oversized batches at the top bucket; every tile runs the
-        # same executable and all tiles dispatch before any fetch
-        outs_d, outs_i = [], []
-        for start in range(0, q, max_b):
-            qt = queries[start:start + max_b]
-            fwt = fw[start:start + max_b] if (
-                fw is not None and fw.ndim == 2) else fw
-            d, i = self._run(index, qt, k, params, fwt, kw,
-                             trace_ids=trace_ids)
-            outs_d.append(d)
-            outs_i.append(i)
-        return jnp.concatenate(outs_d), jnp.concatenate(outs_i)
+        return self._search(index, [queries], k, params, fw, kw,
+                            trace_ids)[0]
 
     def coalesce_key(self, index, k: int, params=None, sample_filter=None,
                      **kw) -> tuple:
@@ -576,21 +634,40 @@ class SearchExecutor:
         function of query content (PR 16), which retired the last
         per-block dispatch special case."""
         expect(len(blocks) > 0, "search_blocks needs at least one block")
-        sizes = [int(np.shape(b)[0]) for b in blocks]
         fw = self._resolve_filter(sample_filter)
-        if len(blocks) == 1:
-            cat = blocks[0]
-        elif all(isinstance(b, np.ndarray) for b in blocks):
-            cat = np.concatenate(blocks)
-        else:
-            cat = jnp.concatenate([jnp.asarray(b) for b in blocks])
-        d, i = self.search(index, cat, k, params, fw,
-                           trace_ids=trace_ids, **kw)
-        out, start = [], 0
-        for m in sizes:
-            out.append((d[start:start + m], i[start:start + m]))
-            start += m
-        return out
+        return self._search(index, blocks, k, params, fw, kw, trace_ids)
+
+    def _search(self, index, blocks, k, params, fw, kw, trace_ids):
+        """The bucketed path of :meth:`search` and :meth:`search_blocks`:
+        the blocks as one batch, per-block ``(distances, indices)``."""
+        sizes = [int(np.shape(b)[0]) for b in blocks]
+        q = sum(sizes)
+        if q == 0:
+            return [(jnp.zeros((0, k), jnp.float32),
+                     jnp.zeros((0, k), jnp.int32))] * len(blocks)
+        max_b = self.buckets[-1]
+        if q <= max_b:
+            return self._run(index, blocks, k, params, fw, kw,
+                             trace_ids=trace_ids)
+        # tile oversized batches at the top bucket; every tile runs the
+        # same executable and all tiles dispatch before any fetch
+        queries = _concat_blocks(blocks)
+        outs_d, outs_i = [], []
+        for start in range(0, q, max_b):
+            qt, n = _take_rows(queries, start, start + max_b)
+            fwt = fw
+            if fw is not None and fw.ndim == 2:
+                fwt, n_fw = _take_rows(fw, start, start + max_b)
+                n += n_fw
+            _count_eager(n)
+            ((d, i),) = self._run(index, [qt], k, params, fwt, kw,
+                                  trace_ids=trace_ids)
+            outs_d.append(d)
+            outs_i.append(i)
+        with tracing.host_span(SLICE_SPAN, hist=SLICE):
+            _count_eager(2)
+            return _split_rows(jnp.concatenate(outs_d),
+                               jnp.concatenate(outs_i), sizes)
 
     # -- ragged packed-batch plan family ------------------------------------
 
@@ -685,9 +762,13 @@ class SearchExecutor:
         tile — the same per-dispatch transfer the bucketed mesh path
         pays); single-chip plans pass host arrays straight through
         (the compiled call owns the transfer)."""
+        eager = int(isinstance(rpt, np.ndarray))
         rpt = jnp.asarray(rpt)
         if plan.qsharding is None:
+            _count_eager(eager)
             return qt, rpt
+        _count_eager(eager + int(isinstance(qt, np.ndarray)
+                                 or qt.dtype != plan.qdtype))
         return jax.device_put([jnp.asarray(qt, plan.qdtype), rpt],
                               [plan.qsharding, plan.qsharding])
 
@@ -751,140 +832,151 @@ class SearchExecutor:
         every chunk is dispatched before anything else can re-donate
         its outputs."""
         expect(len(blocks) > 0, "search_ragged needs at least one block")
-        n = len(blocks)
-        if not isinstance(ks, (list, tuple)):
-            ks = [ks] * n
-        if not isinstance(params_list, (list, tuple)):
-            params_list = [params_list] * n
-        expect(len(ks) == n and len(params_list) == n,
-               "ks/params_list must match blocks")
-        fw = self._resolve_filter(sample_filter)
-        # blocks repeat few distinct (params, k) pairs, and resolution
-        # builds a base plan (one resolution authority — see
-        # _ragged_resolve): memoize per distinct pair so a packed
-        # dispatch of n blocks resolves once per pair, not n times
-        memo: dict = {}
-        specs = []
-        for kj, pj in zip(ks, params_list):
-            mk = (pj, kj)
-            if mk not in memo:
-                memo[mk] = self._ragged_resolve(index, kj, pj, fw, kw)
-            s, reason = memo[mk]
-            expect(s is not None,
-                   "a block is not servable by the ragged plan "
-                   f"family: {reason}")
-            specs.append(s)
-        classes = {(s["family"], s["engine"], s["np_class"],
-                    s["k_class"]) for s in specs}
-        expect(len(classes) == 1,
-               "blocks must agree on the ragged params class — group "
-               "submissions by SearchExecutor.ragged_key")
-        spec = specs[0]
-        k_class = spec["k_class"]
-        sizes = [int(np.shape(b)[0]) for b in blocks]
-        for b in blocks:
-            expect(int(np.shape(b)[1]) == index.dim,
-                   "query dim mismatch")
-        total = sum(sizes)
-        if total == 0:
-            return [(np.zeros((0, kj), np.float32),
-                     np.zeros((0, kj), np.int32)) for kj in ks]
-        if fw is not None and fw.ndim == 2:
-            expect(int(fw.shape[0]) == total,
-                   "2-D filter rows must match the packed query rows")
-        tile = self._ragged_tile_for(total)
-        plan = self._plan_ragged(index, fw, spec, tile)
+        # the prepare stage ends inside the locked core, where the
+        # first tile's enqueue begins
+        with tracing.host_span(PREPARE_SPAN, hist=PREPARE) as prepare:
+            n = len(blocks)
+            if not isinstance(ks, (list, tuple)):
+                ks = [ks] * n
+            if not isinstance(params_list, (list, tuple)):
+                params_list = [params_list] * n
+            expect(len(ks) == n and len(params_list) == n,
+                   "ks/params_list must match blocks")
+            fw = self._resolve_filter(sample_filter)
+            # blocks repeat few distinct (params, k) pairs, and resolution
+            # builds a base plan (one resolution authority — see
+            # _ragged_resolve): memoize per distinct pair so a packed
+            # dispatch of n blocks resolves once per pair, not n times
+            memo: dict = {}
+            specs = []
+            for kj, pj in zip(ks, params_list):
+                mk = (pj, kj)
+                if mk not in memo:
+                    memo[mk] = self._ragged_resolve(index, kj, pj, fw, kw)
+                s, reason = memo[mk]
+                expect(s is not None,
+                       "a block is not servable by the ragged plan "
+                       f"family: {reason}")
+                specs.append(s)
+            classes = {(s["family"], s["engine"], s["np_class"],
+                        s["k_class"]) for s in specs}
+            expect(len(classes) == 1,
+                   "blocks must agree on the ragged params class — group "
+                   "submissions by SearchExecutor.ragged_key")
+            spec = specs[0]
+            k_class = spec["k_class"]
+            sizes = [int(np.shape(b)[0]) for b in blocks]
+            for b in blocks:
+                expect(int(np.shape(b)[1]) == index.dim,
+                       "query dim mismatch")
+            total = sum(sizes)
+            if total == 0:
+                return [(np.zeros((0, kj), np.float32),
+                         np.zeros((0, kj), np.int32)) for kj in ks]
+            if fw is not None and fw.ndim == 2:
+                expect(int(fw.shape[0]) == total,
+                       "2-D filter rows must match the packed query rows")
+            tile = self._ragged_tile_for(total)
+            plan = self._plan_ragged(index, fw, spec, tile)
 
-        # host-side packing: adjacent blocks, zero pad rows, per-row
-        # probe budgets (0 on pads). numpy blocks (the serving path)
-        # pack with zero device ops; device arrays fall back to one
-        # concat + pad program per distinct total
-        from raft_tpu.ops.ivf_scan import ragged_row_probes
+            # host-side packing: adjacent blocks, zero pad rows, per-row
+            # probe budgets (0 on pads). numpy blocks (the serving path)
+            # pack with zero device ops; device arrays fall back to one
+            # concat + pad program per distinct total
+            from raft_tpu.ops.ivf_scan import ragged_row_probes
 
-        padded_total = -(-total // tile) * tile
-        row_probes = ragged_row_probes(
-            sizes, [s["n_probes"] for s in specs], padded_total)
-        if all(isinstance(b, np.ndarray) for b in blocks):
-            packed = np.zeros((padded_total, index.dim), np.float32)
-            r = 0
-            for b, m in zip(blocks, sizes):
-                packed[r:r + m] = b
-                r += m
-        else:
-            from raft_tpu.neighbors._batching import pad_rows
+            padded_total = -(-total // tile) * tile
+            row_probes = ragged_row_probes(
+                sizes, [s["n_probes"] for s in specs], padded_total)
+            if all(isinstance(b, np.ndarray) for b in blocks):
+                packed = np.zeros((padded_total, index.dim), np.float32)
+                r = 0
+                for b, m in zip(blocks, sizes):
+                    packed[r:r + m] = b
+                    r += m
+            else:
+                from raft_tpu.neighbors._batching import pad_rows
 
-            packed = pad_rows(
-                jnp.concatenate([jnp.asarray(b, jnp.float32)
-                                 for b in blocks]), padded_total)
-        fwp = fw
-        if fw is not None and fw.ndim == 2 and padded_total > total:
-            fwp = self._pad(fw, padded_total, fw.dtype)
+                packed = pad_rows(
+                    jnp.concatenate([jnp.asarray(b, jnp.float32)
+                                     for b in blocks]), padded_total)
+                _count_eager(2 if padded_total > total else 1)
+            fwp = fw
+            if fw is not None and fw.ndim == 2 and padded_total > total:
+                fwp = self._pad(fw, padded_total, fw.dtype)
 
-        # pad-waste attribution: the aggregate serving.execute.rows /
-        # .padded_rows counters (bumped per dispatch in the locked
-        # core) additionally split per (params class, tile) here, so
-        # metrics.derived()["pad_waste_by_class"] and the exporter's
-        # labeled family attribute waste to the small-vs-large tile
-        # choice. Class labels are pow2-bounded, tiles ≤ 2 — the
-        # counter-name cardinality is structural, not client-driven.
-        split = (f"p{spec['np_class']}.t{tile}")
-        parts_d, parts_i, raw = [], [], []
-        with self._lock:
-            for start in range(0, padded_total, tile):
-                q_real = min(total - start, tile)
-                qt, rpt = self._place_ragged_chunk(
-                    plan, packed[start:start + tile],
-                    row_probes[start:start + tile])
-                args = [qt, rpt]
-                args.extend(plan.post)
-                if plan.use_filter:
-                    fwt = fwp
-                    if fwp is not None and fwp.ndim == 2:
-                        fwt = fwp[start:start + tile]
-                    args.append(fwt)
-                _, out_d, out_i, _ = self._execute_entry_locked(
-                    plan, tile, k_class, args, q_real)
-                tracing.inc_counters({
-                    f"serving.execute.rows.{split}": q_real,
-                    f"serving.execute.padded_rows.{split}": tile,
-                })
-                if plan.has_state:
-                    # donated-state (xla) engine: the outputs ARE the
-                    # state the next chunk (or the next caller)
-                    # immediately re-donates, so they must be read
-                    # before the lock releases — one batched fetch
-                    # per tile. See the docstring for why the split
-                    # is host-side by design.
-                    # graftlint: disable=R5(ragged split is host-side by design: one batched fetch per packed tile replaces per-shape device-slice micro-programs; the serving caller blocks on results immediately)
-                    host = jax.device_get((out_d, out_i))
-                    parts_d.append(host[0][:q_real])
-                    parts_i.append(host[1][:q_real])
-                else:
-                    # stateless (pallas) engine: nothing aliases the
-                    # outputs, so only ENQUEUE under the lock — every
-                    # tile dispatches before anything is fetched, and
-                    # concurrent searches/scrapes are not blocked for
-                    # a device execution
-                    raw.append((out_d, out_i, q_real))
-        for out_d, out_i, q_real in raw:
-            # graftlint: disable=R5(ragged split is host-side by design: one batched fetch per packed tile replaces per-shape device-slice micro-programs; the serving caller blocks on results immediately)
-            host = jax.device_get((out_d, out_i))
-            parts_d.append(host[0][:q_real])
-            parts_i.append(host[1][:q_real])
-        if len(parts_d) == 1:
-            d_all, i_all = parts_d[0], parts_i[0]
-        else:
-            d_all = np.concatenate(parts_d)
-            i_all = np.concatenate(parts_i)
-        out, row = [], 0
-        for m, kj in zip(sizes, ks):
-            # per-request k: a column slice of the class-cap top-k —
-            # the merge is a total order, so the first k_j columns ARE
-            # the solo top-k_j
-            out.append((d_all[row:row + m, :kj],
-                        i_all[row:row + m, :kj]))
-            row += m
-        return out
+            # pad-waste attribution: the aggregate serving.execute.rows /
+            # .padded_rows counters (bumped per dispatch in the locked
+            # core) additionally split per (params class, tile) here, so
+            # metrics.derived()["pad_waste_by_class"] and the exporter's
+            # labeled family attribute waste to the small-vs-large tile
+            # choice. Class labels are pow2-bounded, tiles ≤ 2 — the
+            # counter-name cardinality is structural, not client-driven.
+            split = (f"p{spec['np_class']}.t{tile}")
+            parts_d, parts_i, raw = [], [], []
+            with self._lock:
+                for start in range(0, padded_total, tile):
+                    q_real = min(total - start, tile)
+                    chunk, eager = _take_rows(packed, start, start + tile)
+                    qt, rpt = self._place_ragged_chunk(
+                        plan, chunk, row_probes[start:start + tile])
+                    args = [qt, rpt]
+                    args.extend(plan.post)
+                    if plan.use_filter:
+                        fwt = fwp
+                        if fwp is not None and fwp.ndim == 2:
+                            fwt, n = _take_rows(fwp, start, start + tile)
+                            eager += n
+                        args.append(fwt)
+                    _count_eager(eager)
+                    _, out_d, out_i, _ = self._execute_entry_locked(
+                        plan, tile, k_class, args, q_real,
+                        prepare=prepare)
+                    tracing.inc_counters({
+                        f"serving.execute.rows.{split}": q_real,
+                        f"serving.execute.padded_rows.{split}": tile,
+                    })
+                    if plan.has_state:
+                        # donated-state (xla) engine: the outputs ARE the
+                        # state the next chunk (or the next caller)
+                        # immediately re-donates, so they must be read
+                        # before the lock releases — one batched fetch
+                        # per tile. See the docstring for why the split
+                        # is host-side by design.
+                        with tracing.host_span(SLICE_SPAN, hist=SLICE):
+                            # graftlint: disable=R5(ragged split is host-side by design: one batched fetch per packed tile replaces per-shape device-slice micro-programs; the serving caller blocks on results immediately)
+                            host = jax.device_get((out_d, out_i))
+                            parts_d.append(host[0][:q_real])
+                            parts_i.append(host[1][:q_real])
+                    else:
+                        # stateless (pallas) engine: nothing aliases the
+                        # outputs, so only ENQUEUE under the lock — every
+                        # tile dispatches before anything is fetched, and
+                        # concurrent searches/scrapes are not blocked for
+                        # a device execution
+                        raw.append((out_d, out_i, q_real))
+        # the slice stage: each stateless tile's batched fetch, then
+        # the host-side split (a stateful tile fetched in the loop)
+        with tracing.host_span(SLICE_SPAN, hist=SLICE):
+            for out_d, out_i, q_real in raw:
+                # graftlint: disable=R5(ragged split is host-side by design: one batched fetch per packed tile replaces per-shape device-slice micro-programs; the serving caller blocks on results immediately)
+                host = jax.device_get((out_d, out_i))
+                parts_d.append(host[0][:q_real])
+                parts_i.append(host[1][:q_real])
+            if len(parts_d) == 1:
+                d_all, i_all = parts_d[0], parts_i[0]
+            else:
+                d_all = np.concatenate(parts_d)
+                i_all = np.concatenate(parts_i)
+            out, row = [], 0
+            for m, kj in zip(sizes, ks):
+                # per-request k: a column slice of the class-cap top-k —
+                # the merge is a total order, so the first k_j columns ARE
+                # the solo top-k_j
+                out.append((d_all[row:row + m, :kj],
+                            i_all[row:row + m, :kj]))
+                row += m
+            return out
 
     # the documented non-raggable residue, as stable reason strings —
     # what ragged_fallback_reason returns and the fallback tests pin
@@ -1122,7 +1214,7 @@ class SearchExecutor:
 
         return resolve_filter_words(sample_filter)
 
-    def _run(self, index, queries, k, params, fw, kw,
+    def _run(self, index, blocks, k, params, fw, kw,
              trace_ids: Tuple[int, ...] = ()):
         # grafttier placement race: an epoch swap DONATES the old hot
         # plane / slot maps, and a dispatch that captured the
@@ -1145,7 +1237,7 @@ class SearchExecutor:
         # apply_plan already established.
         for _ in range(4):
             try:
-                return self._run_once(index, queries, k, params, fw,
+                return self._run_once(index, blocks, k, params, fw,
                                       kw, trace_ids=trace_ids)
             except (RuntimeError, ValueError) as e:
                 if "deleted" not in str(e).lower():
@@ -1153,42 +1245,51 @@ class SearchExecutor:
                 tracing.inc_counter(
                     "serving.execute.placement_retries")
         with self._lock:
-            return self._run_once(index, queries, k, params, fw, kw,
+            return self._run_once(index, blocks, k, params, fw, kw,
                                   trace_ids=trace_ids)
 
-    def _run_once(self, index, queries, k, params, fw, kw,
+    def _run_once(self, index, blocks, k, params, fw, kw,
                   trace_ids: Tuple[int, ...] = ()):
-        q = int(np.shape(queries)[0])
-        bucket = self.bucket_for(q)
-        plan = self._plan(index, params, k, bucket, fw, kw)
-        expect(int(np.shape(queries)[1]) == plan.qdim, "query dim mismatch")
-
-        # 2-D query-sharded plans round the padded block up to the
-        # grid extent (plan.rows); every other plan pads to the bucket
-        rows = plan.rows or bucket
-        qp = self._pad(queries, rows, plan.qdtype)
-        if plan.qsharding is not None:
-            qp = jax.device_put(qp, plan.qsharding)
-        args = list(plan.pre) + [qp]
-        args.extend(plan.post)
-        if plan.use_filter:
-            fwp = fw
-            if fw is not None and fw.ndim == 2:
-                fwp = self._pad(fw, rows, fw.dtype)
-            args.append(fwp)
+        """One bucketed dispatch of ``blocks`` (one batch of at most
+        the top bucket's rows): per-block ``(distances, indices)``."""
+        sizes = [int(np.shape(b)[0]) for b in blocks]
         ret = None
-        with self._lock:
-            entry, out_d, out_i, t0 = self._execute_entry_locked(
-                plan, rows, k, args, q)
-            if plan.has_state and self.donate:
-                # outputs alias the donated state storage: the result
-                # slice (or, at full bucket, a copy — the un-padded
-                # slice would BE the state arrays) must dispatch
-                # before the lock releases, or a concurrent dispatch
-                # of the same plan could re-donate the buffers first
-                ret = ((jnp.copy(out_d), jnp.copy(out_i))
-                       if q == rows
-                       else (out_d[:q], out_i[:q]))
+        # the prepare stage ends inside the locked core, where the
+        # enqueue begins: waiting for the lock is part of preparing
+        with tracing.host_span(PREPARE_SPAN, hist=PREPARE) as prepare:
+            queries = _concat_blocks(blocks)
+            q = sum(sizes)
+            bucket = self.bucket_for(q)
+            plan = self._plan(index, params, k, bucket, fw, kw)
+            expect(int(np.shape(queries)[1]) == plan.qdim,
+                   "query dim mismatch")
+
+            # 2-D query-sharded plans round the padded block up to the
+            # grid extent (plan.rows); every other plan pads to the
+            # bucket
+            rows = plan.rows or bucket
+            qp = self._pad(queries, rows, plan.qdtype)
+            if plan.qsharding is not None:
+                qp = jax.device_put(qp, plan.qsharding)
+            args = list(plan.pre) + [qp]
+            args.extend(plan.post)
+            if plan.use_filter:
+                fwp = fw
+                if fw is not None and fw.ndim == 2:
+                    fwp = self._pad(fw, rows, fw.dtype)
+                args.append(fwp)
+            with self._lock:
+                entry, out_d, out_i, t0 = self._execute_entry_locked(
+                    plan, rows, k, args, q, prepare=prepare)
+                if plan.has_state and self.donate:
+                    # outputs alias the donated state storage: the
+                    # result slice (or, at full bucket, a copy — the
+                    # un-padded slice would BE the state arrays) must
+                    # dispatch before the lock releases, or a
+                    # concurrent dispatch of the same plan could
+                    # re-donate the buffers first
+                    ret = _slice_outputs(out_d, out_i, q, sizes,
+                                         copy=q == rows)
         # mesh recording AFTER the lock releases: the readiness poll
         # lasts as long as the slowest shard, and holding the executor
         # lock through it would stall OTHER threads — concurrent
@@ -1202,15 +1303,16 @@ class SearchExecutor:
                                        trace_ids)
         if ret is not None:
             return ret
-        return out_d[:q], out_i[:q]
+        return _slice_outputs(out_d, out_i, q, sizes)
 
     def _execute_entry_locked(self, plan: _Plan, rows: int, k: int,
-                              args, q_real: int):
+                              args, q_real: int, prepare=None):
         """Shared locked dispatch core of the bucketed and ragged
         paths: entry fetch/compile, donated top-k state + graftgauge
         probe-plane threading, and the modeled-work counters. The
         caller holds ``self._lock`` (RLock) and has assembled ``args``
-        up to (but not including) the donated state. Returns
+        up to (but not including) the donated state; its open
+        ``prepare`` span ends where the enqueue span begins. Returns
         ``(entry, out_d, out_i, t0)``; with ``plan.has_state`` the
         outputs ARE the next call's donated state — the caller must
         slice or copy them before anything re-donates."""
@@ -1218,6 +1320,7 @@ class SearchExecutor:
         if plan.has_state:
             args = list(args) + list(entry.state)
         kwargs = {}
+        eager = 0
         if plan.probe is not None:
             # graftgauge: thread the per-index donated counter
             # plane + the valid-row count (traced scalar — inert
@@ -1230,6 +1333,7 @@ class SearchExecutor:
             if counts is None:
                 self._evict_dead_probe_planes_locked()
                 counts = jnp.zeros((n_lists,), jnp.int32)
+                eager += 1
                 if csharding is not None:
                     counts = jax.device_put(counts, csharding)
                 self._probe_info[pkey] = {
@@ -1246,11 +1350,15 @@ class SearchExecutor:
                 except TypeError:       # non-weakref-able index
                     pass
             nv = jnp.asarray(q_real, jnp.int32)
+            eager += 1
             if plan.state_sharding is not None:
                 nv = jax.device_put(nv, plan.state_sharding)
             kwargs = {"probe_counts": counts, "n_valid": nv}
-        t0 = time.perf_counter()
-        out = entry.compiled(*args, **kwargs)
+        if prepare is not None:
+            prepare.close()
+        with tracing.host_span(ENQUEUE_SPAN, hist=ENQUEUE):
+            t0 = time.perf_counter()
+            out = entry.compiled(*args, **kwargs)
         if plan.probe is not None:
             out_d, out_i, new_counts = out
             self._probe_state[plan.probe[0]] = new_counts
@@ -1279,6 +1387,7 @@ class SearchExecutor:
             # what the CI snapshot floors check (lifetime ledger)
             amounts["index.probe.dispatches"] = 1.0
             amounts["index.probe.rows"] = float(q_real)
+            amounts[EAGER_PROGRAMS] = float(eager)
         tracing.inc_counters(amounts)
         if self._memwatch is not None:
             # graftledger watermark: a host-only memory_stats read
@@ -1343,7 +1452,9 @@ class SearchExecutor:
 
         arr = jnp.asarray(arr)
         if arr.dtype != dtype:
+            _count_eager(1)
             arr = arr.astype(dtype)
+        _count_eager(int(arr.shape[0] < rows))
         return pad_rows(arr, rows)
 
     def _get_entry(self, plan: _Plan, bucket: int, k: int) -> _Entry:

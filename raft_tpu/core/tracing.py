@@ -33,8 +33,9 @@ PR 6 (graftscope) grows this module into the full observability core:
   bounded, lock-protected ring buffer of host-side stage spans keyed
   by ``trace_id``, doubling as a flight recorder for post-mortems.
   :meth:`SpanRecorder.to_chrome_trace` exports Chrome trace-event JSON
-  so the serving stage spans overlay the ``jax.profiler`` device
-  timeline in Perfetto.
+  on the recording clock (the batcher's); the stage spans that line
+  up with the ``jax.profiler`` device timeline are the profiler
+  annotations :class:`host_span` opens beside them.
 - :class:`Histogram` grew cumulative bucket counts (the Prometheus
   exposition format needs them) and its own lock — ``get_histogram``
   hands out live instances, so unlocked ``observe`` raced concurrent
@@ -363,8 +364,10 @@ class Span:
 
     ``start``/``end`` are seconds in the *recording clock's* domain —
     the serving stack records with its injectable clock, so spans from
-    a manual-clock test are exact virtual timestamps, and spans from
-    production overlay the profiler timeline. Zero-duration spans are
+    a manual-clock test are exact virtual timestamps. That clock is not
+    the profiler's: the copy of a stage span that lines up with the
+    device ops is the ``jax.profiler.TraceAnnotation`` that
+    :class:`host_span` opens beside it. Zero-duration spans are
     instant markers (shed/cancel/reject reasons). ``events`` is a
     tuple of ``(ts, name, attrs)`` marks inside the span."""
 
@@ -825,19 +828,71 @@ def js_divergence(p, q) -> float:
     return 0.5 * kl(pa, m) + 0.5 * kl(qa, m)
 
 
-@contextlib.contextmanager
-def host_span(name: str, *, trace_ids: Tuple[int, ...] = (),
-              attrs: Optional[dict] = None):
-    """Context manager recording a wall-clock host span (build paths,
-    scripts — places with no injectable clock). The serving stack does
-    NOT use this: it records explicit clock-domain timestamps so the
-    manual-clock harness stays deterministic."""
-    t0 = time.perf_counter()
-    try:
-        yield
-    finally:
-        record_span(name, t0, time.perf_counter(),
-                    trace_ids=trace_ids, attrs=attrs)
+class host_span:
+    """One host stage, timed where the work happens::
+
+        with tracing.host_span("serving.assembly", clock=clock.now,
+                               hist=ASSEMBLY, ring=True, trace_ids=ids,
+                               attrs={"rows": n}):
+            ...
+
+    On enter it opens a ``jax.profiler.TraceAnnotation(name)``: while a
+    profiler trace runs, the stage lands on the calling thread's host
+    line on the profiler's own clock, beside the device ops it
+    dispatched (with no trace running, the annotation does nothing).
+    On exit the duration, measured on ``clock`` (the
+    serving batcher passes its injectable clock, so manual-clock runs
+    stay exact; the default is ``time.perf_counter``), is observed into
+    the histogram ``hist`` when one is named, and with ``ring=True``
+    the span is recorded into the process-wide ring on that same clock.
+    A stage that raises observes nothing and records its ring span with
+    a ``failed`` event naming the error.
+
+    :meth:`close` ends the stage early, at a boundary inside the
+    ``with`` block (a later close or exit is then a no-op);
+    ``start``/``end``/``duration`` read the clock's timestamps."""
+
+    __slots__ = ("name", "hist", "ring", "trace_ids", "attrs", "_now",
+                 "_annotation", "start", "end")
+
+    def __init__(self, name: str, *, clock=time.perf_counter,
+                 hist: Optional[str] = None, ring: bool = False,
+                 trace_ids: Tuple[int, ...] = (),
+                 attrs: Optional[dict] = None):
+        self.name, self.hist, self.ring = name, hist, ring
+        self.trace_ids, self.attrs = trace_ids, attrs
+        self._now = clock
+        self._annotation = None
+        self.start = self.end = None
+
+    def __enter__(self) -> "host_span":
+        self._annotation = jax.profiler.TraceAnnotation(self.name)
+        self._annotation.__enter__()
+        self.start = self._now()
+        return self
+
+    def close(self, error: Optional[BaseException] = None) -> None:
+        """End the stage now (idempotent)."""
+        if self.end is not None:
+            return
+        self.end = self._now()
+        self._annotation.__exit__(None, None, None)
+        if error is None and self.hist is not None:
+            observe(self.hist, self.end - self.start)
+        if self.ring:
+            events = () if error is None else (
+                (self.end, "failed", {"error": type(error).__name__}),)
+            record_span(self.name, self.start, self.end,
+                        trace_ids=self.trace_ids, attrs=self.attrs,
+                        events=events)
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self.close(exc)
+        return False
+
+    @property
+    def duration(self) -> float:
+        return self.end - self.start
 
 
 _compile_listener_installed = False

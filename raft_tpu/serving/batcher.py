@@ -436,7 +436,9 @@ class DynamicBatcher:
             if not heads:
                 if self._closing or not block:
                     return None if self._closing else ()
-                self._clock.wait(self._cond, None)
+                with tracing.host_span(metrics.IDLE_SPAN,
+                                       clock=self._clock.now):
+                    self._clock.wait(self._cond, None)
                 continue
             wait = self._effective_max_wait()
             # every group's trigger is evaluated (not only the most
@@ -460,7 +462,9 @@ class DynamicBatcher:
             if not block:
                 return ()
             soonest = min(h.arrival + wait for h in heads)
-            self._clock.wait(self._cond, soonest - now)
+            with tracing.host_span(metrics.HOLD_SPAN, clock=self._clock.now,
+                                   hist=metrics.HOLD):
+                self._clock.wait(self._cond, soonest - now)
 
     def _loop(self) -> None:
         while True:
@@ -478,41 +482,55 @@ class DynamicBatcher:
     def _dispatch(self, key, reqs) -> None:
         """Assemble one micro-batch, execute, split results back.
 
-        Each stage records a span into the flight recorder carrying
-        every member request's ``trace_id`` — pure host-side deque
-        appends in the batcher clock's domain, so the device dispatch
-        sequence (and its zero-recompile guarantee) is untouched."""
-        t0 = self._clock.now()
+        Each stage is a :class:`~raft_tpu.core.tracing.host_span`: a
+        profiler annotation, its latency histogram, and a span into the
+        flight recorder carrying every member request's ``trace_id`` —
+        pure host-side work in the batcher clock's domain, so the
+        device dispatch sequence (and its zero-recompile guarantee) is
+        untouched."""
+        clock = self._clock.now
         ids = tuple(r.trace_id for r in reqs)
-        for r in reqs:
-            metrics.observe_stage(metrics.QUEUE_WAIT, t0 - r.arrival)
-        rep = reqs[0]
-        blocks = [r.queries for r in reqs]
         n_rows = sum(r.rows for r in reqs)
-        # requests carry RESOLVED filter words (see submit): 1-D words
-        # are shared by coalesce-key construction, 2-D (per-row) words
-        # concatenate to match the concatenated query rows
-        fw = rep.sample_filter
-        if fw is not None and fw.ndim == 2 and len(reqs) > 1:
-            parts = [r.sample_filter for r in reqs]
-            if all(isinstance(p, np.ndarray) for p in parts):
-                fw = np.concatenate(parts)
-            else:
-                fw = jnp.concatenate([jnp.asarray(p) for p in parts])
-        t1 = self._clock.now()
-        metrics.observe_stage(metrics.ASSEMBLY, t1 - t0)
-        tracing.record_span("serving.assembly", t0, t1, trace_ids=ids,
-                            attrs={"requests": len(reqs), "rows": n_rows})
+        with tracing.host_span("serving.assembly", clock=clock,
+                               hist=metrics.ASSEMBLY, ring=True,
+                               trace_ids=ids,
+                               attrs={"requests": len(reqs),
+                                      "rows": n_rows}) as assembly:
+            for r in reqs:
+                metrics.observe_stage(metrics.QUEUE_WAIT,
+                                      assembly.start - r.arrival)
+            rep = reqs[0]
+            blocks = [r.queries for r in reqs]
+            # requests carry RESOLVED filter words (see submit): 1-D
+            # words are shared by coalesce-key construction, 2-D
+            # (per-row) words concatenate to match the concatenated
+            # query rows
+            fw = rep.sample_filter
+            if fw is not None and fw.ndim == 2 and len(reqs) > 1:
+                parts = [r.sample_filter for r in reqs]
+                if all(isinstance(p, np.ndarray) for p in parts):
+                    fw = np.concatenate(parts)
+                else:
+                    fw = jnp.concatenate([jnp.asarray(p) for p in parts])
+        execute = tracing.host_span(
+            "serving.execute", clock=clock, hist=metrics.EXECUTE,
+            ring=True, trace_ids=ids,
+            attrs={"requests": len(reqs), "rows": n_rows})
         try:
-            # trace_ids ride into the executor so mesh dispatches (and
-            # their per-shard straggler spans) attribute back to the
-            # member requests — graftscope v2's mesh-deep propagation
-            results = self.executor.search_blocks(
-                rep.index, blocks, rep.k, params=rep.params,
-                sample_filter=fw, trace_ids=ids, **rep.kw)
-            results = jax.block_until_ready(results)
+            with execute:
+                # trace_ids ride into the executor so mesh dispatches
+                # (and their per-shard straggler spans) attribute back
+                # to the member requests — graftscope v2's mesh-deep
+                # propagation
+                results = self.executor.search_blocks(
+                    rep.index, blocks, rep.k, params=rep.params,
+                    sample_filter=fw, trace_ids=ids, **rep.kw)
+                with tracing.host_span(metrics.DEVICE_WAIT_SPAN,
+                                       clock=clock,
+                                       hist=metrics.DEVICE_WAIT):
+                    results = jax.block_until_ready(results)
         except Exception as e:  # noqa: BLE001 — fail the handles, not the worker
-            t_fail = self._clock.now()
+            t_fail = execute.end
             for r in reqs:
                 performed = r.handle._set_exception(e)
                 # a failed deadline-carrying request is an SLO miss: a
@@ -524,30 +542,22 @@ class DynamicBatcher:
                 if performed and self._slo is not None \
                         and r.deadline is not None:
                     self._slo.record(t_fail, False)
+            # the execute span recorded itself with a "failed" event
             tracing.inc_counter("serving.batcher.failed_batches")
-            tracing.record_span(
-                "serving.execute", t1, t_fail, trace_ids=ids,
-                attrs={"requests": len(reqs), "rows": n_rows},
-                events=((t_fail, "failed",
-                         {"error": type(e).__name__}),))
             return
-        t2 = self._clock.now()
-        metrics.observe_stage(metrics.EXECUTE, t2 - t1)
         # per-params-class latency (graftflight satellite): the class
         # label pairs this histogram with the params-sweep recall
         # gauges (index.recall.sweep.p<NP>) — a coalesced batch shares
         # one params object, so one observation covers the batch
         cls = metrics.params_class(rep.params)
         if cls is not None:
-            metrics.observe_execute_class(cls, t2 - t1)
-        tracing.record_span("serving.execute", t1, t2, trace_ids=ids,
-                            attrs={"requests": len(reqs), "rows": n_rows})
-        delivered = [r.handle._set_result(d, i)
-                     for r, (d, i) in zip(reqs, results)]
-        t3 = self._clock.now()
-        metrics.observe_stage(metrics.SPLIT, t3 - t2)
-        tracing.record_span("serving.split", t2, t3, trace_ids=ids,
-                            attrs={"requests": len(reqs)})
+            metrics.observe_execute_class(cls, execute.duration)
+        with tracing.host_span("serving.split", clock=clock,
+                               hist=metrics.SPLIT, ring=True, trace_ids=ids,
+                               attrs={"requests": len(reqs)}) as split:
+            delivered = [r.handle._set_result(d, i)
+                         for r, (d, i) in zip(reqs, results)]
+        t3 = split.end
         for r, ok in zip(reqs, delivered):
             metrics.observe_stage(metrics.E2E, t3 - r.arrival)
             tracing.record_span("serving.request", r.arrival, t3,
@@ -572,81 +582,81 @@ class DynamicBatcher:
         (result, SLO outcome, ``serving.request`` span) happens exactly
         once, when the last slice arrives. Stage spans mirror the
         bucketed dispatch, with the packing described in attrs."""
-        t0 = self._clock.now()
+        clock = self._clock.now
         ids = tuple(dict.fromkeys(r.trace_id for r, _, _ in slices))
         n_rows = sum(stop - start for _, start, stop in slices)
-        blocks, ks, params_list = [], [], []
-        fw2 = []
-        rep = slices[0][0]
-        for r, start, stop in slices:
-            if start == 0:
-                metrics.observe_stage(metrics.QUEUE_WAIT,
-                                      t0 - r.arrival)
-            blocks.append(r.queries[start:stop])
-            ks.append(r.k)
-            params_list.append(r.params)
-            if r.sample_filter is not None and r.sample_filter.ndim == 2:
-                fw2.append(r.sample_filter[start:stop])
-        # 1-D filter words are shared by packing-key construction (the
-        # words' identity joins the key); 2-D per-row words concatenate
-        # to the packed rows
-        fw = rep.sample_filter
-        if fw2:
-            if all(isinstance(p, np.ndarray) for p in fw2):
-                fw = np.concatenate(fw2)
-            else:
-                fw = jnp.concatenate([jnp.asarray(p) for p in fw2])
-        t1 = self._clock.now()
-        metrics.observe_stage(metrics.ASSEMBLY, t1 - t0)
-        tracing.record_span(
-            "serving.assembly", t0, t1, trace_ids=ids,
-            attrs={"requests": len(ids), "slices": len(slices),
-                   "rows": n_rows, "ragged": True})
+        with tracing.host_span(
+                "serving.assembly", clock=clock, hist=metrics.ASSEMBLY,
+                ring=True, trace_ids=ids,
+                attrs={"requests": len(ids), "slices": len(slices),
+                       "rows": n_rows, "ragged": True}) as assembly:
+            blocks, ks, params_list = [], [], []
+            fw2 = []
+            rep = slices[0][0]
+            for r, start, stop in slices:
+                if start == 0:
+                    metrics.observe_stage(metrics.QUEUE_WAIT,
+                                          assembly.start - r.arrival)
+                blocks.append(r.queries[start:stop])
+                ks.append(r.k)
+                params_list.append(r.params)
+                if (r.sample_filter is not None
+                        and r.sample_filter.ndim == 2):
+                    fw2.append(r.sample_filter[start:stop])
+            # 1-D filter words are shared by packing-key construction
+            # (the words' identity joins the key); 2-D per-row words
+            # concatenate to the packed rows
+            fw = rep.sample_filter
+            if fw2:
+                if all(isinstance(p, np.ndarray) for p in fw2):
+                    fw = np.concatenate(fw2)
+                else:
+                    fw = jnp.concatenate([jnp.asarray(p) for p in fw2])
+        execute = tracing.host_span(
+            "serving.execute", clock=clock, hist=metrics.EXECUTE,
+            ring=True, trace_ids=ids,
+            attrs={"requests": len(ids), "rows": n_rows, "ragged": True})
         try:
-            results = self.executor.search_ragged(
-                rep.index, blocks, ks, params_list=params_list,
-                sample_filter=fw, trace_ids=ids, **rep.kw)
-            results = jax.block_until_ready(results)
+            with execute:
+                results = self.executor.search_ragged(
+                    rep.index, blocks, ks, params_list=params_list,
+                    sample_filter=fw, trace_ids=ids, **rep.kw)
+                with tracing.host_span(metrics.DEVICE_WAIT_SPAN,
+                                       clock=clock,
+                                       hist=metrics.DEVICE_WAIT):
+                    results = jax.block_until_ready(results)
         except Exception as e:  # noqa: BLE001 — fail the handles, not the worker
-            t_fail = self._clock.now()
+            t_fail = execute.end
             for r in {id(r): r for r, _, _ in slices}.values():
                 performed = r.handle._set_exception(e)
                 if performed and self._slo is not None \
                         and r.deadline is not None:
                     self._slo.record(t_fail, False)
+            # the execute span recorded itself with a "failed" event
             tracing.inc_counter("serving.batcher.failed_batches")
-            tracing.record_span(
-                "serving.execute", t1, t_fail, trace_ids=ids,
-                attrs={"requests": len(ids), "rows": n_rows,
-                       "ragged": True},
-                events=((t_fail, "failed",
-                         {"error": type(e).__name__}),))
             return
-        t2 = self._clock.now()
-        metrics.observe_stage(metrics.EXECUTE, t2 - t1)
         # ragged tiles pack MIXED n_probes under one class: the shared
         # execute latency lands once in each distinct class present,
         # so every sweep operating point keeps a latency axis
         for cls in dict.fromkeys(
                 metrics.params_class(p) for p in params_list):
             if cls is not None:
-                metrics.observe_execute_class(cls, t2 - t1)
-        tracing.record_span("serving.execute", t1, t2, trace_ids=ids,
-                            attrs={"requests": len(ids), "rows": n_rows,
-                                   "ragged": True})
+                metrics.observe_execute_class(cls, execute.duration)
         finished = []
-        for (r, start, stop), (d, i) in zip(slices, results):
-            if start == 0 and stop == r.rows:
-                finished.append((r, d, i))       # unsplit fast path
-            elif r.add_part(start, d, i):
-                fd, fi = r.assemble()
-                finished.append((r, fd, fi))
-        delivered = [(r, r.handle._set_result(d, i))
-                     for r, d, i in finished]
-        t3 = self._clock.now()
-        metrics.observe_stage(metrics.SPLIT, t3 - t2)
-        tracing.record_span("serving.split", t2, t3, trace_ids=ids,
-                            attrs={"requests": len(finished)})
+        split = tracing.host_span("serving.split", clock=clock,
+                                  hist=metrics.SPLIT, ring=True,
+                                  trace_ids=ids)
+        with split:
+            for (r, start, stop), (d, i) in zip(slices, results):
+                if start == 0 and stop == r.rows:
+                    finished.append((r, d, i))       # unsplit fast path
+                elif r.add_part(start, d, i):
+                    fd, fi = r.assemble()
+                    finished.append((r, fd, fi))
+            delivered = [(r, r.handle._set_result(d, i))
+                         for r, d, i in finished]
+            split.attrs = {"requests": len(finished)}
+        t3 = split.end
         for r, ok in delivered:
             metrics.observe_stage(metrics.E2E, t3 - r.arrival)
             tracing.record_span("serving.request", r.arrival, t3,

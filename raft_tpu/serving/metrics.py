@@ -8,6 +8,10 @@ Per-stage latency **histograms** (log2 buckets, p50/p95/p99 estimates):
 - ``serving.batcher.execute_seconds``      — device execute (blocked)
 - ``serving.batcher.split_seconds``        — result re-split + handle set
 - ``serving.batcher.e2e_seconds``          — admission → handle complete
+- ``serving.batcher.hold_seconds``         — timer holding a queued group
+- ``serving.batcher.device_wait_seconds``  — worker blocked on the device
+- ``serving.executor.{prepare,enqueue,slice}_seconds`` — the executor's
+  host stages inside execute (named in ``core/executor.py``)
 
 **Counters** (throughput / shed / occupancy):
 
@@ -20,6 +24,8 @@ Per-stage latency **histograms** (log2 buckets, p50/p95/p99 estimates):
 - ``serving.execute.calls`` / ``.rows`` /
   ``.modeled_flops`` / ``.modeled_bytes``         — executor dispatches
   priced by each executable's compile-time ``cost_analysis()``
+- ``serving.execute.eager_programs``              — eager device
+  programs launched beside the compiled executable
 - ``serving.execute.padded_rows``                 — dispatched row
   capacity incl. bucket/tile pad; with ``.rows`` it derives the
   pad-waste fraction the ragged-vs-bucketed A/B gates on
@@ -158,6 +164,16 @@ import threading
 from typing import Optional
 
 from raft_tpu.core import profiling, tracing
+from raft_tpu.core.executor import (  # noqa: F401 — re-exported
+    EAGER_PROGRAMS,
+    ENQUEUE,
+    ENQUEUE_SPAN,
+    PREPARE,
+    PREPARE_SPAN,
+    SLICE,
+    SLICE_SPAN,
+    STAGE_PREFIX,
+)
 
 PREFIX = "serving.batcher."
 
@@ -166,6 +182,19 @@ ASSEMBLY = PREFIX + "assembly_seconds"
 EXECUTE = PREFIX + "execute_seconds"
 SPLIT = PREFIX + "split_seconds"
 E2E = PREFIX + "e2e_seconds"
+
+# host stages of the served path, each a span (the profiler annotation
+# and, where a histogram is named, the stage's latency). The batcher's
+# own: the timer half of the dual trigger holding a queued group for
+# company, the untimed wait on an empty queue (profiler only), and the
+# worker blocking on the device (the last of the four stages inside
+# EXECUTE). The executor's three stages and its eager-program counter
+# are named where they are recorded (core/executor.py).
+HOLD_SPAN = PREFIX + "hold"
+HOLD = PREFIX + "hold_seconds"
+IDLE_SPAN = PREFIX + "idle"
+DEVICE_WAIT_SPAN = PREFIX + "device_wait"
+DEVICE_WAIT = PREFIX + "device_wait_seconds"
 
 SLO_ATTAINED = "serving.slo.attained"
 SLO_MISSED = "serving.slo.missed"
@@ -546,6 +575,7 @@ def reset() -> None:
     tracing.reset_counters("memory.")
     tracing.reset_gauges("memory.")
     tracing.reset_histograms(PREFIX)
+    tracing.reset_histograms(STAGE_PREFIX)
     # the class-label cap tracks the histograms it guards
     with _execute_classes_lock:
         _execute_classes.clear()

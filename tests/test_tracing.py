@@ -228,15 +228,39 @@ class TestSpanRecorder:
             tracing.reset_spans()
 
     def test_host_span_context_manager(self):
+        """The stage helper: on the caller's clock, its duration goes
+        into the named histogram and (asked) the ring; a stage that
+        raises observes nothing and records a ``failed`` event; an
+        early ``close`` ends the stage and the exit is a no-op."""
         tracing.reset_spans()
+        tracing.reset_histograms("test.stage")
+        now = iter([1.0, 3.5, 10.0, 12.0, 20.0, 21.0]).__next__
         try:
-            with tracing.host_span("build.extend", attrs={"n": 3}):
+            with tracing.host_span("build.extend", clock=now,
+                                   hist="test.stage_seconds", ring=True,
+                                   trace_ids=(4,), attrs={"n": 3}) as sp:
                 pass
+            assert (sp.start, sp.end, sp.duration) == (1.0, 3.5, 2.5)
             (s,) = tracing.span_recorder().spans(name="build.extend")
-            assert s.end >= s.start
-            assert s.attrs == {"n": 3}
+            assert (s.start, s.end, s.trace_ids) == (1.0, 3.5, (4,))
+            assert s.attrs == {"n": 3} and s.events == ()
+            with pytest.raises(KeyError):
+                with tracing.host_span("build.fail", clock=now,
+                                       hist="test.stage_seconds",
+                                       ring=True):
+                    raise KeyError("x")
+            (f,) = tracing.span_recorder().spans(name="build.fail")
+            assert f.events == ((12.0, "failed", {"error": "KeyError"}),)
+            with tracing.host_span("build.early", clock=now,
+                                   hist="test.stage_seconds") as sp:
+                sp.close()
+            assert sp.duration == 1.0
+            h = tracing.histograms("test.stage")["test.stage_seconds"]
+            assert (h["count"], h["sum"]) == (2, 3.5)
+            assert not tracing.span_recorder().spans(name="build.early")
         finally:
             tracing.reset_spans()
+            tracing.reset_histograms("test.stage")
 
 
 class TestStragglerDetector:

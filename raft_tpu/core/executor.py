@@ -89,16 +89,20 @@ forking the params-class ladder: the tile is selected per dispatch
 by packed-row count and never joins :meth:`~SearchExecutor
 .ragged_key`.
 
-Small print: padding/slicing a batch to/from its bucket executes tiny
-device ops whose programs XLA caches per distinct batch size — the
-*search* program itself never recompiles, and once a batch size has
-been seen, repeats are entirely compile-free. (The ragged path has no
-such per-shape micro-programs at all: packing is host-side numpy in,
-one batched fetch out.) Each such program is counted in
-``serving.execute.eager_programs``, and every dispatch's host stages
-(prepare, enqueue, slice) are :class:`~raft_tpu.core.tracing.host_span`
-spans: profiler annotations beside the device ops, and latency
-histograms.
+Small print: the serving entries (:meth:`SearchExecutor.search_blocks`,
+:meth:`~SearchExecutor.search_ragged`) run no per-shape micro-programs:
+host (numpy) blocks are padded or packed in numpy, and each call's
+outputs come back in one batched fetch and are cut per request in
+numpy, so their results are host arrays. The library entry
+:meth:`~SearchExecutor.search` returns device arrays; cutting a batch
+short of its bucket to its rows (or padding a device batch) executes
+tiny device ops whose programs XLA caches per distinct batch size —
+the *search* program itself never recompiles, and once a batch size
+has been seen, repeats are entirely compile-free. Each such program is
+counted in ``serving.execute.eager_programs``, and every dispatch's
+host stages (prepare, enqueue, device wait, slice) are
+:class:`~raft_tpu.core.tracing.host_span` spans: profiler annotations
+beside the device ops, and latency histograms.
 """
 
 from __future__ import annotations
@@ -123,9 +127,12 @@ from raft_tpu.core.validation import expect
 # The host stages of one dispatch, back to back: prepare (block concat,
 # bucket, plan, pad, placement, the wait for the lock, up to the
 # enqueue), enqueue (the AOT executable's call, argument transfer
-# included) and slice (the bucket's output cut into each block's
-# result). Each is a span of that name and a latency histogram;
-# serving.metrics re-exports them beside the batcher's stages.
+# included), the device wait (blocking until the outputs are computed)
+# and slice (the outputs fetched to the host and cut into each block's
+# result; search()'s cut on the device). Each is a span of that name
+# and a latency histogram; serving.metrics re-exports them beside the
+# batcher's stages. The device wait keeps its serving.batcher. name:
+# the benchmark's readers and recorded traces key on it.
 STAGE_PREFIX = "serving.executor."
 PREPARE_SPAN = STAGE_PREFIX + "prepare"
 PREPARE = STAGE_PREFIX + "prepare_seconds"
@@ -133,6 +140,8 @@ ENQUEUE_SPAN = STAGE_PREFIX + "enqueue"
 ENQUEUE = STAGE_PREFIX + "enqueue_seconds"
 SLICE_SPAN = STAGE_PREFIX + "slice"
 SLICE = STAGE_PREFIX + "slice_seconds"
+DEVICE_WAIT_SPAN = "serving.batcher.device_wait"
+DEVICE_WAIT = "serving.batcher.device_wait_seconds"
 # eager device programs a dispatch launches beside its compiled
 # executable: partial slices and copies of device arrays, device-side
 # concatenations, pads and casts, jnp.asarray of host values (implicit
@@ -164,33 +173,51 @@ def _concat_blocks(blocks):
     return jnp.concatenate([jnp.asarray(b) for b in blocks])
 
 
-def _split_rows(d, i, sizes):
-    """A batch's ``(d, i)`` results cut into consecutive per-block row
-    ranges of ``sizes``."""
-    out, start, n = [], 0, 0
-    for m in sizes:
-        dm, nd = _take_rows(d, start, start + m)
-        im, ni = _take_rows(i, start, start + m)
-        out.append((dm, im))
-        n += nd + ni
-        start += m
-    _count_eager(n)
-    return out
+def _search_results(tiles, sizes, ks=None):
+    """A call's per-block ``(distances, indices)`` as host numpy arrays,
+    from its output tiles ``[(d, i, rows), ...]`` in row order, each of
+    whose first ``rows`` rows are real.
+
+    Two stages: the device wait (blocking until every tile is
+    computed; nothing to wait for when all are on the host already),
+    then the slice stage: one batched fetch of every tile, the real
+    rows joined and cut into consecutive blocks of ``sizes`` rows —
+    with ``ks``, each block's first ``ks[j]`` columns (the ragged
+    path's per-request ``k``). The cuts are numpy views, so no device
+    program runs and none compiles per shape."""
+    outs = [(d, i) for d, i, _ in tiles]
+    if not all(isinstance(d, np.ndarray) for d, _ in outs):
+        with tracing.host_span(DEVICE_WAIT_SPAN, hist=DEVICE_WAIT):
+            jax.block_until_ready(outs)
+    with tracing.host_span(SLICE_SPAN, hist=SLICE):
+        # graftlint: disable=R5(the serving split is host-side by design: one batched fetch of a call's output tiles replaces per-request device-slice programs; every serving caller reads its results on the host)
+        host = jax.device_get(outs)
+        parts = [(d[:r], i[:r]) for (d, i), (_, _, r) in zip(host, tiles)]
+        if len(parts) == 1:
+            d_all, i_all = parts[0]
+        else:
+            d_all = np.concatenate([d for d, _ in parts])
+            i_all = np.concatenate([i for _, i in parts])
+        out, row = [], 0
+        for j, m in enumerate(sizes):
+            cols = slice(None if ks is None else ks[j])
+            out.append((d_all[row:row + m, cols], i_all[row:row + m, cols]))
+            row += m
+        return out
 
 
-def _slice_outputs(out_d, out_i, q: int, sizes, copy: bool = False):
-    """The slice stage: a bucket's outputs cut to the ``q`` real rows
-    (``copy``: copied whole instead, for a full bucket whose outputs
-    alias donated state), then into per-block results."""
+def _device_cut(out_d, out_i, q: int, copy: bool = False):
+    """:meth:`SearchExecutor.search`'s slice stage: a bucket's outputs
+    cut on the device to the ``q`` real rows (``copy``: copied whole
+    instead, for a full bucket whose outputs alias donated state) —
+    eager programs, counted."""
     with tracing.host_span(SLICE_SPAN, hist=SLICE):
         if copy:
             _count_eager(2)
-            d, i = jnp.copy(out_d), jnp.copy(out_i)
-        else:
-            (d, nd), (i, ni) = (_take_rows(out_d, 0, q),
-                                _take_rows(out_i, 0, q))
-            _count_eager(nd + ni)
-        return _split_rows(d, i, sizes)
+            return jnp.copy(out_d), jnp.copy(out_i)
+        (d, nd), (i, ni) = _take_rows(out_d, 0, q), _take_rows(out_i, 0, q)
+        _count_eager(nd + ni)
+        return d, i
 
 
 def _fused_entry_fn(queries, dataset, norms, *, k: int, metric):
@@ -592,8 +619,11 @@ class SearchExecutor:
                sample_filter=None, trace_ids: Tuple[int, ...] = (),
                **kw) -> Tuple[jax.Array, jax.Array]:
         """Bucketed, compile-free search. Returns (distances (q, k),
-        indices (q, k) int32), bit-identical to the direct per-family
-        ``search`` entry point. Extra ``kw`` are family-specific knobs
+        indices (q, k) int32) as device arrays, bit-identical to the
+        direct per-family ``search`` entry point: library callers
+        compose them on the device, so the result is not waited for,
+        and a batch short of its bucket pays one cut on the device.
+        Extra ``kw`` are family-specific knobs
         (brute force: ``db_tile``, ``approx``). ``trace_ids`` tags the
         dispatch's flight-recorder spans (mesh plans with
         ``mesh_trace`` on) — the serving batcher passes its members'
@@ -601,7 +631,7 @@ class SearchExecutor:
         expect(len(np.shape(queries)) == 2, "queries must be (q, dim)")
         fw = self._resolve_filter(sample_filter)
         return self._search(index, [queries], k, params, fw, kw,
-                            trace_ids)[0]
+                            trace_ids, host=False)
 
     def coalesce_key(self, index, k: int, params=None, sample_filter=None,
                      **kw) -> tuple:
@@ -626,48 +656,63 @@ class SearchExecutor:
         ``blocks`` is a sequence of (m_j, dim) query arrays that agreed
         on :meth:`coalesce_key`; a 2-D ``sample_filter`` must be the
         row-wise concatenation matching the blocks. Returns a list of
-        per-block ``(distances, indices)`` pairs, each bit-identical to
-        a direct :meth:`search` of that block alone (bucketing pads
-        with inert rows, so coalescing cannot perturb results).
+        per-block ``(distances, indices)`` pairs as HOST (numpy)
+        arrays, each bit-identical to a direct :meth:`search` of that
+        block alone (bucketing pads with inert rows, so coalescing
+        cannot perturb results).
+
+        The serving frontend hands every result to a caller that reads
+        it on the host, so the call waits for the device and fetches
+        the bucket's outputs in one batch, then cuts each block's rows
+        in numpy: no per-shape slice, copy or concatenate program runs
+        beside the executable.
 
         Every family concatenates — CAGRA's seeds became a pure
         function of query content (PR 16), which retired the last
         per-block dispatch special case."""
         expect(len(blocks) > 0, "search_blocks needs at least one block")
         fw = self._resolve_filter(sample_filter)
-        return self._search(index, blocks, k, params, fw, kw, trace_ids)
+        return self._search(index, blocks, k, params, fw, kw, trace_ids,
+                            host=True)
 
-    def _search(self, index, blocks, k, params, fw, kw, trace_ids):
+    def _search(self, index, blocks, k, params, fw, kw, trace_ids, host):
         """The bucketed path of :meth:`search` and :meth:`search_blocks`:
-        the blocks as one batch, per-block ``(distances, indices)``."""
+        the blocks as one batch, tiled at the top bucket. With ``host``,
+        per-block ``(distances, indices)`` on the host; without (one
+        block), its ``(distances, indices)`` on the device."""
         sizes = [int(np.shape(b)[0]) for b in blocks]
         q = sum(sizes)
         if q == 0:
-            return [(jnp.zeros((0, k), jnp.float32),
-                     jnp.zeros((0, k), jnp.int32))] * len(blocks)
-        max_b = self.buckets[-1]
-        if q <= max_b:
-            return self._run(index, blocks, k, params, fw, kw,
-                             trace_ids=trace_ids)
-        # tile oversized batches at the top bucket; every tile runs the
-        # same executable and all tiles dispatch before any fetch
-        queries = _concat_blocks(blocks)
-        outs_d, outs_i = [], []
-        for start in range(0, q, max_b):
-            qt, n = _take_rows(queries, start, start + max_b)
-            fwt = fw
-            if fw is not None and fw.ndim == 2:
-                fwt, n_fw = _take_rows(fw, start, start + max_b)
-                n += n_fw
-            _count_eager(n)
-            ((d, i),) = self._run(index, [qt], k, params, fwt, kw,
-                                  trace_ids=trace_ids)
-            outs_d.append(d)
-            outs_i.append(i)
+            xp = np if host else jnp
+            tiles = [(xp.zeros((0, k), np.float32),
+                      xp.zeros((0, k), np.int32), 0)]
+        elif q <= self.buckets[-1]:
+            tiles = [self._run(index, blocks, k, params, fw, kw,
+                               trace_ids=trace_ids, host=host)]
+        else:
+            # tile oversized batches at the top bucket; every tile runs
+            # the same executable and all tiles dispatch before any
+            # fetch
+            max_b = self.buckets[-1]
+            queries = _concat_blocks(blocks)
+            tiles = []
+            for start in range(0, q, max_b):
+                qt, n = _take_rows(queries, start, start + max_b)
+                fwt = fw
+                if fw is not None and fw.ndim == 2:
+                    fwt, n_fw = _take_rows(fw, start, start + max_b)
+                    n += n_fw
+                _count_eager(n)
+                tiles.append(self._run(index, [qt], k, params, fwt, kw,
+                                       trace_ids=trace_ids, host=host))
+        if host:
+            return _search_results(tiles, sizes)
+        if len(tiles) == 1:
+            return tiles[0][:2]
         with tracing.host_span(SLICE_SPAN, hist=SLICE):
             _count_eager(2)
-            return _split_rows(jnp.concatenate(outs_d),
-                               jnp.concatenate(outs_i), sizes)
+            return (jnp.concatenate([d for d, _, _ in tiles]),
+                    jnp.concatenate([i for _, i, _ in tiles]))
 
     # -- ragged packed-batch plan family ------------------------------------
 
@@ -822,15 +867,16 @@ class SearchExecutor:
         membership-masked probes + total-order merges make the packed
         results independent of what else shares the tile).
 
-        The per-request split is deliberately host-side: one batched
-        device fetch per packed tile replaces per-(offset, rows, k)
-        device slices — whose tiny programs would otherwise compile
-        per load shape, resurrecting through the back door the shape
-        churn the ONE packed executable exists to kill. The serving
-        batcher blocks on results immediately, so the fetch costs what
-        the caller was about to pay anyway; like the bucketed path,
-        every chunk is dispatched before anything else can re-donate
-        its outputs."""
+        The per-request split is deliberately host-side, as in
+        :meth:`search_blocks`: one batched device fetch of the packed
+        tiles replaces per-(offset, rows, k) device slices — whose
+        tiny programs would otherwise compile per load shape,
+        resurrecting through the back door the shape churn the ONE
+        packed executable exists to kill. The serving batcher hands
+        every result to a caller that reads it on the host, so the
+        fetch costs what the caller was about to pay anyway; like the
+        bucketed path, a donated-state tile is fetched before anything
+        else can re-donate its outputs."""
         expect(len(blocks) > 0, "search_ragged needs at least one block")
         # the prepare stage ends inside the locked core, where the
         # first tile's enqueue begins
@@ -913,7 +959,7 @@ class SearchExecutor:
             # choice. Class labels are pow2-bounded, tiles ≤ 2 — the
             # counter-name cardinality is structural, not client-driven.
             split = (f"p{spec['np_class']}.t{tile}")
-            parts_d, parts_i, raw = [], [], []
+            outs = []
             with self._lock:
                 for start in range(0, padded_total, tile):
                     q_real = min(total - start, tile)
@@ -939,44 +985,20 @@ class SearchExecutor:
                     if plan.has_state:
                         # donated-state (xla) engine: the outputs ARE the
                         # state the next chunk (or the next caller)
-                        # immediately re-donates, so they must be read
-                        # before the lock releases — one batched fetch
-                        # per tile. See the docstring for why the split
-                        # is host-side by design.
-                        with tracing.host_span(SLICE_SPAN, hist=SLICE):
-                            # graftlint: disable=R5(ragged split is host-side by design: one batched fetch per packed tile replaces per-shape device-slice micro-programs; the serving caller blocks on results immediately)
-                            host = jax.device_get((out_d, out_i))
-                            parts_d.append(host[0][:q_real])
-                            parts_i.append(host[1][:q_real])
-                    else:
-                        # stateless (pallas) engine: nothing aliases the
-                        # outputs, so only ENQUEUE under the lock — every
-                        # tile dispatches before anything is fetched, and
-                        # concurrent searches/scrapes are not blocked for
-                        # a device execution
-                        raw.append((out_d, out_i, q_real))
-        # the slice stage: each stateless tile's batched fetch, then
-        # the host-side split (a stateful tile fetched in the loop)
-        with tracing.host_span(SLICE_SPAN, hist=SLICE):
-            for out_d, out_i, q_real in raw:
-                # graftlint: disable=R5(ragged split is host-side by design: one batched fetch per packed tile replaces per-shape device-slice micro-programs; the serving caller blocks on results immediately)
-                host = jax.device_get((out_d, out_i))
-                parts_d.append(host[0][:q_real])
-                parts_i.append(host[1][:q_real])
-            if len(parts_d) == 1:
-                d_all, i_all = parts_d[0], parts_i[0]
-            else:
-                d_all = np.concatenate(parts_d)
-                i_all = np.concatenate(parts_i)
-            out, row = [], 0
-            for m, kj in zip(sizes, ks):
-                # per-request k: a column slice of the class-cap top-k —
-                # the merge is a total order, so the first k_j columns ARE
-                # the solo top-k_j
-                out.append((d_all[row:row + m, :kj],
-                            i_all[row:row + m, :kj]))
-                row += m
-            return out
+                        # immediately re-donates, so they are fetched
+                        # before the lock releases
+                        ((out_d, out_i),) = _search_results(
+                            [(out_d, out_i, q_real)], [q_real])
+                    # a stateless (pallas) engine's outputs alias
+                    # nothing, so only ENQUEUE happens under the lock —
+                    # every tile dispatches before anything is fetched,
+                    # and concurrent searches/scrapes are not blocked
+                    # for a device execution
+                    outs.append((out_d, out_i, q_real))
+        # per-request k: a column slice of the class-cap top-k — the
+        # merge is a total order, so the first k_j columns ARE the solo
+        # top-k_j
+        return _search_results(outs, sizes, ks)
 
     # the documented non-raggable residue, as stable reason strings —
     # what ragged_fallback_reason returns and the fallback tests pin
@@ -1215,7 +1237,7 @@ class SearchExecutor:
         return resolve_filter_words(sample_filter)
 
     def _run(self, index, blocks, k, params, fw, kw,
-             trace_ids: Tuple[int, ...] = ()):
+             trace_ids: Tuple[int, ...] = (), host: bool = False):
         # grafttier placement race: an epoch swap DONATES the old hot
         # plane / slot maps, and a dispatch that captured the
         # pre-swap generation but enqueued after the swap finds its
@@ -1238,7 +1260,7 @@ class SearchExecutor:
         for _ in range(4):
             try:
                 return self._run_once(index, blocks, k, params, fw,
-                                      kw, trace_ids=trace_ids)
+                                      kw, trace_ids=trace_ids, host=host)
             except (RuntimeError, ValueError) as e:
                 if "deleted" not in str(e).lower():
                     raise
@@ -1246,19 +1268,23 @@ class SearchExecutor:
                     "serving.execute.placement_retries")
         with self._lock:
             return self._run_once(index, blocks, k, params, fw, kw,
-                                  trace_ids=trace_ids)
+                                  trace_ids=trace_ids, host=host)
 
     def _run_once(self, index, blocks, k, params, fw, kw,
-                  trace_ids: Tuple[int, ...] = ()):
+                  trace_ids: Tuple[int, ...] = (), host: bool = False):
         """One bucketed dispatch of ``blocks`` (one batch of at most
-        the top bucket's rows): per-block ``(distances, indices)``."""
-        sizes = [int(np.shape(b)[0]) for b in blocks]
+        the top bucket's rows): the output tile ``(distances, indices,
+        q)``, whose first ``q`` rows are the blocks' results. With
+        ``host``, outputs that alias donated state come back fetched
+        to the host and others as the device arrays, possibly still
+        computing (:func:`_search_results` reads them); without, both
+        come back cut on the device to the ``q`` rows."""
         ret = None
         # the prepare stage ends inside the locked core, where the
         # enqueue begins: waiting for the lock is part of preparing
         with tracing.host_span(PREPARE_SPAN, hist=PREPARE) as prepare:
             queries = _concat_blocks(blocks)
-            q = sum(sizes)
+            q = int(np.shape(queries)[0])
             bucket = self.bucket_for(q)
             plan = self._plan(index, params, k, bucket, fw, kw)
             expect(int(np.shape(queries)[1]) == plan.qdim,
@@ -1283,13 +1309,18 @@ class SearchExecutor:
                     plan, rows, k, args, q, prepare=prepare)
                 if plan.has_state and self.donate:
                     # outputs alias the donated state storage: the
-                    # result slice (or, at full bucket, a copy — the
-                    # un-padded slice would BE the state arrays) must
-                    # dispatch before the lock releases, or a
-                    # concurrent dispatch of the same plan could
-                    # re-donate the buffers first
-                    ret = _slice_outputs(out_d, out_i, q, sizes,
-                                         copy=q == rows)
+                    # fetch (or, on the device, the result slice or at
+                    # full bucket a copy — the un-padded slice would BE
+                    # the state arrays) must happen before the lock
+                    # releases, or a concurrent dispatch of the same
+                    # plan could re-donate the buffers first
+                    if host:
+                        ((d, i),) = _search_results(
+                            [(out_d, out_i, q)], [q])
+                    else:
+                        d, i = _device_cut(out_d, out_i, q,
+                                           copy=q == rows)
+                    ret = (d, i, q)
         # mesh recording AFTER the lock releases: the readiness poll
         # lasts as long as the slowest shard, and holding the executor
         # lock through it would stall OTHER threads — concurrent
@@ -1303,7 +1334,9 @@ class SearchExecutor:
                                        trace_ids)
         if ret is not None:
             return ret
-        return _slice_outputs(out_d, out_i, q, sizes)
+        if host:
+            return out_d, out_i, q
+        return _device_cut(out_d, out_i, q) + (q,)
 
     def _execute_entry_locked(self, plan: _Plan, rows: int, k: int,
                               args, q_real: int, prepare=None):
@@ -1315,7 +1348,7 @@ class SearchExecutor:
         ``prepare`` span ends where the enqueue span begins. Returns
         ``(entry, out_d, out_i, t0)``; with ``plan.has_state`` the
         outputs ARE the next call's donated state — the caller must
-        slice or copy them before anything re-donates."""
+        fetch, slice or copy them before anything re-donates."""
         entry = self._get_entry_locked(plan, rows, k)
         if plan.has_state:
             args = list(args) + list(entry.state)

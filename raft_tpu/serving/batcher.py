@@ -58,7 +58,6 @@ import threading
 import time
 from typing import Optional
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -521,14 +520,11 @@ class DynamicBatcher:
                 # trace_ids ride into the executor so mesh dispatches
                 # (and their per-shard straggler spans) attribute back
                 # to the member requests — graftscope v2's mesh-deep
-                # propagation
+                # propagation. The results come back on the host: the
+                # executor waits for the device and fetches them
                 results = self.executor.search_blocks(
                     rep.index, blocks, rep.k, params=rep.params,
                     sample_filter=fw, trace_ids=ids, **rep.kw)
-                with tracing.host_span(metrics.DEVICE_WAIT_SPAN,
-                                       clock=clock,
-                                       hist=metrics.DEVICE_WAIT):
-                    results = jax.block_until_ready(results)
         except Exception as e:  # noqa: BLE001 — fail the handles, not the worker
             t_fail = execute.end
             for r in reqs:
@@ -621,10 +617,6 @@ class DynamicBatcher:
                 results = self.executor.search_ragged(
                     rep.index, blocks, ks, params_list=params_list,
                     sample_filter=fw, trace_ids=ids, **rep.kw)
-                with tracing.host_span(metrics.DEVICE_WAIT_SPAN,
-                                       clock=clock,
-                                       hist=metrics.DEVICE_WAIT):
-                    results = jax.block_until_ready(results)
         except Exception as e:  # noqa: BLE001 — fail the handles, not the worker
             t_fail = execute.end
             for r in {id(r): r for r, _, _ in slices}.values():

@@ -9,7 +9,8 @@ Per-stage latency **histograms** (log2 buckets, p50/p95/p99 estimates):
 - ``serving.batcher.split_seconds``        — result re-split + handle set
 - ``serving.batcher.e2e_seconds``          — admission → handle complete
 - ``serving.batcher.hold_seconds``         — timer holding a queued group
-- ``serving.batcher.device_wait_seconds``  — worker blocked on the device
+- ``serving.batcher.device_wait_seconds``  — worker blocked on the
+  device, inside the executor (named in ``core/executor.py``)
 - ``serving.executor.{prepare,enqueue,slice}_seconds`` — the executor's
   host stages inside execute (named in ``core/executor.py``)
 
@@ -165,6 +166,8 @@ from typing import Optional
 
 from raft_tpu.core import profiling, tracing
 from raft_tpu.core.executor import (  # noqa: F401 — re-exported
+    DEVICE_WAIT,
+    DEVICE_WAIT_SPAN,
     EAGER_PROGRAMS,
     ENQUEUE,
     ENQUEUE_SPAN,
@@ -186,15 +189,13 @@ E2E = PREFIX + "e2e_seconds"
 # host stages of the served path, each a span (the profiler annotation
 # and, where a histogram is named, the stage's latency). The batcher's
 # own: the timer half of the dual trigger holding a queued group for
-# company, the untimed wait on an empty queue (profiler only), and the
-# worker blocking on the device (the last of the four stages inside
-# EXECUTE). The executor's three stages and its eager-program counter
-# are named where they are recorded (core/executor.py).
+# company, and the untimed wait on an empty queue (profiler only). The
+# four stages inside EXECUTE (prepare, enqueue, the device wait, slice)
+# and the eager-program counter are named where they are recorded
+# (core/executor.py).
 HOLD_SPAN = PREFIX + "hold"
 HOLD = PREFIX + "hold_seconds"
 IDLE_SPAN = PREFIX + "idle"
-DEVICE_WAIT_SPAN = PREFIX + "device_wait"
-DEVICE_WAIT = PREFIX + "device_wait_seconds"
 
 SLO_ATTAINED = "serving.slo.attained"
 SLO_MISSED = "serving.slo.missed"

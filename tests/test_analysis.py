@@ -1076,19 +1076,15 @@ class TestRepoWide:
 
     # (path, rule, reason) for every pragma in the tree — KEEP SORTED
     EXPECTED_SUPPRESSIONS = [
-        # PR 9: the ragged split fetches once per packed tile instead
-        # of dispatching per-(offset, rows, k) device slices whose
-        # micro-programs would recompile per load shape
+        # the serving split (bucketed and ragged) fetches a call's
+        # output tiles once, in one shared helper, instead of
+        # dispatching per-request device slices whose micro-programs
+        # would recompile per load shape
         ("raft_tpu/core/executor.py", "R5",
-         "ragged split is host-side by design: one batched fetch per "
-         "packed tile replaces per-shape device-slice micro-programs; "
-         "the serving caller blocks on results immediately"),
-        # second site, same design: the stateless-engine fetch happens
-        # OUTSIDE the executor lock (nothing aliases those outputs)
-        ("raft_tpu/core/executor.py", "R5",
-         "ragged split is host-side by design: one batched fetch per "
-         "packed tile replaces per-shape device-slice micro-programs; "
-         "the serving caller blocks on results immediately"),
+         "the serving split is host-side by design: one batched fetch "
+         "of a call's output tiles replaces per-request device-slice "
+         "programs; every serving caller reads its results on the "
+         "host"),
         ("raft_tpu/distributed/ivf.py", "R5",
          "streaming deal: per-block puts bound build staging to "
          "O(block)"),

@@ -1,6 +1,6 @@
 """The served path's host stages as spans: each stage of a dispatch
-(the batcher's hold, idle and device wait; the executor's prepare,
-enqueue and slice) lands on the profiler's host timeline and in its
+(the batcher's hold and idle; the executor's prepare, enqueue, device
+wait and slice) lands on the profiler's host timeline and in its
 latency histogram, and the executor counts the eager device programs
 a dispatch launches beside its compiled executable."""
 
@@ -147,15 +147,12 @@ class TestHold:
 
 
 class TestEagerPrograms:
-    # (requests of one row each, programs): the bucket's output is cut
-    # to the real rows (a slice of distances and one of ids) unless the
-    # rows fill the bucket (8, 16, 32, ...), and each block's rows are
-    # cut from that (two slices a block) unless one block holds them
-    # all — a whole-array slice launches nothing
-    @pytest.mark.parametrize("requests,programs",
-                             [(1, 2), (10, 2 + 2 * 10), (15, 2 + 2 * 15),
-                              (16, 2 * 16), (17, 2 + 2 * 17)])
-    def test_count_per_dispatch(self, served, requests, programs):
+    # requests of one row each, in buckets 8, 16 and 32, short of the
+    # bucket or filling it: the bucket's outputs come to the host in one
+    # fetch and are cut there, so no dispatch launches a program beside
+    # its executable
+    @pytest.mark.parametrize("requests", [1, 10, 15, 16, 17])
+    def test_count_per_dispatch(self, served, requests):
         index, q, ex = served
         clock = ManualClock()
         b = DynamicBatcher(ex, BatcherConfig(max_wait_s=0.01),
@@ -166,10 +163,26 @@ class TestEagerPrograms:
         clock.advance(0.01)
         assert b.pump() == 1
         for h in handles:
-            h.result(timeout=0)
+            d, i = h.result(timeout=0)
+            assert isinstance(d, np.ndarray) and isinstance(i, np.ndarray)
         b.close()
-        assert tracing.get_counter(metrics.EAGER_PROGRAMS) == programs
+        assert tracing.get_counter(metrics.EAGER_PROGRAMS) == 0
         assert _batches() == 1
+
+    # (rows, programs): the library entry keeps its results on the
+    # device, so a batch short of its bucket (1 row in bucket 8) is cut
+    # there, a slice of distances and one of ids; a full bucket is the
+    # outputs themselves
+    @pytest.mark.parametrize("rows,programs", [(1, 2), (8, 0)])
+    def test_search_keeps_device_arrays(self, served, rows, programs):
+        import jax
+
+        index, q, ex = served
+        metrics.reset()
+        d, i = ex.search(index, q[:rows], K, params=PARAMS)
+        assert isinstance(d, jax.Array) and isinstance(i, jax.Array)
+        assert d.shape == i.shape == (rows, K)
+        assert tracing.get_counter(metrics.EAGER_PROGRAMS) == programs
 
     def test_results_unchanged_by_the_split(self, served):
         index, q, ex = served

@@ -2,14 +2,19 @@
 benchmark's own runs never run it).
 
     python3 benchmark/calibrate.py --workload <cell> --seeds 1 2 ... \
-        --control-seeds 7 8 9 --seconds 3
+        --control-seeds 7 8 9 --seconds 3 [--bench <BENCHMARK.json>]
 
 In one process, so set-up compiles once: the program's numbers
 (:mod:`benchmark.check`) over a short window of the cell's own traffic
-at its own size, for each ``--seeds``; then the control's, for each
-``--control-seeds``: the reference computed in bfloat16
-(:func:`benchmark.reference.control_knn`) put in the program's place,
-its answers judged by the same comparison. With ``--recall-probes``,
+at its own size, for each ``--seeds``; then, for each
+``--control-seeds``, the control's: the configuration's reference one
+step coarser than its data (its ``control``: bfloat16 for float32 data,
+values rounded to even numbers for bytes) put in the program's place,
+its answers judged by the same comparison, beside the reference's own
+answers judged alike (which must read 0 on bytes), with each phase's
+seconds and each chip's peak memory on standard error. ``--bench``
+reads the cells of another ``BENCHMARK.json`` (a rehearsal's), whose
+files lie beside it. With ``--recall-probes``,
 IVF recall@k over whole pools for each probe count (``--recall-seeds``):
 the curve, and the ``miss`` of a search cut to fewer probes, the fault
 ``miss`` has to catch. Prints one JSON line per reading and a summary:
@@ -31,24 +36,38 @@ if ROOT not in sys.path:
 from benchmark import run, spec  # noqa: E402
 
 
-def control_reading(cell, seed: int) -> dict:
-    """The control's numbers on ``seed`` at the cell's size."""
+def control_reading(cell, seed: int, devs) -> dict:
+    """On ``seed`` at the cell's size, over the cell's chips:
+    ``{"control": numbers, "reference": numbers}``, the control's
+    answers and the reference's own, each judged as the program's."""
     import numpy as np
 
-    from benchmark import check, data, reference
+    from benchmark import check, data
 
     ds, p = cell.conf["dataset"], int(cell.traffic["pool"])
-    x, pool = data.for_dataset(ds, seed, p)
+    ref_mod, limits = cell.reference, cell.conf.get("limits", {})
+    phases = run.Phases(devs)
+    corpus, pool = data.for_dataset(ds, seed, p, devs)
+    x, pool = corpus.array, np.asarray(pool)
+    phases.mark("data")
     k = int(ds["k"])
-    ref = reference.exact_knn(x, pool, k)
-    d, i = reference.control_knn(x, pool, k)
-    answers = (np.arange(p), d, i, np.ones(p, bool), 0)
-    out = check.judge(x, np.asarray(pool), ref, answers,
-                      cell.conf.get("limits", {}))
-    return {name: c["value"] for name, c in out["checks"].items()}
+    ref = ref_mod.knn(x, pool, k)
+    phases.mark("reference")
+    ctl = ref_mod.control(x, pool, k)
+    phases.mark("control")
+    out = {}
+    for name, (d, i) in (("reference", (ref[0].astype(np.float32),
+                                        ref[1].astype(np.int32))),
+                         ("control", ctl)):
+        answers = (np.arange(p), d, i, np.ones(p, bool), 0)
+        verdict = check.judge(x, pool, ref, answers, limits,
+                              ref_mod.true_distances)
+        out[name] = {n: c["value"] for n, c in verdict["checks"].items()}
+        phases.mark(f"judge_{name}")
+    return out
 
 
-def recall_curve(cell, seeds, probes) -> dict:
+def recall_curve(cell, seeds, probes, devs) -> dict:
     """Recall@k of the configuration's index over each seed's pool for
     each ``n_probes`` (direct ``search``, the kernels the served path
     runs): ``{seed: {n_probes: recall}}``. One index serves every seed,
@@ -57,7 +76,7 @@ def recall_curve(cell, seeds, probes) -> dict:
 
     import numpy as np
 
-    from benchmark import check, data, reference
+    from benchmark import check, data
 
     ds, p = cell.conf["dataset"], int(cell.traffic["pool"])
     k = int(ds["k"])
@@ -66,11 +85,12 @@ def recall_curve(cell, seeds, probes) -> dict:
 
     index, out = None, {}
     for seed in seeds:
-        x, pool = data.for_dataset(ds, seed, p)
+        corpus, pool = data.for_dataset(ds, seed, p, devs)
+        x = corpus.array
         if index is None:
-            index = cell.family.build(cell.conf, x)
+            index = run.build(cell, corpus, devs)
             print("index", cell.family.describe(index), file=sys.stderr)
-        ref = reference.exact_knn(x, pool, k)
+        ref = cell.reference.knn(x, pool, k)
         out[seed] = {}
         for n in probes:
             d, i = ivf_flat.search(None, dataclasses.replace(base, n_probes=n),
@@ -78,7 +98,8 @@ def recall_curve(cell, seeds, probes) -> dict:
             answers = (np.arange(p), np.asarray(d), np.asarray(i),
                        np.ones(p, bool), 0)
             out[seed][n] = check.judge(x, np.asarray(pool), ref, answers,
-                                       {})["recall"]
+                                       {}, cell.reference.true_distances
+                                       )["recall"]
         print(json.dumps({"recall_curve": {seed: out[seed]}}), flush=True)
     return out
 
@@ -91,10 +112,14 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, default=3.0)
     ap.add_argument("--recall-probes", type=int, nargs="*", default=())
     ap.add_argument("--recall-seeds", type=int, nargs="*", default=())
+    ap.add_argument("--bench", default=spec.BENCHMARK_JSON)
     args = ap.parse_args(argv)
-    cell = spec.Cell(args.workload)
+    bench = os.path.abspath(args.bench)
+    dirs = ((spec.BENCH_DIR,) if bench == spec.BENCHMARK_JSON
+            else (os.path.dirname(bench), spec.BENCH_DIR))
+    cell = spec.Cell(args.workload, bench, dirs)
     os.environ["JAX_COMPILATION_CACHE_DIR"] = run.CACHE_DIR
-    devs = run.require_chips(cell.chips)
+    devs = run.require_chips(cell.chips)[:cell.chips]
     prog, ctl = [], []
     for s in args.seeds:
         res = run.measure(cell, s, args.seconds, False, devs)
@@ -104,19 +129,23 @@ def main(argv=None) -> int:
         prog.append(r)
         print(json.dumps({"program": r}), flush=True)
     for s in args.control_seeds:
-        r = dict(control_reading(cell, s), seed=s)
+        r = dict(control_reading(cell, s, devs), seed=s)
         ctl.append(r)
         print(json.dumps({"control": r}), flush=True)
     if args.recall_probes:
         recall_curve(cell, args.recall_seeds or list(args.seeds[:1]),
-                     args.recall_probes)
+                     args.recall_probes, devs)
     names = ("dist_err", "miss")
+
+    def most(rows, pick):
+        return {n: pick(r[n] for r in rows) for n in names} if rows else None
+
     print(json.dumps({"summary": {
         "workload": args.workload,
-        "program_max": ({n: max(r[n] for r in prog) for n in names}
-                        if prog else None),
-        "control_min": ({n: min(r[n] for r in ctl) for n in names}
-                        if ctl else None)}}), flush=True)
+        "program_max": most(prog, max),
+        "reference_max": most([r["reference"] for r in ctl], max),
+        "control_min": most([r["control"] for r in ctl], min)}}),
+        flush=True)
     return 0
 
 

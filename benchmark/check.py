@@ -1,7 +1,8 @@
 """The comparison that decides ``correct``.
 
 Every answer the clients received is judged against the plain
-reference (:mod:`benchmark.reference`) by what it says:
+reference the configuration names (``references/<name>.py``) by what
+it says:
 
 - ``dist_err``: the widest gap, over every returned (query, id) pair,
   between the distance the program reported and the float64 distance
@@ -22,11 +23,10 @@ from __future__ import annotations
 
 import numpy as np
 
-from benchmark.reference import true_distances
 
-
-def judge_rows(x, pool, ref_d, ref_i, qids, dist, ids):
-    """Per answered row: ``(gap (m,), recall (m,))``."""
+def judge_rows(x, pool, ref_d, ref_i, qids, dist, ids, true_distances):
+    """Per answered row: ``(gap (m,), recall (m,))``; ``true_distances``
+    is the reference's (``(x, queries, ids) -> float64 distances``)."""
     m, k = ids.shape
     n = int(x.shape[0])
     uq, first = np.unique(qids, return_index=True)
@@ -59,17 +59,21 @@ def judge_rows(x, pool, ref_d, ref_i, qids, dist, ids):
     return gap[pos], recall[pos]
 
 
-def judge(x, pool, ref, answers, limits: dict) -> dict:
+def judge(x, pool, ref, answers, limits: dict, true_distances) -> dict:
     """The numbers compared, each beside its limit, and the recall.
 
-    ``answers``: ``(qids (m,), dist (m, k), ids (m, k), in_window (m,))``
-    of every answered row; ``limits``: the configuration's ``limits``.
+    ``x``: the corpus (the sharded ``jax.Array``); ``ref``: the
+    reference's ``(distances, ids)``; ``answers``: ``(qids (m,), dist
+    (m, k), ids (m, k), in_window (m,), failed)`` of every answered row;
+    ``limits``: the configuration's ``limits``; ``true_distances``: the
+    reference's.
     Returns ``{"checks": {name: {"value", "limit"}}, "recall": float,
     "correct": bool}``."""
     qids, dist, ids, in_window, failed = answers
     ref_d, ref_i = ref
     if len(qids):
-        gap, recall = judge_rows(x, pool, ref_d, ref_i, qids, dist, ids)
+        gap, recall = judge_rows(x, pool, ref_d, ref_i, qids, dist, ids,
+                                 true_distances)
         dist_err = float(gap.max())
         win = recall[in_window]
         rec = float(win.mean()) if len(win) else 0.0

@@ -7,21 +7,28 @@ configuration and a traffic mix, each a file of its own (see
 :mod:`benchmark.spec`). One run:
 
 1. set-up (``setup_s``, from process start to the first timed
-   request): the corpus and the query pool made on the device from
-   ``--seed``, the index built, the executor warmed for exactly the
-   buckets and host-side shapes this mix produces, one pass through the
-   batcher;
+   request): the corpus made from the configuration's ``data_seed`` in
+   its ``dataset.dtype`` (float32, uint8 or int8), sharded by rows over
+   the cell's ``chips`` and drawn block by block on the chip that holds
+   each block (:mod:`benchmark.data`), and the query pool from
+   ``--seed``; the index built by the family's adapter, which may take
+   the corpus and the cell's chips (:mod:`benchmark.spec`); the
+   executor warmed for exactly the buckets and host-side shapes this
+   mix produces, one pass through the batcher;
 2. the window: the mix's clients drive ``DynamicBatcher.submit`` (the
    default ``BatcherConfig``) for ``--seconds``; with ``--trace 1`` the
    profiler records it;
-3. after the window: the device's peak memory is read, the program's
-   state freed, and the plain reference judges every answer the clients
-   received (:mod:`benchmark.check`).
+3. after the window: the chips' peak memory is read, the program's
+   state freed, and the plain reference the configuration names judges
+   every answer the clients received (:mod:`benchmark.check`), each
+   chip searching the rows it holds.
 
 The last line of standard output is the result. With ``--trace 0`` its
 metrics are the cell's end-to-end metrics, with ``--trace 1`` its
-per-layer metrics. Without a TPU, or with fewer chips than the cell
-asks for, it prints no result and exits 2.
+per-layer metrics. Standard error gives each phase's seconds with the
+peak memory of each of the cell's chips (``memory_peak_bytes`` is the
+fullest). Without a TPU, or with fewer chips than the cell asks for, it
+prints no result and exits 2.
 """
 
 from __future__ import annotations
@@ -129,16 +136,35 @@ class Window:
         return 100.0 * ideal / seconds
 
 
-class Phases:
-    """Seconds each step of set-up and judging took, on standard error."""
+def peak_bytes(devs) -> list:
+    """Each device's peak memory in use so far (0 where the backend
+    keeps no count)."""
+    return [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+            for d in devs]
 
-    def __init__(self):
+
+class Phases:
+    """Seconds each step of set-up and judging took, on standard error,
+    with each device's peak memory so far."""
+
+    def __init__(self, devs=()):
+        self.devs = list(devs)
         self.t = time.perf_counter()
 
     def mark(self, name: str) -> None:
         now = time.perf_counter()
-        print(f"phase {name} {now - self.t:.3f} s", file=sys.stderr)
+        print(f"phase {name} {now - self.t:.3f} s peak_bytes "
+              f"{peak_bytes(self.devs)}", file=sys.stderr)
         self.t = now
+
+
+def build(cell, corpus, devs):
+    """The family's index over the corpus: ``build_on`` with the corpus
+    and the cell's devices where the adapter has it, else ``build`` with
+    the corpus's array."""
+    if hasattr(cell.family, "build_on"):
+        return cell.family.build_on(cell.conf, corpus, devs)
+    return cell.family.build(cell.conf, corpus.array)
 
 
 def snapshot(ex) -> dict:
@@ -181,13 +207,14 @@ def measure(cell, seed: int, seconds: float, trace: bool, devs) -> dict:
     from raft_tpu.core.resources import init_compile_cache
     from raft_tpu.serving import BatcherConfig, DynamicBatcher
 
-    from benchmark import check, data, peaks, reference
+    from benchmark import check, data, peaks
     from benchmark import trace as trace_mod
     from benchmark import traffic as traffic_mod
 
     jax.config.update("jax_compilation_cache_dir", CACHE_DIR)
     init_compile_cache()
     tracing.install_xla_compile_listener()
+    devs = list(devs[:cell.chips])
     dev = devs[0]
     peak = peaks.lookup(dev.device_kind) if dev.platform == "tpu" else None
     conf, tr = cell.conf, cell.traffic
@@ -195,11 +222,11 @@ def measure(cell, seed: int, seconds: float, trace: bool, devs) -> dict:
     k = int(ds["k"])
     traffic_mod.check_mix(tr)
 
-    phases = Phases()
-    x, pool_dev = data.for_dataset(ds, seed, int(tr["pool"]))
+    phases = Phases(devs)
+    corpus, pool_dev = data.for_dataset(ds, seed, int(tr["pool"]), devs)
     pool = np.asarray(pool_dev)
     phases.mark("data")
-    index = cell.family.build(conf, x)
+    index = build(cell, corpus, devs)
     jax.block_until_ready(index)
     phases.mark("build")
     if hasattr(cell.family, "describe"):
@@ -232,8 +259,9 @@ def measure(cell, seed: int, seconds: float, trace: bool, devs) -> dict:
     if trace:
         jax.profiler.stop_trace()
     after = snapshot(ex)
-    mem = [d.memory_stats() or {} for d in devs[:cell.chips]]
-    memory_peak = max(int(s.get("peak_bytes_in_use", 0)) for s in mem)
+    mem = peak_bytes(devs)
+    memory_peak = max(mem)
+    print(f"memory peak_bytes by chip {mem}", file=sys.stderr)
 
     recs = loop.records
     ok = [r for r in recs if r.error is None]
@@ -247,8 +275,9 @@ def measure(cell, seed: int, seconds: float, trace: bool, devs) -> dict:
     del batcher, ex, index, loop
     gc.collect()
 
-    phases = Phases()
-    ref = reference.exact_knn(x, pool_dev, k)
+    phases = Phases(devs)
+    x = corpus.array
+    ref = cell.reference.knn(x, pool_dev, k)
     phases.mark("reference")
     if ok:
         qids = np.concatenate([r.rows for r in ok])
@@ -261,7 +290,8 @@ def measure(cell, seed: int, seconds: float, trace: bool, devs) -> dict:
         dist, ids = np.zeros((0, k)), np.zeros((0, k), np.int64)
         win = np.zeros(0, bool)
     verdict = check.judge(x, pool, ref, (qids, dist, ids, win, failed),
-                          conf.get("limits", {}))
+                          conf.get("limits", {}),
+                          cell.reference.true_distances)
 
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(jax.devices()), "memory_peak_bytes": memory_peak}

@@ -1,15 +1,38 @@
 """Finds what ``BENCHMARK.json`` names, by name, in files of their own.
 
-- a configuration: ``configs/<config>.json`` (sizes, family, limits);
+- a configuration: ``configs/<config>.json`` (sizes, family,
+  reference, limits; ``dataset.dtype`` is ``float32``, ``uint8`` or
+  ``int8``, and a byte dtype adds ``byte_scale`` and ``byte_offset``,
+  see :mod:`benchmark.data`);
 - a traffic mix: ``traffic/<traffic>.json`` (read by
   :mod:`benchmark.traffic`);
-- an index family's adapter: ``families/<family>.py``;
+- an index family's adapter: ``families/<family>.py`` (the
+  configuration's ``family``);
+- the plain reference: ``references/<reference>.py`` (the
+  configuration's ``reference``);
 - a kernel's work function: ``work/<kernel>.py``;
 - a per-layer metric's reader: ``metrics/<metric>.py``.
 
 Each is looked up in ``dirs`` in order (the benchmark's own directory
 by default), so a later PR adds a cell, a configuration or a metric by
 adding files and entries, and edits no file that is there.
+
+What the harness calls on them:
+
+- a family adapter: ``build_on(conf, corpus, devices)`` if it has one,
+  given the :class:`benchmark.data.Corpus` (``array``, ``n_rows``,
+  ``dim``, ``dtype``, ``iter_chunks(chunk_rows)`` yielding
+  ``(first_row, rows)`` on the chip that holds them) and the cell's
+  ``chips`` devices, so a mesh adapter can build its ``Comms`` over
+  them; otherwise ``build(conf, x)`` with the corpus's array.
+  Then ``search_params(conf)``; ``work_inputs(conf, index, pool)``
+  where the configuration names a ``kernel``; ``describe(index)``
+  optionally, for stderr.
+- a reference: ``knn(x, queries, k) -> (float64 distances, ids)``
+  exact and ascending; ``true_distances(x, queries, ids) -> float64``
+  for each (query row, id) pair; ``control(x, queries, k) -> (float32
+  distances, int32 ids)``, the control's answers (used by
+  ``calibrate.py`` only). ``x`` is the corpus's sharded array.
 """
 
 from __future__ import annotations
@@ -69,6 +92,8 @@ class Cell:
         self.conf = load_json(dirs, "configs", self.entry["config"])
         self.traffic = load_json(dirs, "traffic", self.entry["traffic"])
         self.family = load_module(dirs, "families", self.conf["family"])
+        self.reference = load_module(dirs, "references",
+                                     self.conf["reference"])
         self.work = (load_module(dirs, "work", self.conf["kernel"])
                      if self.conf.get("kernel") else None)
 

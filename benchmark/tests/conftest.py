@@ -1,4 +1,6 @@
-"""The benchmark's own tests run on the CPU at tiny sizes."""
+"""The benchmark's own tests run on the CPU at tiny sizes, on four
+virtual devices (``xla_force_host_platform_device_count``, set before
+JAX starts) so the four-chip cells' sharded paths run too."""
 
 import os
 import sys
@@ -6,6 +8,10 @@ import sys
 import pytest
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
+_flags = os.environ.get("XLA_FLAGS", "")
+if "xla_force_host_platform_device_count" not in _flags:
+    os.environ["XLA_FLAGS"] = (
+        _flags + " --xla_force_host_platform_device_count=4").strip()
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)

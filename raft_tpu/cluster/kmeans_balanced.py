@@ -61,7 +61,7 @@ def _predict_impl(x, centroids, metric: DistanceType):
     ``detail/kmeans_balanced.cuh:371`` ``predict``."""
     if metric in _NORMALIZED_METRICS:
         sims = jax.lax.dot_general(
-            x, centroids, (((1,), (1,)), ((), ())),
+            x.astype(jnp.float32), centroids, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         labels = jnp.argmax(sims, axis=1).astype(jnp.int32)
@@ -166,9 +166,11 @@ def predict(
     x,
 ) -> jax.Array:
     """Label each row with its nearest centroid
-    (``kmeans_balanced::predict``)."""
+    (``kmeans_balanced::predict``). ``x`` may hold any real dtype: it
+    is widened to float32 inside the compiled program, on the device
+    that holds it, so byte rows cross to the device as bytes."""
     ensure_resources(res)
-    x = jnp.asarray(x, jnp.float32)
+    x = jnp.asarray(x)
     centroids = jnp.asarray(centroids, jnp.float32)
     with tracing.range("raft_tpu.kmeans_balanced.predict"):
         return _predict_impl(x, centroids, params.metric)

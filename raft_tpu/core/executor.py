@@ -147,6 +147,10 @@ DEVICE_WAIT = "serving.batcher.device_wait_seconds"
 # concatenations, pads and casts, jnp.asarray of host values (implicit
 # transfers of numpy arguments into the compiled call are not counted)
 EAGER_PROGRAMS = "serving.execute.eager_programs"
+# modeled collective bytes of the sharded dispatches: each adds its
+# entry's collective_payload_model coarse + merge bytes (a per-shard
+# payload, fixed when the executable compiles)
+MESH_WIRE_BYTES = "serving.mesh.wire_bytes"
 
 
 def _count_eager(n: int) -> None:
@@ -293,7 +297,7 @@ class _Plan:
 
 class _Entry:
     __slots__ = ("compiled", "state", "cost", "digest", "family",
-                 "payload_model")
+                 "payload_model", "wire_bytes")
 
     def __init__(self, compiled, state, cost=None, digest="",
                  family="", payload_model=None):
@@ -306,6 +310,8 @@ class _Entry:
         # per-dispatch mesh spans can attach modeled per-phase bytes
         # without rebuilding the model on the hot path
         self.payload_model = payload_model
+        self.wire_bytes = 0.0 if payload_model is None else float(
+            payload_model["coarse_bytes"] + payload_model["merge_bytes"])
 
 
 # readiness-poll quantum for per-shard arrival timing (mesh_trace):
@@ -1415,6 +1421,8 @@ class SearchExecutor:
             "serving.execute.modeled_bytes":
                 entry.cost.get("bytes_accessed", 0.0),
         }
+        if entry.payload_model is not None:
+            amounts[MESH_WIRE_BYTES] = entry.wire_bytes
         if plan.probe is not None:
             # the host-side heartbeat of the device accounting —
             # what the CI snapshot floors check (lifetime ledger)

@@ -74,7 +74,7 @@ from raft_tpu.matrix.select_k import merge_topk
 from raft_tpu.neighbors import ivf_flat as ivf_flat_mod
 from raft_tpu.neighbors import ivf_pq as ivf_pq_mod
 from raft_tpu.neighbors._batching import coarse_select
-from raft_tpu.neighbors._packing import padded_extent
+from raft_tpu.neighbors._packing import padded_extent, streaming_ranks
 from raft_tpu.neighbors.ivf_flat import IvfFlatIndexParams, IvfFlatSearchParams
 from raft_tpu.neighbors.ivf_pq import (
     CodebookKind,
@@ -848,6 +848,57 @@ def search(
             ))
 
 
+_BYTE_STORAGE = (np.dtype(np.uint8), np.dtype(np.int8))
+
+# one host span (and a ``<name>_seconds`` histogram) per pass of
+# build_streaming, each ended once its results are ready
+STREAM_STAGES = ("sample", "quantizer", "labels", "scatter", "norms")
+STREAM_SPAN = "distributed.build.{}"
+
+
+def _stage(name: str):
+    span = STREAM_SPAN.format(name)
+    return tracing.host_span(span, hist=span + "_seconds")
+
+
+def _list_storage(source_dtype) -> np.dtype:
+    """The dtype :func:`build_streaming` holds the lists in: a
+    uint8/int8 source's own bytes, float32 for anything else."""
+    src = np.dtype(source_dtype)
+    return src if src in _BYTE_STORAGE else np.dtype(np.float32)
+
+
+def _scatter_rows_fn(data, idx, rows, meta, *, axis: str, mesh):
+    """Scatter one chunk (replicated over the mesh) into the
+    list-sharded buffers. ``meta`` is ``(3, m)`` int32: each row's id,
+    dealt list and rank within the list. Each shard keeps the rows of
+    the lists it owns and drops the rest."""
+
+    def body(data_l, idx_l, rows, meta):
+        ids, pos, ranks = meta
+        n_local = data_l.shape[0]
+        local = pos - comm_rank(axis).astype(jnp.int32) * n_local
+        local = jnp.where((local >= 0) & (local < n_local), local, n_local)
+        return (data_l.at[local, ranks].set(rows, mode="drop"),
+                idx_l.at[local, ranks].set(ids, mode="drop"))
+
+    return shard_map(
+        body, mesh=mesh,
+        in_specs=(P(axis, None, None), P(axis, None), P(), P()),
+        out_specs=(P(axis, None, None), P(axis, None)),
+        check_vma=False)(data, idx, rows, meta)
+
+
+_scatter_rows = partial(jax.jit, donate_argnums=(0, 1),
+                        static_argnames=("axis", "mesh"))(_scatter_rows_fn)
+
+
+@jax.jit
+def _stream_norms(data, indices):
+    norms = jnp.sum(jnp.square(data.astype(jnp.float32)), axis=2)
+    return jnp.where(indices >= 0, norms, jnp.inf)
+
+
 def build_streaming(
     res: Optional[Resources],
     comms: Comms,
@@ -858,10 +909,28 @@ def build_streaming(
 ) -> DistributedIvfFlat:
     """Stream a dataset larger than any single chip's HBM directly into
     the list-sharded index: the quantizer trains on a strided sample,
-    then every prefetched chunk is scattered into the ALREADY-SHARDED
+    then every chunk is labelled and scattered into the ALREADY-SHARDED
     device buffers (donated, so updates stay in place on their shards).
     This is the capacity story of the distributed index — the dataset
-    never materializes on one device or in host memory.
+    never materializes on one device or in host memory. A uint8/int8
+    source stays bytes in the lists.
+
+    ``source`` has ``n_rows``, ``dim``, ``dtype`` and ``iter_chunks``:
+    a :class:`raft_tpu.io.BinDataset` (host chunks), or a corpus whose
+    chunks are ``jax.Array`` s on any of the mesh's devices, each
+    labelled on the device that holds it
+    (:func:`raft_tpu.neighbors._streaming.label_pass`).
+
+    The lists keep a uint8/int8 source's bytes (a quarter of float32's
+    HBM; the list scan widens them in VMEM) and hold anything else as
+    float32. ``data_norms`` are float32 sums of squares of the stored values
+    (exact integers for bytes). The quantizer trains on a
+    ``train_rows``-row sample (of which ``ivf_flat.build`` keeps its
+    ``kmeans_trainset_fraction``): enough rows per list keep the lists
+    even, and the padded extent — the largest list — sets the index's
+    bytes. Each pass runs under a ``distributed.build.<stage>`` host
+    span (:data:`STREAM_STAGES`) whose ``_seconds`` histogram records
+    its duration.
     """
     res = ensure_resources(res)
     r = comms.size
@@ -869,6 +938,7 @@ def build_streaming(
     params = dataclasses.replace(params, n_lists=n_lists,
                                  add_data_on_build=False)
     n, d = source.n_rows, source.dim
+    storage = _list_storage(getattr(source, "dtype", np.float32))
 
     with tracing.range("raft_tpu.distributed.ivf_flat.build_streaming"):
         # quantizer on a strided sample + per-chunk labels: the SAME
@@ -880,17 +950,23 @@ def build_streaming(
             sample_trainset,
         )
 
-        train_rows = max(n_lists, min(train_rows, n))
-        trainset = sample_trainset(source, train_rows, chunk_rows)
-        quant = ivf_flat_mod.build(res, params, trainset)
+        with _stage("sample"):
+            train_rows = max(n_lists, min(train_rows, n))
+            trainset = sample_trainset(source, train_rows, chunk_rows,
+                                       storage)
+        with _stage("quantizer"):
+            quant = ivf_flat_mod.build(res, params, trainset)
+            jax.block_until_ready(quant.centers)
 
         km = KMeansBalancedParams(
             metric=(DistanceType.InnerProduct
                     if params.metric == DistanceType.InnerProduct
                     else DistanceType.L2Expanded))
-        labels_np, sizes_np = label_pass(res, km, quant.centers, source,
-                                         chunk_rows, n_lists)
-        max_size = padded_extent(sizes_np)
+        with _stage("labels"):
+            labels_np, sizes_np = label_pass(
+                res, km, jax.device_put(quant.centers, comms.replicated()),
+                source, chunk_rows, n_lists)
+        max_size = padded_extent(sizes_np, storage)
 
         # deal lists round-robin by population; dealt[i] = original list
         deal = deal_order(sizes_np, r)
@@ -898,54 +974,45 @@ def build_streaming(
         dealt_pos[deal] = np.arange(n_lists, dtype=np.int32)
 
         shard = comms.sharding(comms.axis)
+        repl = comms.replicated()
         # gate the per-shard staging BEFORE the sharded buffers (and
         # the norms plane derived later) allocate — planned shapes,
         # nothing materialized yet
         admit_deal(
-            (jax.ShapeDtypeStruct((n_lists, max_size, d), jnp.float32),
+            (jax.ShapeDtypeStruct((n_lists, max_size, d), storage),
              jax.ShapeDtypeStruct((n_lists, max_size), jnp.int32),
              jax.ShapeDtypeStruct((n_lists, max_size), jnp.float32)),
             r, "distributed.ivf_flat.build_streaming.deal")
-        data = jax.device_put(
-            jnp.zeros((n_lists, max_size, d), jnp.float32), shard)
-        indices = jax.device_put(
-            jnp.full((n_lists, max_size), -1, jnp.int32), shard)
+        # each device allocates only its own shard of the buffers
+        data = jax.jit(partial(jnp.zeros, (n_lists, max_size, d), storage),
+                       out_shardings=shard)()
+        indices = jax.jit(partial(jnp.full, (n_lists, max_size), -1,
+                                  jnp.int32), out_shardings=shard)()
 
-        @partial(jax.jit, donate_argnums=(0, 1))
-        def scatter_chunk(data, idx, rows, ids, list_ids, ranks):
-            return (data.at[list_ids, ranks].set(rows),
-                    idx.at[list_ids, ranks].set(ids))
-
-        fill = np.zeros((n_lists,), np.int64)
-        for first, chunk in source.iter_chunks(chunk_rows):
-            interruptible.yield_()  # cancellation point per chunk
-            m = chunk.shape[0]
-            lab = labels_np[first : first + m]
-            corder = np.argsort(lab, kind="stable")
-            sl = lab[corder]
-            first_pos = np.searchsorted(sl, np.arange(n_lists))
-            rank_sorted = np.arange(m) - first_pos[sl] + fill[sl]
-            ranks = np.empty((m,), np.int32)
-            ranks[corder] = rank_sorted.astype(np.int32)
-            np.add.at(fill, lab, 1)
-            data, indices = scatter_chunk(
-                data, indices,
-                jnp.asarray(chunk, jnp.float32),
-                jnp.asarray(first + np.arange(m, dtype=np.int32)),
-                jnp.asarray(dealt_pos[lab]),
-                jnp.asarray(ranks),
-            )
-
-        @jax.jit
-        def make_norms(data, indices):
-            norms = jnp.sum(jnp.square(data), axis=2)
-            return jnp.where(indices >= 0, norms, jnp.inf)
+        with _stage("scatter"):
+            fill = np.zeros((n_lists,), np.int64)
+            for first, chunk in source.iter_chunks(chunk_rows):
+                interruptible.yield_()  # cancellation point per chunk
+                m = chunk.shape[0]
+                lab = labels_np[first : first + m]
+                ranks = streaming_ranks(lab, fill, n_lists)
+                meta = np.stack([first + np.arange(m, dtype=np.int32),
+                                 dealt_pos[lab], ranks])
+                # graftlint: disable=R5(streaming scatter: one replicated put per chunk bounds build staging to O(chunk))
+                rows, meta = jax.device_put((chunk.astype(storage), meta),
+                                            repl)
+                data, indices = _scatter_rows(data, indices, rows, meta,
+                                              axis=comms.axis,
+                                              mesh=comms.mesh)
+            jax.block_until_ready((data, indices))
+        with _stage("norms"):
+            norms = jax.block_until_ready(_stream_norms(data, indices))
 
         return DistributedIvfFlat(
             comms=comms,
             centers=place_dealt(quant.centers, deal, comms),
             data=data,
-            data_norms=make_norms(data, indices),
+            data_norms=norms,
             indices=indices,
             list_sizes=jax.device_put(
                 jnp.asarray(sizes_np[deal], jnp.int32), shard),

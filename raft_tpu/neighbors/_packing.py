@@ -33,10 +33,21 @@ def streaming_ranks(labels_chunk, fill, n_lists: int):
     return ranks
 
 
-def padded_extent(sizes) -> int:
+def sublane_multiple(dtype=jnp.float32) -> int:
+    """Rows of one TPU tile of ``dtype`` storage: 8 for 4-byte
+    elements, 16 for 2-byte, 32 for 1-byte (an (8, 128) tile of 32-bit
+    words packs narrower elements along the sublanes)."""
+    return max(8, 32 // np.dtype(dtype).itemsize)
+
+
+def padded_extent(sizes, dtype=jnp.float32) -> int:
     """Shared max-list-size rounding: the largest list, rounded up to
-    the sublane multiple (8). One host sync per build/extend."""
-    return max(8, -(-int(jnp.max(jnp.asarray(sizes))) // 8) * 8)
+    the sublane multiple of the ``dtype`` the lists are stored in
+    (:func:`sublane_multiple`; 8 for the default float32), so the
+    Pallas list scan reads each list as whole tiles. One host sync per
+    build/extend."""
+    sub = sublane_multiple(dtype)
+    return max(sub, -(-int(jnp.max(jnp.asarray(sizes))) // sub) * sub)
 
 
 def pack_padded_lists(

@@ -281,7 +281,8 @@ def extend(
             sizes_new = index.list_sizes + jax.ops.segment_sum(
                 jnp.ones((n_new,), jnp.int32), new_labels,
                 num_segments=index.n_lists)
-            if padded_extent(sizes_new) <= index.max_list_size:
+            if (padded_extent(sizes_new, index.data.dtype)
+                    <= index.max_list_size):
                 lab_np = np.asarray(new_labels)
                 fill = np.asarray(index.list_sizes).astype(np.int64)
                 ranks = streaming_ranks(lab_np, fill, index.n_lists)
@@ -316,7 +317,7 @@ def extend(
             num_segments=index.n_lists,
         )
         # one host sync at build/extend time to fix the padded extent
-        max_size = padded_extent(sizes)
+        max_size = padded_extent(sizes, all_vecs.dtype)
 
         # graftledger capacity gate (opt-in, no-op unless installed):
         # the repack is the allocation event — admit its padded layout

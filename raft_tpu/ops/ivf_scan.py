@@ -38,8 +38,22 @@ Two engines share the formulation:
 - ``xla``: the same union/mask/merge as a ``lax.scan`` over unique
   lists, merging via one lexicographic two-key ``lax.sort`` (the same
   smallest-id tie-break as the kernel, any k without unrolling) — the
-  portable fallback (CPU/GPU, 2-D per-query filters, int8 storage,
-  large k, misaligned layouts on TPU).
+  portable fallback (CPU/GPU, 2-D per-query filters, large k,
+  misaligned layouts on TPU).
+
+**Storage dtypes.** The kernel streams float32, bfloat16, uint8 and
+int8 lists. A list block moves HBM->VMEM in its stored dtype and is
+widened in VMEM, never in HBM. Float lists widen to float32 and
+contract at ``HIGHEST`` precision. Byte lists (``uint8``/``int8``, the
+BIGANN-style corpora) widen to bfloat16, which holds every byte value
+exactly, and contract in two single-pass bfloat16 products with
+float32 accumulation: one with the queries rounded to bfloat16 and one
+with the remainder (:func:`_split_bf16`). For byte-valued queries the
+remainder is zero, every product is exact and every partial sum is an
+integer below 128 * 255**2 < 2**24, so the distances are the exact
+integers. Sentinel steps skip the contraction. The layout wants lists
+padded to the dtype's sublane multiple (32 rows for bytes,
+:func:`raft_tpu.neighbors._packing.sublane_multiple`).
 
 **Ragged query-tile front** (the continuous-batching serving path):
 several requests with *different* per-request ``n_probes`` pack
@@ -74,12 +88,16 @@ from raft_tpu.core.chips import vmem_budget_mb
 from raft_tpu.core.logger import logger
 from raft_tpu.core.validation import expect
 from raft_tpu.distance.types import DistanceType
+from raft_tpu.neighbors._packing import sublane_multiple
 from raft_tpu.ops.fused_topk import _extract_topk
 
 SCAN_ENGINES = ("auto", "pallas", "xla", "rank")
 
 # the merge network unrolls k rounds; past this the XLA merge wins
 _PALLAS_MAX_K = 128
+
+# list storage the Pallas kernel streams (bytes widen in VMEM)
+_KERNEL_STORAGE = (jnp.float32, jnp.bfloat16, jnp.uint8, jnp.int8)
 
 
 @functools.lru_cache(maxsize=None)
@@ -99,14 +117,17 @@ def degrade(family: str, reason: str) -> str:
 
 def resolve_scan_engine(engine: str, *, data=None, filter_words=None,
                         k=None, vmem_mb: int = 0) -> str:
-    """Resolve a ``scan_engine`` search param to a concrete engine.
+    """Resolve a ``scan_engine`` search param to a concrete engine. The
+    Pallas kernel serves float32, bfloat16, uint8 and int8 lists (byte
+    lists widen to bfloat16 in VMEM; see the module docstring).
 
     ``auto`` is the Pallas kernel on TPU and the list-major XLA scan
     elsewhere. ``pallas`` degrades to ``xla`` when the kernel's
     preconditions fail: per-query (2-D) filter words (the id-fold
-    trick needs one shared id plane), non-f32/bf16 storage (Mosaic
-    block tiling), ``k`` past the unrolled-merge budget, or a single
-    list block that cannot fit the VMEM budget double-buffered.
+    trick needs one shared id plane), storage other than
+    float32/bfloat16/uint8/int8, ``k`` past the unrolled-merge budget,
+    a list layout that is not whole tiles on TPU, or a single list
+    block that cannot fit the VMEM budget double-buffered.
     ``rank`` is the legacy rank-major gather scan, kept for parity
     testing and as the small-``n_lists`` escape hatch."""
     expect(engine in SCAN_ENGINES,
@@ -120,19 +141,18 @@ def resolve_scan_engine(engine: str, *, data=None, filter_words=None,
     if k is not None and k > _PALLAS_MAX_K:
         return degrade("ivf_scan", f"k > {_PALLAS_MAX_K}")
     if data is not None:
-        if data.dtype not in (jnp.float32, jnp.bfloat16):
+        if data.dtype not in _KERNEL_STORAGE:
             return degrade("ivf_scan", f"{data.dtype} storage")
-        itemsize = 2 if data.dtype == jnp.bfloat16 else 4
-        sub = 16 if itemsize == 2 else 8
+        sub = sublane_multiple(data.dtype)
         m_pad = -(-data.shape[1] // sub) * sub
         d_pad = -(-data.shape[2] // 128) * 128
         # on real hardware a misaligned layout would force _scan_pallas
         # to jnp.pad the WHOLE packed tensor per call — a full HBM
         # read+write dwarfing the probe scan — so compiled runs demand
-        # build-time alignment (padded_extent gives m % 8; lane-aligned
-        # dims like 128/256 give d). Interpret mode (off-TPU) keeps the
-        # pad path: it exists so CPU CI can cover the kernel at any
-        # test shape.
+        # build-time alignment (padded_extent rounds m to the dtype's
+        # sublane multiple; lane-aligned dims like 128/256 give d).
+        # Interpret mode (off-TPU) keeps the pad path: it exists so CPU
+        # CI can cover the kernel at any test shape.
         if jax.default_backend() == "tpu" and (
                 m_pad != data.shape[1] or d_pad != data.shape[2]):
             return degrade("ivf_scan", "list layout not tile-aligned")
@@ -144,11 +164,38 @@ def resolve_scan_engine(engine: str, *, data=None, filter_words=None,
         # vmem_limit_bytes and fail Mosaic compilation instead of
         # degrading here. p_pad is unknown at resolve time; 256 covers
         # n_probes up to 256 conservatively.
-        fixed = 3 * m_pad * (d_pad * itemsize + 8) + (2 << 20)
-        per_q = 4 * (d_pad + 256) + 24 * m_pad + 16 * (k or _PALLAS_MAX_K)
+        fixed = _vmem_fixed(m_pad, d_pad, data.dtype)
+        per_q = _vmem_per_query(m_pad, d_pad, 256, k or _PALLAS_MAX_K,
+                                data.dtype)
         if fixed + 8 * per_q > vmem_mb << 20:
             return degrade("ivf_scan", "list block exceeds the VMEM budget")
     return engine
+
+
+def _is_bytes(dtype) -> bool:
+    return jnp.dtype(dtype) in (jnp.uint8, jnp.int8)
+
+
+def _vmem_fixed(m_pad: int, d_pad: int, dtype) -> int:
+    """VMEM the kernel holds whatever the query tile: the list block
+    double-buffered, its widened copy, the norm and id rows, and a
+    2 MB margin. Float lists widen to float32 (one strip of the
+    block's size); byte lists pass through int32 and float32 on their
+    way to bfloat16 (10 bytes an element at most)."""
+    itemsize = jnp.dtype(dtype).itemsize
+    if _is_bytes(dtype):
+        return m_pad * (d_pad * (2 * itemsize + 10) + 24) + (2 << 20)
+    return 3 * m_pad * (d_pad * itemsize + 8) + (2 << 20)
+
+
+def _vmem_per_query(m_pad: int, d_pad: int, p_pad: int, k: int,
+                    dtype) -> int:
+    """VMEM per query row of the tile: the query vector (float32, or
+    its two bfloat16 halves for byte lists), the probe row, the (m)
+    distance and merge intermediates (one more product strip for byte
+    lists) and the (k) running state."""
+    per_m = 28 if _is_bytes(dtype) else 24
+    return 4 * (d_pad + p_pad) + per_m * m_pad + 16 * k
 
 
 def probe_histogram(probes: jax.Array, counts: jax.Array,
@@ -281,7 +328,9 @@ def list_major_scan(qf, data, data_norms, indices, probes,
     top-k ``(best_d, best_i)`` in the rank-major scan's convention
     (min-space ``norms - 2 x·y`` for L2 with +inf pads; raw inner
     products for IP with -inf pads), so the caller's metric epilog is
-    shared across engines.
+    shared across engines. Byte (uint8/int8) lists stream as bytes, and
+    their distances to byte-valued queries are exact integers in both
+    engines.
 
     Both engines break distance ties by smallest dataset id (the
     ``_extract_topk`` order), so their outputs are bit-identical to
@@ -377,29 +426,18 @@ def _scan_xla(qf, data, data_norms, indices, probes, filter_words,
 # ---------------------------------------------------------------------------
 
 
-def _ivf_scan_kernel(u_ref, probes_ref, q_ref, x_ref, xn_ref, ids_ref,
-                     outd_ref, outi_ref, bestd, besti, *, k: int,
-                     n_steps: int, n_lists: int, ip_metric: bool):
-    j = pl.program_id(1)
-
+def _init_state(j, bestd, besti):
     @pl.when(j == 0)
     def _():
         bestd[:] = jnp.full_like(bestd, jnp.inf)
         besti[:] = jnp.full_like(besti, -1)
 
-    lid = u_ref[j]                        # scalar-prefetched list id
-    # ONE dense (q_tile, d) x (d, m) MXU contraction for the whole
-    # query tile against the whole list — the TPU-KNN shape. Storage
-    # upcasts to f32 so bf16 lists match the rank-major scan's math.
-    xt = x_ref[0].astype(jnp.float32)     # (m, d)
-    ip = jax.lax.dot_general(
-        q_ref[:], xt, (((1,), (1,)), ((), ())),
-        precision=jax.lax.Precision.HIGHEST,
-        preferred_element_type=jnp.float32,
-    )                                     # (q_tile, m)
-    # min-space distances; IP negates back at the final step
-    dist = -ip if ip_metric else xn_ref[0] - 2.0 * ip
-    ids = ids_ref[0]                      # (1, m) — -1 marks pad/filtered
+
+def _merge_list(dist, ids, probes_ref, lid, bestd, besti, *, k: int,
+                n_lists: int):
+    """Fold one list's min-space ``(q_tile, m)`` distances into the
+    running top-k: rows of queries that did not probe the list, and
+    pad or filtered slots (id -1), are masked first."""
     # membership predicate: which tile rows actually probed this list.
     # The lid < n_lists guard kills sentinel steps outright, including
     # the case where probe slots carry the sentinel value themselves
@@ -421,10 +459,88 @@ def _ivf_scan_kernel(u_ref, probes_ref, q_ref, x_ref, xn_ref, ids_ref,
         bestd[:] = new_d
         besti[:] = new_i
 
+
+def _emit(j, outd_ref, outi_ref, bestd, besti, *, n_steps: int,
+          ip_metric: bool):
     @pl.when(j == n_steps - 1)
     def _():
         outd_ref[:] = -bestd[:] if ip_metric else bestd[:]
         outi_ref[:] = besti[:]
+
+
+def _ivf_scan_kernel(u_ref, probes_ref, q_ref, x_ref, xn_ref, ids_ref,
+                     outd_ref, outi_ref, bestd, besti, *, k: int,
+                     n_steps: int, n_lists: int, ip_metric: bool):
+    j = pl.program_id(1)
+    _init_state(j, bestd, besti)
+    lid = u_ref[j]                        # scalar-prefetched list id
+    # ONE dense (q_tile, d) x (d, m) MXU contraction for the whole
+    # query tile against the whole list — the TPU-KNN shape. Storage
+    # upcasts to f32 so bf16 lists match the rank-major scan's math.
+    xt = x_ref[0].astype(jnp.float32)     # (m, d)
+    ip = jax.lax.dot_general(
+        q_ref[:], xt, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
+    )                                     # (q_tile, m)
+    # min-space distances; IP negates back at the final step
+    dist = -ip if ip_metric else xn_ref[0] - 2.0 * ip
+    ids = ids_ref[0]                      # (1, m) — -1 marks pad/filtered
+    _merge_list(dist, ids, probes_ref, lid, bestd, besti, k=k,
+                n_lists=n_lists)
+    _emit(j, outd_ref, outi_ref, bestd, besti, n_steps=n_steps,
+          ip_metric=ip_metric)
+
+
+def _split_bf16(q):
+    """``(hi, lo)`` bfloat16 halves of float32 queries: ``hi`` is ``q``
+    rounded to bfloat16, ``lo`` the remainder rounded again.
+    ``q - hi - lo`` is at most 2**-16 |q| elementwise, and ``lo`` is 0
+    wherever ``q`` holds an integer of at most 8 significant bits (any
+    byte value)."""
+    hi = q.astype(jnp.bfloat16)
+    lo = (q - hi.astype(jnp.float32)).astype(jnp.bfloat16)
+    return hi, lo
+
+
+def _ivf_scan_bytes_kernel(u_ref, probes_ref, qhi_ref, qlo_ref, x_ref,
+                           xn_ref, ids_ref, outd_ref, outi_ref, bestd,
+                           besti, *, k: int, n_steps: int, n_lists: int,
+                           ip_metric: bool):
+    """The list scan over uint8/int8 lists. The block arrives as bytes
+    and widens to bfloat16 here, in VMEM; bfloat16's 8 significant
+    bits hold every byte value exactly. The contraction is two
+    single-pass (DEFAULT precision) bfloat16 products accumulated in
+    float32, ``hi . x + lo . x`` (:func:`_split_bf16`). Byte-valued
+    queries have ``lo = 0``, each product of two bytes is exact in
+    float32, and every partial sum is an integer of magnitude at most
+    128 * 255**2 = 8,323,200 < 2**24, so ``x . q``, the stored norm and
+    ``norm - 2 x . q`` are all exact integers. Float queries keep
+    ``|error| <= 2**-16 * sum_i |q_i| |x_i|`` on the inner product, plus
+    float32 accumulation rounding. On a v5e, at 8,192 lists x 6,976
+    slots x 128 and 1,000 queries, this body took 263 ms a call, the
+    float body on bytes widened to float32 at ``HIGHEST`` 788 ms (433
+    ms with this body's sentinel skip)."""
+    j = pl.program_id(1)
+    _init_state(j, bestd, besti)
+    lid = u_ref[j]
+
+    # a sentinel step (no probed list left) contracts nothing
+    @pl.when(lid < n_lists)
+    def _():
+        xt = x_ref[0].astype(jnp.int32).astype(jnp.float32).astype(
+            jnp.bfloat16)                 # (m, d), exact
+        dims = (((1,), (1,)), ((), ()))
+        ip = jax.lax.dot_general(qhi_ref[:], xt, dims,
+                                 preferred_element_type=jnp.float32)
+        ip = ip + jax.lax.dot_general(qlo_ref[:], xt, dims,
+                                      preferred_element_type=jnp.float32)
+        dist = -ip if ip_metric else xn_ref[0] - 2.0 * ip
+        _merge_list(dist, ids_ref[0], probes_ref, lid, bestd, besti, k=k,
+                    n_lists=n_lists)
+
+    _emit(j, outd_ref, outi_ref, bestd, besti, n_steps=n_steps,
+          ip_metric=ip_metric)
 
 
 def _scan_pallas(qf, data, data_norms, indices, probes, filter_words, *,
@@ -437,8 +553,8 @@ def _scan_pallas(qf, data, data_norms, indices, probes, filter_words, *,
     ip_metric = metric == DistanceType.InnerProduct
     if vmem_mb <= 0:
         vmem_mb = vmem_budget_mb()
-    itemsize = 2 if data.dtype == jnp.bfloat16 else 4
-    sub = 16 if itemsize == 2 else 8
+    byte_lists = _is_bytes(data.dtype)
+    sub = sublane_multiple(data.dtype)
 
     uniq = unique_lists(probes, n_lists)
     n_steps = uniq.shape[0]
@@ -456,7 +572,8 @@ def _scan_pallas(qf, data, data_norms, indices, probes, filter_words, *,
         ids_g = jnp.where(bits & (ids_g >= 0), ids_g, -1)
 
     # lane/sublane alignment; all no-ops on aligned serving layouts
-    # (padded_extent rounds max_list_size to 8, d=128-multiples common)
+    # (padded_extent rounds max_list_size to the dtype's sublane
+    # multiple, d=128-multiples common)
     m_pad = -(-m // sub) * sub
     d_pad = -(-d // 128) * 128
     if m_pad != m or d_pad != d:
@@ -472,24 +589,26 @@ def _scan_pallas(qf, data, data_norms, indices, probes, filter_words, *,
     p_pad = -(-p // 128) * 128
 
     # query-tile sizing from the VMEM budget: double-buffered list
-    # block + f32 upcast strip are the fixed cost; per query row the
+    # block + its widened strip are the fixed cost; per query row the
     # kernel keeps the query vector, the probe row, the (m) dist/cat
-    # intermediates (~24 B) and the (k) running state
-    budget = (vmem_mb << 20) - 3 * m_pad * (d_pad * itemsize + 8) - (2 << 20)
-    per_q = 4 * (d_pad + p_pad) + 24 * m_pad + 16 * k
+    # intermediates and the (k) running state
+    budget = (vmem_mb << 20) - _vmem_fixed(m_pad, d_pad, data.dtype)
+    per_q = _vmem_per_query(m_pad, d_pad, p_pad, k, data.dtype)
     q_tile = min(max(8, (budget // per_q) // 8 * 8), -(-q // 8) * 8)
     q_pad = -(-q // q_tile) * q_tile
 
     qs = jnp.pad(qf.astype(jnp.float32),
                  ((0, q_pad - q), (0, d_pad - d)))
+    query_ops = _split_bf16(qs) if byte_lists else (qs,)
     # pad probe rows/cols with -1: a pad query probes nothing, so its
     # running state stays empty and its rows are sliced away
     probes_p = jnp.pad(probes.astype(jnp.int32),
                        ((0, q_pad - q), (0, p_pad - p)),
                        constant_values=-1)
 
-    kernel = functools.partial(_ivf_scan_kernel, k=k, n_steps=n_steps,
-                               n_lists=n_lists, ip_metric=ip_metric)
+    kernel = functools.partial(
+        _ivf_scan_bytes_kernel if byte_lists else _ivf_scan_kernel, k=k,
+        n_steps=n_steps, n_lists=n_lists, ip_metric=ip_metric)
     clamp = n_lists - 1
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -497,8 +616,8 @@ def _scan_pallas(qf, data, data_norms, indices, probes, filter_words, *,
         in_specs=[
             pl.BlockSpec((q_tile, p_pad), lambda i, j, u: (i, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((q_tile, d_pad), lambda i, j, u: (i, 0),
-                         memory_space=pltpu.VMEM),
+            *[pl.BlockSpec((q_tile, d_pad), lambda i, j, u: (i, 0),
+                           memory_space=pltpu.VMEM) for _ in query_ops],
             # the scalar-prefetched dynamic index map: step j streams
             # list u[j]'s block; the sentinel clamps to a real list and
             # is masked by the membership predicate
@@ -531,5 +650,5 @@ def _scan_pallas(qf, data, data_norms, indices, probes, filter_words, *,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_mb << 20),
         interpret=interpret,
-    )(uniq, probes_p, qs, data, xn_g, ids_g)
+    )(uniq, probes_p, *query_ops, data, xn_g, ids_g)
     return outd[:q], outi[:q]

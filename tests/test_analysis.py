@@ -1088,6 +1088,11 @@ class TestRepoWide:
         ("raft_tpu/distributed/ivf.py", "R5",
          "streaming deal: per-block puts bound build staging to "
          "O(block)"),
+        # the byte-typed streaming build: each chunk crosses to every
+        # list shard once, the same bound as the deal's
+        ("raft_tpu/distributed/ivf.py", "R5",
+         "streaming scatter: one replicated put per chunk bounds "
+         "build staging to O(chunk)"),
         ("raft_tpu/serving/harness.py", "R5",
          "device-free test shim: inputs are host arrays by contract"),
         # PR 9: FakeExecutor grew the ragged dispatch entry — same

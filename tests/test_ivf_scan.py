@@ -220,9 +220,11 @@ class TestResolveEngine:
         # shared 1-D words are fine
         assert resolve_scan_engine(
             "pallas", data=data, filter_words=fw[0]) == "pallas"
-        # int8 storage
+        # byte storage is the kernel's; float16 storage is not
         assert resolve_scan_engine(
-            "pallas", data=data.astype(jnp.int8)) == "xla"
+            "pallas", data=data.astype(jnp.int8)) == "pallas"
+        assert resolve_scan_engine(
+            "pallas", data=data.astype(jnp.float16)) == "xla"
         # k beyond the unrolled-merge budget
         assert resolve_scan_engine("pallas", data=data, k=512) == "xla"
         # a single list block that cannot fit VMEM

@@ -151,6 +151,12 @@ EAGER_PROGRAMS = "serving.execute.eager_programs"
 # entry's collective_payload_model coarse + merge bytes (a per-shard
 # payload, fixed when the executable compiles)
 MESH_WIRE_BYTES = "serving.mesh.wire_bytes"
+# dispatches whose executable runs the grouped Pallas list scan
+# (ops/ivf_scan): the resolved engine of an IVF-Flat plan, single-chip
+# or list-sharded, is "pallas". Every IVF-Flat dispatch adds 1 or 0,
+# so a degrade to the XLA engine reads 0, not nothing
+GROUPED_SCAN_DISPATCHES = "serving.scan.grouped_dispatches"
+_GROUPED_FAMILIES = ("ivf_flat", "dist_ivf_flat")
 
 
 def _count_eager(n: int) -> None:
@@ -293,19 +299,37 @@ class _Plan:
     # split evenly AND each list shard's scatter-merge slice stays
     # whole. Dispatch pads/compiles to this instead of the bucket.
     rows: Optional[int] = None
+    # the grouped Pallas list scan (IVF-Flat plans whose engine
+    # resolved to "pallas"): (n_probes, one chip's (n_lists, m, d) list
+    # shape, list dtype, query shards) — the executable's G and S are
+    # recorded from it, and its dispatches counted
+    grouped_scan: Any = None
+
+    @property
+    def valid_rows(self) -> bool:
+        """The compiled signature carries the real row count
+        ``n_valid`` (traced int32 scalar), so bucket-pad rows probe
+        nothing: grouped-scan plans with unsharded queries. Ragged
+        plans zero pad rows by their budgets; plans with probe
+        accounting carry ``n_valid`` already."""
+        return (self.grouped_scan is not None and not self.ragged
+                and self.grouped_scan[3] == 1)
 
 
 class _Entry:
     __slots__ = ("compiled", "state", "cost", "digest", "family",
-                 "payload_model", "wire_bytes")
+                 "payload_model", "wire_bytes", "grouped")
 
     def __init__(self, compiled, state, cost=None, digest="",
-                 family="", payload_model=None):
+                 family="", payload_model=None, grouped=None):
         self.compiled = compiled
         self.state = state
         self.cost = cost or {}
         self.digest = digest
         self.family = family
+        # IVF-Flat entries: whether the executable runs the grouped
+        # Pallas scan; None for every other family
+        self.grouped = grouped
         # mesh entries keep their collective_payload_model dict so the
         # per-dispatch mesh spans can attach modeled per-phase bytes
         # without rebuilding the model on the hot path
@@ -1393,6 +1417,9 @@ class SearchExecutor:
             if plan.state_sharding is not None:
                 nv = jax.device_put(nv, plan.state_sharding)
             kwargs = {"probe_counts": counts, "n_valid": nv}
+        elif plan.valid_rows:
+            # a host scalar: its transfer rides the call itself
+            kwargs = {"n_valid": np.asarray(q_real, np.int32)}
         if prepare is not None:
             prepare.close()
         with tracing.host_span(ENQUEUE_SPAN, hist=ENQUEUE):
@@ -1423,6 +1450,8 @@ class SearchExecutor:
         }
         if entry.payload_model is not None:
             amounts[MESH_WIRE_BYTES] = entry.wire_bytes
+        if entry.grouped is not None:
+            amounts[GROUPED_SCAN_DISPATCHES] = float(entry.grouped)
         if plan.probe is not None:
             # the host-side heartbeat of the device accounting —
             # what the CI snapshot floors check (lifetime ledger)
@@ -1540,6 +1569,12 @@ class SearchExecutor:
         info = {"family": plan.key[0], "bucket": bucket, "k": k,
                 "engine": _plan_engine(plan), "compile_seconds": dt,
                 **cost}
+        if plan.grouped_scan is not None:
+            from raft_tpu.ops.ivf_scan import group_shape
+
+            n_probes, shape, dtype, shards = plan.grouped_scan
+            info["scan_group_rows"], info["scan_steps"] = group_shape(
+                bucket // shards, n_probes, shape, dtype, k)
         payload_model = None
         if plan.payload is not None:
             family, model_fn = plan.payload
@@ -1551,8 +1586,12 @@ class SearchExecutor:
             publish_payload_gauges(family, payload_model)
         self._cost_table[digest] = info
         tracing.set_gauges(_cost_gauge_values(digest, cost))
+        grouped = None
+        if plan.key[0].removesuffix("_ragged") in _GROUPED_FAMILIES:
+            grouped = plan.grouped_scan is not None
         ent = _Entry(compiled, state, cost=cost, digest=digest,
-                     family=plan.key[0], payload_model=payload_model)
+                     family=plan.key[0], payload_model=payload_model,
+                     grouped=grouped)
         self._cache[plan.key] = ent
         while len(self._cache) > self.max_entries:
             _, old = self._cache.popitem(last=False)
@@ -1685,6 +1724,9 @@ class SearchExecutor:
             _, n_lists, csharding = plan.probe[:3]
             kwargs["probe_counts"] = jax.ShapeDtypeStruct(
                 (n_lists,), jnp.int32, sharding=csharding)
+            kwargs["n_valid"] = jax.ShapeDtypeStruct(
+                (), jnp.int32, sharding=plan.state_sharding)
+        elif plan.valid_rows:
             kwargs["n_valid"] = jax.ShapeDtypeStruct(
                 (), jnp.int32, sharding=plan.state_sharding)
         return jitted.lower(*args, **kwargs, **plan.static).compile()
@@ -1921,6 +1963,7 @@ class SearchExecutor:
                "distributed searches have no sample_filter support")
         (comms, probe_mode, wire_dtype, probe_wire_dtype,
          query_axis) = self._dist_statics(index, kw)
+        grouped = None
         if isinstance(index, DistributedIvfFlat):
             from raft_tpu.ops.ivf_scan import resolve_scan_engine
 
@@ -1930,13 +1973,19 @@ class SearchExecutor:
                 params.n_probes, index.n_lists, comms.size, probe_mode)
             engine = resolve_scan_engine(params.scan_engine,
                                          data=index.data, k=k)
+            if engine == "pallas":
+                n_lists, m, d = index.data.shape
+                shards = (1 if query_axis is None
+                          else comms.mesh.shape[query_axis])
+                grouped = (n_probes, (n_lists // comms.size, m, d),
+                           index.data.dtype, shards)
             extra, key_extra = {}, ()
             arrays = (index.centers, index.data, index.data_norms,
                       index.indices)
             # same engine/donation split as the single-chip plans: the
             # rank and XLA list-major scans thread the donated
-            # per-shard (q, k) state through HBM; the Pallas kernel
-            # keeps it in VMEM scratch
+            # per-shard (q, k) state through HBM; the grouped Pallas
+            # scan carries none
             has_state = engine != "pallas"
         elif isinstance(index, DistributedIvfPq):
             family, fn = "dist_ivf_pq", dist_ivf._dist_search_pq_fn
@@ -2005,7 +2054,7 @@ class SearchExecutor:
             qsharding = comms.sharding(query_axis, None)
         return _Plan(key=key, fn=fn, static=static, post=arrays,
                      qdim=index.dim, sharded=True, probe=probe,
-                     has_state=has_state,
+                     has_state=has_state, grouped_scan=grouped,
                      qsharding=qsharding,
                      state_sharding=qsharding,
                      rows=rows if query_axis is not None else None,
@@ -2069,11 +2118,15 @@ class SearchExecutor:
                _filter_spec(fw))
         key, probe = self._probe_plumbing(index, "ivf_flat", key)
         # the rank-major and XLA list-major scans thread the donated
-        # (q, k) running state through HBM; the Pallas kernel keeps
-        # its state in VMEM scratch, so donated buffers would go unused
+        # (q, k) running state through HBM; the grouped Pallas scan
+        # carries no running state, so donated buffers would go unused
+        grouped = None
+        if engine == "pallas":
+            grouped = (n_probes, index.data.shape, index.data.dtype, 1)
         return _Plan(key=key, fn=m._search_impl_fn, static=static,
                      post=arrays, use_filter=True, qdim=index.dim,
-                     has_state=engine != "pallas", probe=probe)
+                     has_state=engine != "pallas", probe=probe,
+                     grouped_scan=grouped)
 
     def _plan_tiered(self, index, params, k, bucket, fw, kw) -> _Plan:
         from raft_tpu.neighbors import tiered as m

@@ -580,7 +580,9 @@ def _dist_search_fn(queries, centers, data, data_norms, indices,
     ``n_local`` so each shard streams only the union of lists it owns.
     ``init_d``/``init_i`` optionally provide the (q, k) running top-k
     storage (values are reset here; the serving path donates them —
-    the Pallas engine keeps its state in VMEM scratch instead).
+    the grouped Pallas engine carries no running state). ``n_valid``
+    (traced scalar, replicated; 1-D meshes) is the real row count:
+    bucket-pad rows own no probe, so they add the scan no pair.
 
     ``probe_counts`` (graftgauge) optionally provides the donated
     list-sharded (n_lists,) int32 probe-frequency plane: each shard
@@ -614,7 +616,8 @@ def _dist_search_fn(queries, centers, data, data_norms, indices,
     def body(centers_l, data_l, norms_l, ids_l, qs, ind, ini, *rest):
         rest = list(rest)
         rp = rest.pop(0) if ragged else None
-        cnt, nv = rest if rest else (None, None)
+        cnt = rest.pop(0) if probe_counts is not None else None
+        nv = rest.pop(0) if n_valid is not None else None
         q = qs.shape[0]
         n_local = centers_l.shape[0]
         qf = qs.astype(jnp.float32)
@@ -651,6 +654,11 @@ def _dist_search_fn(queries, centers, data, data_norms, indices,
                     mine, rp,
                     shards=(mesh.shape[axis]
                             if probe_mode == "local" else 1))
+            if nv is not None:
+                # bucket-pad rows own no probe, so the scan gives them
+                # no (query, list) pair
+                valid = jnp.arange(q, dtype=jnp.int32) < nv
+                mine = jnp.logical_and(mine, valid[:, None])
         if cnt is not None:
             from raft_tpu.ops.ivf_scan import probe_histogram
 
@@ -658,9 +666,8 @@ def _dist_search_fn(queries, centers, data, data_norms, indices,
 
         if scan_engine != "rank":
             # list-major: not-owned probes mask to the sentinel id
-            # n_local (ops/ivf_scan mask plumbing); each owned unique
-            # list streams from HBM once and scores the whole query
-            # tile in one MXU GEMM — the PR 2 single-chip engines,
+            # n_local (ops/ivf_scan mask plumbing); each owned probed
+            # list streams from HBM once — the single-chip engines,
             # unchanged, running inside the shard_map body
             with jax.named_scope("scan"):
                 masked = jnp.where(mine, local, n_local).astype(jnp.int32)
@@ -720,9 +727,12 @@ def _dist_search_fn(queries, centers, data, data_norms, indices,
         args += [row_probes]
         in_specs += [P()]           # replicated per-row budget plane
     if probe_counts is not None:
-        args += [probe_counts, n_valid]
-        in_specs += [P(axis), P()]
+        args += [probe_counts]
+        in_specs += [P(axis)]
         out_specs += [P(axis)]
+    if n_valid is not None:
+        args += [n_valid]
+        in_specs += [P()]
     outs = shard_map(
         body, mesh=mesh,
         in_specs=tuple(in_specs),

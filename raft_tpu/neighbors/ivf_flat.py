@@ -13,10 +13,11 @@ live in ONE dense padded tensor ``data[n_lists, max_list_size, dim]``
 (max_list_size = padded max cluster population; balanced k-means keeps the
 overhead ≈2× worst case). The probe scan is pluggable
 (``IvfFlatSearchParams.scan_engine``): the default **list-major**
-engines (:mod:`raft_tpu.ops.ivf_scan` — fused Pallas kernel on TPU,
-XLA scan elsewhere) stream each probed list once and score it against
-the whole query tile in one dense MXU GEMM, with a per-query
-membership mask; the legacy **rank-major** engine is a ``lax.scan``
+engines (:mod:`raft_tpu.ops.ivf_scan` — the grouped Pallas kernel on
+TPU, which scores each probed list against the queries that probed it,
+and the XLA scan elsewhere, which scores it against the whole query
+tile under a per-query membership mask) stream each probed list once;
+the legacy **rank-major** engine is a ``lax.scan``
 over probe ranks gathering one probed list per query into a batched
 GEMM. Per-slot squared norms are precomputed so every engine's scan is
 a pure ``norms - 2 x·y`` epilog (the reference caches norms the same
@@ -476,6 +477,11 @@ def _search_impl_fn(queries, centers, center_norms, data, data_norms, indices,
     updated plane returns as a third output. The search results never
     read it, so enabling accounting cannot perturb them.
 
+    ``n_valid`` (traced scalar, the executor's real row count) also
+    turns the probes of bucket-pad rows into sentinels before a
+    list-major scan, so they cost the grouped Pallas scan no steps;
+    pad rows' own results are never read.
+
     ``row_probes`` (the ragged query-tile front, via
     :func:`_search_ragged_fn`) optionally provides the per-ROW probe
     budget plane of a packed ragged batch: the coarse stage then
@@ -515,14 +521,20 @@ def _search_impl_fn(queries, centers, center_norms, data, data_norms, indices,
         probe_counts = probe_histogram(
             probes, probe_counts,
             None if row_probes is not None else n_valid)
+    if n_valid is not None and scan_engine != "rank":
+        # bucket-pad rows probe nothing: the list-major scans skip
+        # sentinel probes, so a pad row adds no (query, list) pair
+        valid = jnp.arange(q, dtype=jnp.int32) < n_valid
+        probes = jnp.where(valid[:, None], probes, n_lists)
 
     pad_val = jnp.inf if select_min else -jnp.inf
 
     if scan_engine != "rank":
         # ---- list-major probe scan (ops/ivf_scan): stream each unique
-        # probed list once, one dense GEMM per list for the whole tile.
-        # The XLA engine reuses the donated running state; the Pallas
-        # kernel's state lives in VMEM scratch and ignores it.
+        # probed list once. The XLA engine scores it against the whole
+        # tile and reuses the donated running state; the grouped Pallas
+        # scan scores it against the rows that probed it and keeps no
+        # running state.
         from raft_tpu.ops.ivf_scan import list_major_scan
 
         best_d, best_i = list_major_scan(

@@ -3,43 +3,57 @@
 re-designed per the two papers the survey flags for this kernel:
 
 - TPU-KNN (arxiv 2206.14286): peak FLOP/s on TPU means expressing kNN as
-  large dense contractions with an in-register merge — never as gathers
+  dense contractions with an in-register merge — never as gathers
   feeding batched matvecs.
 - Ragged Paged Attention (arxiv 2604.15464): the TPU-native way to fetch
   data-dependent pages is a **scalar-prefetched block index map** — the
-  page table (here: the probed-list union) rides ahead of the grid in
-  SMEM and steers each step's HBM->VMEM block DMA.
+  page table (here: the step schedule) rides ahead of the grid in SMEM
+  and steers each step's HBM->VMEM block DMA.
 
 The rank-major scan (``ivf_flat._search_impl_fn`` with
 ``scan_engine="rank"``) gathers one probed list *per query* per probe
 rank: a `(q, m, d)` HBM materialization and a gather-bound batched
 matvec, repeated ``n_probes`` times. This module turns the scan
-**list-major**: compute the union of probed list ids for the whole
-query tile (sort/unique on device, padded to a static cap with a
-sentinel id ``n_lists``), then stream each unique list's
-``(max_list_size, d)`` block from the packed ``data`` tensor exactly
-once and contract it against the *entire* query tile in one MXU GEMM.
-A per-query "did this query probe this list" predicate masks rows out,
-so results match the rank-major scan (indices exactly; distances to
-XLA's dot-reassociation tolerance — the same caveat as
-``beam_search``'s two lowerings). Per-probe HBM traffic drops from
-``q * n_probes`` gathered lists to at most ``min(n_lists,
-q * n_probes)`` streamed lists, and the matvecs become dense GEMMs.
+**list-major**: each probed list's ``(max_list_size, d)`` block streams
+from the packed ``data`` tensor once, so per-probe HBM traffic drops
+from ``q * n_probes`` gathered lists to at most ``min(n_lists,
+q * n_probes)`` streamed lists.
 
-Two engines share the formulation:
+Two engines share the formulation and are bit-identical to each other
+in ids and distances:
 
-- ``pallas``: the fused kernel. Grid ``(query_tiles, n_unique)``; the
-  unique-list array is the scalar-prefetch operand steering the
-  ``data``/``data_norms`` BlockSpec index maps; the running ``(q, k)``
-  top-k lives in VMEM scratch and merges via the
-  ``ops.fused_topk._extract_topk`` network with the ``any_better``
-  skip. Shared (1-D) bitset filters fold into the gathered id planes
-  before the kernel (a filtered slot becomes id -1 — padding).
-- ``xla``: the same union/mask/merge as a ``lax.scan`` over unique
-  lists, merging via one lexicographic two-key ``lax.sort`` (the same
-  smallest-id tie-break as the kernel, any k without unrolling) — the
-  portable fallback (CPU/GPU, 2-D per-query filters, large k,
-  misaligned layouts on TPU).
+- ``pallas``: the grouped kernel. The ``q * p`` (query row, probe slot)
+  pairs sort by list id (sentinel pairs last, dropped); each list's run
+  of pairs is cut into groups of at most ``G`` rows, one grid step
+  each, and a step contracts only its group's ``(G, d)`` query rows
+  against the list, so a list is scored only against the queries that
+  probed it. ``G`` is the mean number of pairs a probed list can have,
+  ``q*p / min(n_lists, q*p)``, rounded up to a multiple of 8 and held
+  to [8, 256] and to what VMEM holds (:func:`group_shape`); the grid
+  has the static cap ``S = min(n_lists, q*p) + (q*p) // G`` steps,
+  which ``sum_l ceil(c_l / G)`` never exceeds. The step's list id is
+  the scalar-prefetched operand of the ``data`` BlockSpec: a list with
+  more than ``G`` probers takes consecutive steps of one block index,
+  so its block moves once, and the steps past the real ones keep the
+  last block and compute nothing. Each step extracts its own ``(G,
+  k)`` top-k with the ``ops.fused_topk._extract_topk`` network and
+  carries no state across steps; an XLA epilogue hands every pair its
+  block and takes each row's top-k of its ``p * k`` candidates in the
+  same (distance, smallest id) order. VMEM holds one list block
+  (double-buffered, widened) and a ``(G, d)`` query block with its
+  ``(G, m)`` distance strip. Shared (1-D) bitset filters fold into the
+  gathered id planes before the kernel (a filtered slot becomes id -1
+  — padding).
+- ``xla``: a ``lax.scan`` over the union of probed lists, contracting
+  each against the whole query tile, with a per-query "did this query
+  probe this list" mask and one lexicographic two-key ``lax.sort``
+  merge (the same smallest-id tie-break, any k without unrolling) —
+  the portable fallback (CPU/GPU, 2-D per-query filters, large k,
+  misaligned layouts on TPU) and the parity reference.
+
+Against the rank-major scan the indices are bit-identical and the
+distances agree to XLA's dot-reassociation tolerance — the same caveat
+as ``beam_search``'s two lowerings.
 
 **Storage dtypes.** The kernel streams float32, bfloat16, uint8 and
 int8 lists. A list block moves HBM->VMEM in its stored dtype and is
@@ -51,7 +65,7 @@ float32 accumulation: one with the queries rounded to bfloat16 and one
 with the remainder (:func:`_split_bf16`). For byte-valued queries the
 remainder is zero, every product is exact and every partial sum is an
 integer below 128 * 255**2 < 2**24, so the distances are the exact
-integers. Sentinel steps skip the contraction. The layout wants lists
+integers. The layout wants lists
 padded to the dtype's sublane multiple (32 rows for bytes,
 :func:`raft_tpu.neighbors._packing.sublane_multiple`).
 
@@ -61,15 +75,15 @@ adjacently into one fixed query tile, and each row's probe slots past
 its own budget mask to the sentinel id ``n_lists``
 (:func:`ragged_row_probes` / :func:`ragged_probes`). Sentinel-valued
 probe slots are exactly how the list-sharded indexes already mark
-not-owned probes, so BOTH engines serve the packed tile unchanged —
-the membership predicate is the raggedness mechanism, and the
-scalar-prefetched index map streams only the union the packed batch
-actually probed. One executable therefore serves every load shape;
-the per-request results are bit-identical to solo calls. The same
-front covers the whole index zoo (graftragged): the PQ LUT scan and
-the fused BQ engines consume the identical sentinel-masked probes,
-and on the mesh :func:`ragged_owned` folds each row's budget into
-the sharded probe-ownership mask — so a replicated packed tile
+not-owned probes, and how the serving executor marks the probes of
+bucket-pad rows, so BOTH engines serve the packed tile unchanged: the
+grouped kernel never gives a sentinel slot a pair, and the XLA scan's
+membership predicate rejects it. One executable therefore serves every
+load shape; the per-request results are bit-identical to solo calls.
+The same front covers the whole index zoo (graftragged): the PQ LUT
+scan and the fused BQ engines consume the identical sentinel-masked
+probes, and on the mesh :func:`ragged_owned` folds each row's budget
+into the sharded probe-ownership mask — so a replicated packed tile
 serves the list-sharded families through their unchanged shard
 bodies.
 """
@@ -160,14 +174,13 @@ def resolve_scan_engine(engine: str, *, data=None, filter_words=None,
             vmem_mb = vmem_budget_mb()
         # mirror _scan_pallas's budget: the list block + margin fixed
         # cost must leave room for at least one minimal (8-row) query
-        # tile — otherwise the kernel's q_tile floor would overshoot
+        # group — otherwise the kernel's group floor would overshoot
         # vmem_limit_bytes and fail Mosaic compilation instead of
-        # degrading here. p_pad is unknown at resolve time; 256 covers
-        # n_probes up to 256 conservatively.
+        # degrading here
         fixed = _vmem_fixed(m_pad, d_pad, data.dtype)
-        per_q = _vmem_per_query(m_pad, d_pad, 256, k or _PALLAS_MAX_K,
+        per_row = _vmem_per_row(m_pad, d_pad, k or _PALLAS_MAX_K,
                                 data.dtype)
-        if fixed + 8 * per_q > vmem_mb << 20:
+        if fixed + 8 * per_row > vmem_mb << 20:
             return degrade("ivf_scan", "list block exceeds the VMEM budget")
     return engine
 
@@ -177,7 +190,7 @@ def _is_bytes(dtype) -> bool:
 
 
 def _vmem_fixed(m_pad: int, d_pad: int, dtype) -> int:
-    """VMEM the kernel holds whatever the query tile: the list block
+    """VMEM the kernel holds whatever the query group: the list block
     double-buffered, its widened copy, the norm and id rows, and a
     2 MB margin. Float lists widen to float32 (one strip of the
     block's size); byte lists pass through int32 and float32 on their
@@ -188,14 +201,14 @@ def _vmem_fixed(m_pad: int, d_pad: int, dtype) -> int:
     return 3 * m_pad * (d_pad * itemsize + 8) + (2 << 20)
 
 
-def _vmem_per_query(m_pad: int, d_pad: int, p_pad: int, k: int,
-                    dtype) -> int:
-    """VMEM per query row of the tile: the query vector (float32, or
-    its two bfloat16 halves for byte lists), the probe row, the (m)
-    distance and merge intermediates (one more product strip for byte
-    lists) and the (k) running state."""
+def _vmem_per_row(m_pad: int, d_pad: int, k: int, dtype) -> int:
+    """VMEM per row of a query group: the float32 query row
+    double-buffered (its two bfloat16 halves beside it for byte
+    lists), the ``(m)`` distance and top-k extraction intermediates
+    (one more product strip for byte lists) and the double-buffered
+    ``(k)`` output rows."""
     per_m = 28 if _is_bytes(dtype) else 24
-    return 4 * (d_pad + p_pad) + per_m * m_pad + 16 * k
+    return 12 * d_pad + per_m * m_pad + 16 * k
 
 
 def probe_histogram(probes: jax.Array, counts: jax.Array,
@@ -261,9 +274,9 @@ def ragged_probes(probes: jax.Array, row_probes: jax.Array,
     search with ``n_probes=b`` would have selected); ``row_probes`` is
     :func:`ragged_row_probes`'s per-row budget plane. Sentinel-masked
     slots ride the exact machinery the list-sharded indexes already
-    use for not-owned probes: :func:`unique_lists` collapses them into
-    sentinel steps, both engines' membership predicates reject them
-    (``lid < n_lists``), and :func:`probe_histogram` drops them — so
+    use for not-owned probes: the grouped Pallas scan gives them no
+    (query, list) pair, the XLA scan's membership predicate rejects
+    them (``lid < n_lists``), and :func:`probe_histogram` drops them — so
     one packed executable serves every per-request ``n_probes`` in the
     class, bit-identical per request to the solo call."""
     slot = jnp.arange(probes.shape[1], dtype=jnp.int32)
@@ -337,7 +350,7 @@ def list_major_scan(qf, data, data_norms, indices, probes,
     each other even on exact duplicates. ``init_d``/``init_i``
     optionally provide the (q, k) running-state storage for the XLA
     engine (values are reset; the serving path donates them); the
-    Pallas engine keeps its state in VMEM scratch and ignores them.
+    grouped Pallas engine carries no running state and ignores them.
 
     Probe slots carrying the sentinel value ``n_lists`` are masked
     probes (the list-sharded indexes mark not-owned probes this way);
@@ -357,17 +370,22 @@ def list_major_scan(qf, data, data_norms, indices, probes,
 # ---------------------------------------------------------------------------
 
 
+def _smallest_k(dist, ids, k: int):
+    """The k smallest of each row's min-space ``(dist, ids)`` under the
+    smallest-id tie-break (the ``_extract_topk`` order) as one
+    lexicographic two-key sort; unfilled slots read id -1."""
+    sd, si = jax.lax.sort((dist, ids), dimension=1, num_keys=2)
+    sd, si = sd[:, :k], si[:, :k]
+    return sd, jnp.where(jnp.isfinite(sd), si, -1)
+
+
 def _merge_smallest_id(best_d, best_i, dist, ids, k: int):
-    """Min-space running top-k merge with the smallest-id tie-break —
-    the ``_extract_topk`` order as one lexicographic two-key sort, so
+    """Min-space running top-k merge with the smallest-id tie-break, so
     the XLA engine matches the Pallas kernel bit-for-bit on exact
     ties (``merge_topk``'s positional tie-break would not), and any k
     works without unrolling k rounds."""
-    cat_d = jnp.concatenate([best_d, dist], axis=1)
-    cat_i = jnp.concatenate([best_i, ids], axis=1)
-    sd, si = jax.lax.sort((cat_d, cat_i), dimension=1, num_keys=2)
-    sd, si = sd[:, :k], si[:, :k]
-    return sd, jnp.where(jnp.isfinite(sd), si, -1)
+    return _smallest_k(jnp.concatenate([best_d, dist], axis=1),
+                       jnp.concatenate([best_i, ids], axis=1), k)
 
 
 def _scan_xla(qf, data, data_norms, indices, probes, filter_words,
@@ -422,74 +440,120 @@ def _scan_xla(qf, data, data_norms, indices, probes, filter_words,
 
 
 # ---------------------------------------------------------------------------
-# Pallas list-major engine
+# Pallas grouped engine
 # ---------------------------------------------------------------------------
 
 
-def _init_state(j, bestd, besti):
-    @pl.when(j == 0)
+def _group_rows(q: int, p: int, n_lists: int) -> int:
+    """Rows of one query group: the mean number of (query, probe)
+    pairs a probed list can have, ``q*p / min(n_lists, q*p)``, rounded
+    up to a multiple of 8 and held to [8, 256]."""
+    pairs = q * p
+    mean = -(-pairs // min(n_lists, pairs))
+    return max(8, min(256, -(-mean // 8) * 8))
+
+
+def group_shape(q: int, p: int, data_shape, dtype, k: int,
+                vmem_mb: int = 0):
+    """``(G, S)`` of the grouped Pallas scan of a ``(q, p)`` probe
+    block over lists of ``data_shape`` ``(n_lists, m, d)``: ``G`` rows
+    a group (:func:`_group_rows`, lowered to what the VMEM budget
+    holds beside one list block), and the static step cap
+    ``S = min(n_lists, q*p) + (q*p) // G``. A list with ``c`` probers
+    takes ``ceil(c / G)`` steps, and ``sum ceil(c_l / G) <= sum
+    floor(c_l / G) + (lists probed) <= S``."""
+    n_lists, m, d = data_shape
+    sub = sublane_multiple(dtype)
+    m_pad = -(-m // sub) * sub
+    d_pad = -(-d // 128) * 128
+    if vmem_mb <= 0:
+        vmem_mb = vmem_budget_mb()
+    room = (vmem_mb << 20) - _vmem_fixed(m_pad, d_pad, dtype)
+    fit = room // _vmem_per_row(m_pad, d_pad, k, dtype) // 8 * 8
+    g = max(8, min(_group_rows(q, p, n_lists), fit))
+    pairs = q * p
+    return g, min(n_lists, pairs) + pairs // g
+
+
+def _schedule(probes, n_lists: int, g: int, s: int):
+    """The grouped scan's steps, on the device. The ``q*p`` (query row,
+    probe slot) pairs sort by list id (sentinel and out-of-range ids
+    last, dropped); each list's run of pairs is cut into groups of at
+    most ``g`` rows, one step each, numbered in list order. Returns
+
+    - ``uniq`` ``(min(n_lists, q*p),)``: the probed lists in order,
+      sentinel-padded (the id and norm planes are gathered by it);
+    - ``steps`` ``(2*s + 1,)``: each step's list id, then each step's
+      rank in ``uniq``, then the number of real steps. Steps past the
+      real ones repeat the last real step's list, so their blocks are
+      not fetched again;
+    - ``rows`` ``(s * g,)``: the query row of each group slot, ``q``
+      (a zero row) where a group is not full;
+    - ``src`` ``(q*p,)``: for each pair, in ``probes``' row-major
+      order, the group slot holding its results, -1 for a sentinel
+      pair or a repeat of a list the row already probed."""
+    q, p = probes.shape
+    n = q * p
+    pos = jnp.arange(n, dtype=jnp.int32)
+    lid = probes.reshape(-1).astype(jnp.int32)
+    lid = jnp.where((lid >= 0) & (lid < n_lists), lid, n_lists)
+    lid, pair = jax.lax.sort((lid, pos), num_keys=2)
+    row = pair // p
+    valid = lid < n_lists
+    first = jnp.concatenate([jnp.ones((1,), bool), lid[1:] != lid[:-1]])
+    off = pos - jax.lax.cummax(jnp.where(first, pos, 0))
+    opens = valid & (off % g == 0)
+    step = jnp.cumsum(opens.astype(jnp.int32)) - 1
+    n_real = jnp.sum(opens.astype(jnp.int32))
+    rank = jnp.cumsum(first.astype(jnp.int32)) - 1
+
+    cap = min(n_lists, n)
+    uniq = jnp.full((cap,), n_lists, jnp.int32).at[
+        jnp.where(first & valid, rank, cap)].set(lid, mode="drop")
+    at = jnp.where(opens, step, s)
+    lists = jnp.zeros((s,), jnp.int32).at[at].set(lid, mode="drop")
+    ranks = jnp.zeros((s,), jnp.int32).at[at].set(rank, mode="drop")
+    last = jnp.maximum(n_real - 1, 0)
+    tail = jnp.arange(s) >= n_real
+    lists = jnp.where(tail, lists[last], lists)
+    ranks = jnp.where(tail, ranks[last], ranks)
+    steps = jnp.concatenate([lists, ranks, n_real[None]])
+
+    slot = jnp.where(valid, step * g + off % g, s * g)
+    rows = jnp.full((s * g,), q, jnp.int32).at[slot].set(row, mode="drop")
+    repeat = ~first & (row == jnp.roll(row, 1))
+    src = jnp.where(valid & ~repeat, slot, -1)
+    src = jnp.zeros((n,), jnp.int32).at[pair].set(src)
+    return uniq, steps, rows, src
+
+
+def _group_topk(dist, ids_ref, outd_ref, outi_ref, *, k: int):
+    """The step's own ``(G, k)`` top-k of its min-space ``(G, m)``
+    distances, smallest id first on ties; pad and filtered slots (id
+    -1) never surface."""
+    ids = ids_ref[0]                      # (1, m)
+    dist = jnp.where(ids >= 0, dist, jnp.inf)
+    d, i = _extract_topk(dist, jnp.broadcast_to(ids, dist.shape), k)
+    outd_ref[0] = d
+    outi_ref[0] = i
+
+
+def _ivf_scan_kernel(steps_ref, q_ref, x_ref, xn_ref, ids_ref, outd_ref,
+                     outi_ref, *, k: int, n_steps: int, ip_metric: bool):
+    # steps past the real ones keep their blocks and compute nothing
+    @pl.when(pl.program_id(0) < steps_ref[2 * n_steps])
     def _():
-        bestd[:] = jnp.full_like(bestd, jnp.inf)
-        besti[:] = jnp.full_like(besti, -1)
-
-
-def _merge_list(dist, ids, probes_ref, lid, bestd, besti, *, k: int,
-                n_lists: int):
-    """Fold one list's min-space ``(q_tile, m)`` distances into the
-    running top-k: rows of queries that did not probe the list, and
-    pad or filtered slots (id -1), are masked first."""
-    # membership predicate: which tile rows actually probed this list.
-    # The lid < n_lists guard kills sentinel steps outright, including
-    # the case where probe slots carry the sentinel value themselves
-    # (shard-masked probes of the list-sharded indexes).
-    probed = jnp.any(probes_ref[:] == lid, axis=1, keepdims=True)
-    probed = jnp.logical_and(probed, lid < n_lists)
-    dist = jnp.where((ids >= 0) & probed, dist, jnp.inf)
-
-    # filtered merge: skip the k-round extraction when no row improves
-    kth = bestd[:, k - 1 : k]
-    any_better = jnp.any(dist < kth)
-
-    @pl.when(any_better)
-    def _():
-        cat_d = jnp.concatenate([bestd[:], dist], axis=1)
-        cat_i = jnp.concatenate(
-            [besti[:], jnp.broadcast_to(ids, dist.shape)], axis=1)
-        new_d, new_i = _extract_topk(cat_d, cat_i, k)
-        bestd[:] = new_d
-        besti[:] = new_i
-
-
-def _emit(j, outd_ref, outi_ref, bestd, besti, *, n_steps: int,
-          ip_metric: bool):
-    @pl.when(j == n_steps - 1)
-    def _():
-        outd_ref[:] = -bestd[:] if ip_metric else bestd[:]
-        outi_ref[:] = besti[:]
-
-
-def _ivf_scan_kernel(u_ref, probes_ref, q_ref, x_ref, xn_ref, ids_ref,
-                     outd_ref, outi_ref, bestd, besti, *, k: int,
-                     n_steps: int, n_lists: int, ip_metric: bool):
-    j = pl.program_id(1)
-    _init_state(j, bestd, besti)
-    lid = u_ref[j]                        # scalar-prefetched list id
-    # ONE dense (q_tile, d) x (d, m) MXU contraction for the whole
-    # query tile against the whole list — the TPU-KNN shape. Storage
-    # upcasts to f32 so bf16 lists match the rank-major scan's math.
-    xt = x_ref[0].astype(jnp.float32)     # (m, d)
-    ip = jax.lax.dot_general(
-        q_ref[:], xt, (((1,), (1,)), ((), ())),
-        precision=jax.lax.Precision.HIGHEST,
-        preferred_element_type=jnp.float32,
-    )                                     # (q_tile, m)
-    # min-space distances; IP negates back at the final step
-    dist = -ip if ip_metric else xn_ref[0] - 2.0 * ip
-    ids = ids_ref[0]                      # (1, m) — -1 marks pad/filtered
-    _merge_list(dist, ids, probes_ref, lid, bestd, besti, k=k,
-                n_lists=n_lists)
-    _emit(j, outd_ref, outi_ref, bestd, besti, n_steps=n_steps,
-          ip_metric=ip_metric)
+        # the group's (G, d) query rows against the whole (m, d) list
+        # in one MXU contraction; float32 at HIGHEST, as the XLA
+        # engine, so a row's products never depend on the other rows
+        xt = x_ref[0].astype(jnp.float32)     # (m, d)
+        ip = jax.lax.dot_general(
+            q_ref[0], xt, (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
+        )                                     # (G, m)
+        dist = -ip if ip_metric else xn_ref[0] - 2.0 * ip
+        _group_topk(dist, ids_ref, outd_ref, outi_ref, k=k)
 
 
 def _split_bf16(q):
@@ -503,44 +567,35 @@ def _split_bf16(q):
     return hi, lo
 
 
-def _ivf_scan_bytes_kernel(u_ref, probes_ref, qhi_ref, qlo_ref, x_ref,
-                           xn_ref, ids_ref, outd_ref, outi_ref, bestd,
-                           besti, *, k: int, n_steps: int, n_lists: int,
+def _ivf_scan_bytes_kernel(steps_ref, q_ref, x_ref, xn_ref, ids_ref,
+                           outd_ref, outi_ref, *, k: int, n_steps: int,
                            ip_metric: bool):
-    """The list scan over uint8/int8 lists. The block arrives as bytes
-    and widens to bfloat16 here, in VMEM; bfloat16's 8 significant
-    bits hold every byte value exactly. The contraction is two
-    single-pass (DEFAULT precision) bfloat16 products accumulated in
-    float32, ``hi . x + lo . x`` (:func:`_split_bf16`). Byte-valued
+    """The grouped scan over uint8/int8 lists. The block arrives as
+    bytes and widens to bfloat16 here, in VMEM; bfloat16's 8
+    significant bits hold every byte value exactly. The contraction is
+    two single-pass (DEFAULT precision) bfloat16 products accumulated
+    in float32, ``hi . x + lo . x`` (:func:`_split_bf16`). Byte-valued
     queries have ``lo = 0``, each product of two bytes is exact in
     float32, and every partial sum is an integer of magnitude at most
     128 * 255**2 = 8,323,200 < 2**24, so ``x . q``, the stored norm and
     ``norm - 2 x . q`` are all exact integers. Float queries keep
     ``|error| <= 2**-16 * sum_i |q_i| |x_i|`` on the inner product, plus
     float32 accumulation rounding. On a v5e, at 8,192 lists x 6,976
-    slots x 128 and 1,000 queries, this body took 263 ms a call, the
-    float body on bytes widened to float32 at ``HIGHEST`` 788 ms (433
-    ms with this body's sentinel skip)."""
-    j = pl.program_id(1)
-    _init_state(j, bestd, besti)
-    lid = u_ref[j]
-
-    # a sentinel step (no probed list left) contracts nothing
-    @pl.when(lid < n_lists)
+    slots x 128 and 1,000 queries, the ungrouped form of this body took
+    263 ms a call, the float body on bytes widened to float32 at
+    ``HIGHEST`` 788 ms."""
+    @pl.when(pl.program_id(0) < steps_ref[2 * n_steps])
     def _():
         xt = x_ref[0].astype(jnp.int32).astype(jnp.float32).astype(
-            jnp.bfloat16)                 # (m, d), exact
+            jnp.bfloat16)                     # (m, d), exact
+        hi, lo = _split_bf16(q_ref[0])
         dims = (((1,), (1,)), ((), ()))
-        ip = jax.lax.dot_general(qhi_ref[:], xt, dims,
+        ip = jax.lax.dot_general(hi, xt, dims,
                                  preferred_element_type=jnp.float32)
-        ip = ip + jax.lax.dot_general(qlo_ref[:], xt, dims,
+        ip = ip + jax.lax.dot_general(lo, xt, dims,
                                       preferred_element_type=jnp.float32)
         dist = -ip if ip_metric else xn_ref[0] - 2.0 * ip
-        _merge_list(dist, ids_ref[0], probes_ref, lid, bestd, besti, k=k,
-                    n_lists=n_lists)
-
-    _emit(j, outd_ref, outi_ref, bestd, besti, n_steps=n_steps,
-          ip_metric=ip_metric)
+        _group_topk(dist, ids_ref, outd_ref, outi_ref, k=k)
 
 
 def _scan_pallas(qf, data, data_norms, indices, probes, filter_words, *,
@@ -550,20 +605,19 @@ def _scan_pallas(qf, data, data_norms, indices, probes, filter_words, *,
 
     q, d = qf.shape
     n_lists, m, _ = data.shape
+    p = probes.shape[1]
     ip_metric = metric == DistanceType.InnerProduct
     if vmem_mb <= 0:
         vmem_mb = vmem_budget_mb()
-    byte_lists = _is_bytes(data.dtype)
     sub = sublane_multiple(data.dtype)
+    g, s = group_shape(q, p, data.shape, data.dtype, k, vmem_mb)
+    uniq, steps, rows, src = _schedule(probes, n_lists, g, s)
 
-    uniq = unique_lists(probes, n_lists)
-    n_steps = uniq.shape[0]
-
-    # gathered id and norm planes, one row per unique list (4 B/slot
-    # each — 1/32 of the d=128 data stream); a shared bitset filter
-    # folds in here: a filtered slot becomes id -1, i.e. padding, so
-    # the kernel needs no per-element word gathers (Mosaic lowers
-    # those to the scalar core)
+    # id and norm planes of the probed lists, one row each in list
+    # order (4 B/slot each, 1/32 of the d=128 float stream); a shared
+    # bitset filter folds in here: a filtered slot becomes id -1, i.e.
+    # padding, so the kernel needs no per-element word gathers
+    # (Mosaic lowers those to the scalar core)
     uc = jnp.minimum(uniq, n_lists - 1)
     ids_g = jnp.take(indices, uc, axis=0)
     xn_g = jnp.take(data_norms, uc, axis=0)
@@ -585,70 +639,61 @@ def _scan_pallas(qf, data, data_norms, indices, probes, filter_words, *,
     # by (8, 128) or equal to the array's, and one list row is (1, m)
     xn_g = xn_g[:, None, :]
     ids_g = ids_g[:, None, :]
-    p = probes.shape[1]
-    p_pad = -(-p // 128) * 128
 
-    # query-tile sizing from the VMEM budget: double-buffered list
-    # block + its widened strip are the fixed cost; per query row the
-    # kernel keeps the query vector, the probe row, the (m) dist/cat
-    # intermediates and the (k) running state
-    budget = (vmem_mb << 20) - _vmem_fixed(m_pad, d_pad, data.dtype)
-    per_q = _vmem_per_query(m_pad, d_pad, p_pad, k, data.dtype)
-    q_tile = min(max(8, (budget // per_q) // 8 * 8), -(-q // 8) * 8)
-    q_pad = -(-q // q_tile) * q_tile
+    # each group's query rows, gathered in step order; row q is zero
+    qs = jnp.pad(qf.astype(jnp.float32), ((0, 1), (0, d_pad - d)))
+    qg = jnp.take(qs, rows, axis=0).reshape(s, g, d_pad)
 
-    qs = jnp.pad(qf.astype(jnp.float32),
-                 ((0, q_pad - q), (0, d_pad - d)))
-    query_ops = _split_bf16(qs) if byte_lists else (qs,)
-    # pad probe rows/cols with -1: a pad query probes nothing, so its
-    # running state stays empty and its rows are sliced away
-    probes_p = jnp.pad(probes.astype(jnp.int32),
-                       ((0, q_pad - q), (0, p_pad - p)),
-                       constant_values=-1)
+    def real(i, st):                      # a tail step keeps the last block
+        return jnp.minimum(i, jnp.maximum(st[2 * s] - 1, 0))
 
     kernel = functools.partial(
-        _ivf_scan_bytes_kernel if byte_lists else _ivf_scan_kernel, k=k,
-        n_steps=n_steps, n_lists=n_lists, ip_metric=ip_metric)
-    clamp = n_lists - 1
+        _ivf_scan_bytes_kernel if _is_bytes(data.dtype)
+        else _ivf_scan_kernel, k=k, n_steps=s, ip_metric=ip_metric)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(q_pad // q_tile, n_steps),
+        grid=(s,),
         in_specs=[
-            pl.BlockSpec((q_tile, p_pad), lambda i, j, u: (i, 0),
+            pl.BlockSpec((1, g, d_pad), lambda i, st: (real(i, st), 0, 0),
                          memory_space=pltpu.VMEM),
-            *[pl.BlockSpec((q_tile, d_pad), lambda i, j, u: (i, 0),
-                           memory_space=pltpu.VMEM) for _ in query_ops],
-            # the scalar-prefetched dynamic index map: step j streams
-            # list u[j]'s block; the sentinel clamps to a real list and
-            # is masked by the membership predicate
+            # the scalar-prefetched dynamic index map: step i streams
+            # list st[i]'s block; consecutive steps of one list keep
+            # the block, so it moves HBM->VMEM once
             pl.BlockSpec((1, m_pad, d_pad),
-                         lambda i, j, u: (jnp.minimum(u[j], clamp), 0, 0),
+                         lambda i, st: (st[i], 0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, m_pad), lambda i, j, u: (j, 0, 0),
+            pl.BlockSpec((1, 1, m_pad), lambda i, st: (st[s + i], 0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1, m_pad), lambda i, j, u: (j, 0, 0),
+            pl.BlockSpec((1, 1, m_pad), lambda i, st: (st[s + i], 0, 0),
                          memory_space=pltpu.VMEM),
         ],
         out_specs=(
-            pl.BlockSpec((q_tile, k), lambda i, j, u: (i, 0),
+            pl.BlockSpec((1, g, k), lambda i, st: (real(i, st), 0, 0),
                          memory_space=pltpu.VMEM),
-            pl.BlockSpec((q_tile, k), lambda i, j, u: (i, 0),
+            pl.BlockSpec((1, g, k), lambda i, st: (real(i, st), 0, 0),
                          memory_space=pltpu.VMEM),
         ),
-        scratch_shapes=[
-            pltpu.VMEM((q_tile, k), jnp.float32),
-            pltpu.VMEM((q_tile, k), jnp.int32),
-        ],
     )
     outd, outi = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=(
-            jax.ShapeDtypeStruct((q_pad, k), jnp.float32),
-            jax.ShapeDtypeStruct((q_pad, k), jnp.int32),
+            jax.ShapeDtypeStruct((s, g, k), jnp.float32),
+            jax.ShapeDtypeStruct((s, g, k), jnp.int32),
         ),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_mb << 20),
         interpret=interpret,
-    )(uniq, probes_p, *query_ops, data, xn_g, ids_g)
-    return outd[:q], outi[:q]
+    )(steps, qg, data, xn_g, ids_g)
+
+    # each pair's (k) block back to its row, then the row's top-k of
+    # its p*k candidates under the kernel's (distance, id) order
+    ok = (src >= 0)[:, None]
+    at = jnp.maximum(src, 0)
+    cand_d = jnp.where(ok, outd.reshape(s * g, k)[at], jnp.inf)
+    cand_i = jnp.where(ok, outi.reshape(s * g, k)[at], -1)
+    best_d, best_i = _smallest_k(cand_d.reshape(q, p * k),
+                                 cand_i.reshape(q, p * k), k)
+    if ip_metric:
+        best_d = -best_d          # inf (unfilled) -> -inf, ip exact
+    return best_d, best_i

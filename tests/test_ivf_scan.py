@@ -161,8 +161,8 @@ class TestEngineParity:
 
     def test_multiple_query_tiles_in_kernel(self, data, indexes,
                                             monkeypatch):
-        """A tiny VMEM budget forces the kernel's query-tile grid
-        dimension > 1; results must not depend on the tiling."""
+        """A tiny VMEM budget forces the kernel's smallest query group
+        (8 rows); results must not depend on the group size."""
         _, q = data
         index = indexes[DistanceType.L2Expanded]
         want_d, want_i = _run(index, q, 10, "pallas")
@@ -469,3 +469,249 @@ class TestRaggedFront:
         counts = probe_histogram(masked,
                                  jnp.zeros((index.n_lists,), jnp.int32))
         assert int(np.asarray(counts).sum()) == 3 * 4 + 2 * 8
+
+
+# ---------------------------------------------------------------------------
+# the grouped Pallas scan: (list, query-group) steps
+# ---------------------------------------------------------------------------
+
+SCAN_DTYPES = ["float32", "bfloat16", "uint8", "int8"]
+N_LISTS, M_SLOTS, DIM = 12, 40, 24
+
+
+def _scan_inputs(dtype, q=21, p=4, seed=0):
+    """Lists of ``dtype`` with pad slots, and ``q`` (not a multiple of
+    8) queries probing ``p`` distinct lists each. Byte lists get
+    byte-valued queries, whose distances both engines give exactly."""
+    import jax.numpy as jnp
+
+    rng = np.random.default_rng(seed)
+    if dtype in ("uint8", "int8"):
+        lo, hi = (0, 256) if dtype == "uint8" else (-128, 128)
+        data = rng.integers(lo, hi, (N_LISTS, M_SLOTS, DIM)).astype(dtype)
+        queries = rng.integers(lo, hi, (q, DIM)).astype(np.float32)
+    else:
+        data = np.asarray(jnp.asarray(
+            rng.standard_normal((N_LISTS, M_SLOTS, DIM)), dtype))
+        queries = rng.standard_normal((q, DIM)).astype(np.float32)
+    ids = np.arange(N_LISTS * M_SLOTS, dtype=np.int32).reshape(
+        N_LISTS, M_SLOTS)
+    ids[:, 35:] = -1                                     # pad slots
+    norms = np.where(ids >= 0, (np.asarray(data, np.float64) ** 2).sum(-1),
+                     np.inf).astype(np.float32)
+    probes = np.stack([rng.choice(N_LISTS, p, replace=False)
+                       for _ in range(q)]).astype(np.int32)
+    return queries, data, norms, ids, probes
+
+
+def _both_engines(queries, data, norms, ids, probes, k, metric,
+                  filter_words=None):
+    """``{engine: (distances, ids)}`` of the jitted list-major scan."""
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from raft_tpu.ops.ivf_scan import list_major_scan
+
+    out = {}
+    for engine in ("pallas", "xla"):
+        fn = jax.jit(functools.partial(
+            list_major_scan, k=k, metric=metric, engine=engine,
+            interpret=True))
+        d, i = fn(jnp.asarray(queries), jnp.asarray(data),
+                  jnp.asarray(norms), jnp.asarray(ids), jnp.asarray(probes),
+                  filter_words)
+        out[engine] = (np.asarray(d), np.asarray(i))
+    return out
+
+
+def _assert_bit_identical(out):
+    np.testing.assert_array_equal(out["pallas"][1], out["xla"][1])
+    np.testing.assert_array_equal(out["pallas"][0], out["xla"][0])
+
+
+def _real_steps(probes, n_lists, g, s):
+    from raft_tpu.ops.ivf_scan import _schedule
+
+    _, steps, rows, src = _schedule(probes, n_lists, g, s)
+    steps = np.asarray(steps)
+    return steps[:s], int(steps[2 * s]), np.asarray(rows), np.asarray(src)
+
+
+class TestGroupedScan:
+    @pytest.mark.parametrize("k", [1, 10, 128])
+    @pytest.mark.parametrize("metric", [DistanceType.L2Expanded,
+                                        DistanceType.InnerProduct])
+    @pytest.mark.parametrize("dtype", SCAN_DTYPES)
+    def test_bit_identical_to_xla(self, dtype, metric, k):
+        """Ids and distances equal the XLA engine's, bit for bit, for
+        every list dtype, both metrics and k from 1 to the unrolled cap
+        (k = 128 exceeds the 3 x 35 rows a query probes: -1 fills)."""
+        out = _both_engines(*_scan_inputs(dtype, p=3), k, metric)
+        _assert_bit_identical(out)
+        if k == 128:
+            assert (out["pallas"][1] == -1).any()
+
+    @pytest.mark.parametrize("q", [21, 64])
+    def test_one_list_probed_by_every_query(self, q):
+        """A list every query probes takes ceil(q / G) consecutive steps
+        of that list, and the results still equal the XLA engine's."""
+        from raft_tpu.ops.ivf_scan import group_shape
+
+        queries, data, norms, ids, probes = _scan_inputs("float32", q=q)
+        probes[:, 0] = 3
+        probes[:, 1:] = np.where(probes[:, 1:] == 3, 4, probes[:, 1:])
+        probes[:, 1:] = np.where(probes[:, 1:] == 4,
+                                 (probes[:, 1:] + 1) % N_LISTS,
+                                 probes[:, 1:])
+        g, s = group_shape(q, 4, data.shape, data.dtype, 10)
+        lists, n_real, _, _ = _real_steps(probes, N_LISTS, g, s)
+        assert (lists[:n_real] == 3).sum() == -(-q // g)
+        _assert_bit_identical(_both_engines(
+            queries, data, norms, ids, probes, 10,
+            DistanceType.L2Expanded))
+
+    @pytest.mark.parametrize("case", ["ragged_budget_0", "not_owned"])
+    def test_sentinel_rows(self, case):
+        """Rows whose every probe is the sentinel (a ragged row of
+        budget 0) or some of whose probes are (probes another shard of
+        a list-sharded index owns) take no pair for those probes; an
+        all-sentinel row comes back empty."""
+        from raft_tpu.ops.ivf_scan import ragged_probes
+
+        queries, data, norms, ids, probes = _scan_inputs("uint8")
+        if case == "ragged_budget_0":
+            budget = np.resize([4, 0, 2], len(probes)).astype(np.int32)
+            probes = np.asarray(ragged_probes(probes, budget, N_LISTS))
+            empty = budget == 0
+        else:
+            rng = np.random.default_rng(5)
+            owned = rng.random(probes.shape) < 0.5
+            owned[0] = False
+            probes = np.where(owned, probes, N_LISTS).astype(np.int32)
+            empty = ~owned.any(axis=1)
+        out = _both_engines(queries, data, norms, ids, probes, 10,
+                            DistanceType.L2Expanded)
+        _assert_bit_identical(out)
+        assert empty.any()
+        assert (out["pallas"][1][empty] == -1).all()
+        assert (out["pallas"][0][empty] == np.inf).all()
+
+    @pytest.mark.parametrize("metric", [DistanceType.L2Expanded,
+                                        DistanceType.InnerProduct])
+    def test_shared_bitset(self, metric):
+        """A shared 1-D bitset folds into the id planes; filtered ids
+        never surface and the results equal the XLA engine's."""
+        from raft_tpu.neighbors.filters import resolve_filter_words
+
+        queries, data, norms, ids, probes = _scan_inputs("float32")
+        keep = np.arange(N_LISTS * M_SLOTS) % 3 != 0
+        words = resolve_filter_words(Bitset.from_mask(keep))
+        out = _both_engines(queries, data, norms, ids, probes, 10, metric,
+                            filter_words=words)
+        _assert_bit_identical(out)
+        got = out["pallas"][1]
+        assert (got[got >= 0] % 3 != 0).all()
+
+    @pytest.mark.parametrize("seed", range(6))
+    def test_real_steps_within_the_static_cap(self, seed):
+        """On random, skewed probe blocks (sentinels and repeats mixed
+        in) the real steps never exceed S, every step holds at most G
+        rows of one list, and every kept pair's slot is in a real step
+        of its own list and row."""
+        from raft_tpu.ops.ivf_scan import group_shape
+
+        rng = np.random.default_rng(seed)
+        n_lists = int(rng.choice([4, 16, 64]))
+        q, p = int(rng.integers(1, 70)), int(rng.integers(1, 9))
+        # skew: most probes land on a few lists
+        hot = rng.integers(0, n_lists, 3)
+        probes = np.where(rng.random((q, p)) < 0.6,
+                          rng.choice(hot, (q, p)),
+                          rng.integers(0, n_lists + 1, (q, p)))
+        probes = probes.astype(np.int32)
+        g, s = group_shape(q, p, (n_lists, 40, 24), np.float32, 10)
+        lists, n_real, rows, src = _real_steps(probes, n_lists, g, s)
+        assert 0 <= n_real <= s
+        flat = probes.reshape(-1)
+        for j in np.flatnonzero(src >= 0):
+            step, row = divmod(int(src[j]), g)
+            assert step < n_real
+            assert lists[step] == flat[j]
+            assert rows[src[j]] == j // p
+        # kept pairs: each real (row, list) probe once, sentinels none
+        want = {(j // p, int(flat[j])) for j in range(flat.size)
+                if flat[j] < n_lists}
+        assert len(want) == int((src >= 0).sum())
+        assert len(set(src[src >= 0].tolist())) == len(want)
+
+    @pytest.mark.parametrize("where", ["single_chip", "mesh"])
+    def test_bucket_pad_rows_add_no_pair(self, data, indexes, monkeypatch,
+                                         where):
+        """The executor hands the search its real row count, so the
+        probes of bucket-pad rows reach the grouped scan as sentinels:
+        13 rows in a 16-row bucket, three pad rows, no pad pair — on one
+        chip and on every shard of a list-sharded index."""
+        import jax
+
+        from raft_tpu.comms import local_comms
+        from raft_tpu.distributed import ivf as dist_ivf
+        from raft_tpu.ops import ivf_scan
+
+        seen = []
+        module = ivf_scan if where == "single_chip" else dist_ivf
+        scan = module.list_major_scan
+
+        def spy(qf, data_, norms, ids, probes, *a, **kw):
+            jax.debug.callback(lambda pr: seen.append(np.asarray(pr)),
+                               probes)
+            return scan(qf, data_, norms, ids, probes, *a, **kw)
+
+        monkeypatch.setattr(module, "list_major_scan", spy)
+        x, q = data
+        index = indexes[DistanceType.L2Expanded]
+        n_lists = index.n_lists
+        if where == "mesh":
+            comms = local_comms()
+            index = dist_ivf.build(None, comms,
+                                   IvfFlatIndexParams(n_lists=16), x)
+            n_lists = index.n_lists // comms.size
+        p = IvfFlatSearchParams(n_probes=5, scan_engine="pallas")
+        ex = SearchExecutor(min_bucket=16, max_bucket=16)
+        d, i = ex.search(index, q[:13], 10, params=p)
+        jax.effects_barrier()
+        assert seen
+        for probes in seen:
+            assert probes.shape == (16, 5)
+            assert (probes[13:] == n_lists).all()
+        if where == "single_chip":
+            assert (seen[0][:13] < n_lists).all()
+        want_d, want_i = _run(indexes[DistanceType.L2Expanded], q[:13],
+                              10, "xla")
+        np.testing.assert_array_equal(np.asarray(i), want_i)
+        np.testing.assert_array_equal(np.asarray(d), want_d)
+        (info,) = ex.executable_costs().values()
+        assert info["engine"] == "pallas"
+        shape = (n_lists,) + tuple(index.data.shape[1:])
+        assert (info["scan_group_rows"], info["scan_steps"]) == \
+            ivf_scan.group_shape(16, 5, shape, index.data.dtype, 10)
+
+    @pytest.mark.parametrize("engine,grouped", [("pallas", 1), ("xla", 0)])
+    def test_grouped_dispatch_counter(self, data, indexes, engine,
+                                      grouped):
+        """``serving.scan.grouped_dispatches`` counts the dispatches of
+        executables that run the grouped Pallas scan, and only those."""
+        _, q = data
+        index = indexes[DistanceType.L2Expanded]
+        p = IvfFlatSearchParams(n_probes=5, scan_engine=engine)
+        ex = SearchExecutor()
+        ex.search(index, q[:9], 10, params=p)
+        before = tracing.get_counter("serving.scan.grouped_dispatches")
+        calls = tracing.get_counter("serving.execute.calls")
+        for n in (9, 16, 33):
+            ex.search(index, q[:n], 10, params=p)
+        calls = tracing.get_counter("serving.execute.calls") - calls
+        after = tracing.get_counter("serving.scan.grouped_dispatches")
+        assert calls >= 3
+        assert after - before == grouped * calls

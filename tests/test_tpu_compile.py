@@ -84,7 +84,7 @@ def test_select_k_tiles(sds):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.uint8])
 def test_ivf_scan(sds, dtype):
     from raft_tpu.ops.ivf_scan import _scan_pallas
 

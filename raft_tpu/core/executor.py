@@ -20,12 +20,10 @@ peak-throughput recipe already cited in ``matrix/select_k.py``:
   traffic arrives, and the persistent compilation cache
   (:func:`~raft_tpu.core.resources.init_compile_cache`) makes that
   warmup survive process restarts.
-- **Donated top-k state**: the running (k-best values, ids) buffers are
-  owned by the executor and donated to each call, so the scan state
-  reuses one HBM allocation across calls instead of re-allocating (and
-  the result write aliases the donated input). Donation is on by
-  default on TPU/GPU backends; CPU ignores donation, so it is off
-  there unless forced.
+- **Stateless calls**: a serving call takes the queries and the
+  index arrays and allocates its own running top-k carry, so its
+  outputs alias nothing the next call reads; each dispatch enqueues
+  under the executor lock and its results are fetched after it.
 
 Counters (compile count, cache hits/misses, evictions, warmup seconds)
 are exported through :mod:`raft_tpu.core.tracing` under the
@@ -52,9 +50,9 @@ Supported index types: ``BruteForceIndex``, ``IvfFlatIndex``,
 ``IvfPqIndex``, ``IvfBqIndex``, ``CagraIndex``, and the mesh-sharded
 ``DistributedIvfFlat`` / ``DistributedIvfPq`` / ``DistributedIvfBq``
 (AOT-compiled per (mesh, index shapes, params, resolved scan engine,
-bucket): queries bucket exactly like the single-chip families, are
-placed replicated on the mesh, and the per-shard running top-k state
-is donated — steady-state multi-chip serving is zero-recompile).
+bucket): queries bucket exactly like the single-chip families and are
+placed replicated on the mesh — steady-state multi-chip serving is
+zero-recompile).
 
 **Ragged packed-batch plans (PR 9).** The bucket ladder trades pad
 compute for shape stability: every batch pow2-rounds (up to ~2x pad
@@ -77,7 +75,7 @@ tile boundaries.
 **One ragged family for the whole index zoo (PR 15, graftragged).**
 The ragged plan DERIVES from each family's bucketed plan
 (:meth:`SearchExecutor._plan_ragged`): same arrays, statics, probe
-plumbing, shardings and donation split, with the serving fn swapped
+plumbing and shardings, with the serving fn swapped
 for a thin wrapper that turns on the ``row_probes`` budget hook in
 the SAME search body. Every IVF family — flat, PQ, BQ, single-chip
 and list-sharded mesh — serves ragged through the one shared
@@ -216,15 +214,11 @@ def _search_results(tiles, sizes, ks=None):
         return out
 
 
-def _device_cut(out_d, out_i, q: int, copy: bool = False):
+def _device_cut(out_d, out_i, q: int):
     """:meth:`SearchExecutor.search`'s slice stage: a bucket's outputs
-    cut on the device to the ``q`` real rows (``copy``: copied whole
-    instead, for a full bucket whose outputs alias donated state) —
-    eager programs, counted."""
+    cut on the device to the ``q`` real rows — eager programs,
+    counted."""
     with tracing.host_span(SLICE_SPAN, hist=SLICE):
-        if copy:
-            _count_eager(2)
-            return jnp.copy(out_d), jnp.copy(out_i)
         (d, nd), (i, ni) = _take_rows(out_d, 0, q), _take_rows(out_i, 0, q)
         _count_eager(nd + ni)
         return d, i
@@ -252,9 +246,8 @@ class ExecutorStats:
 class _Plan:
     """Everything needed to compile and call one bucket specialization.
 
-    Call argument order is ``(*pre, queries, *post, [filter_words],
-    [init_d, init_i])`` — matching each family's serving function
-    signature."""
+    Call argument order is ``(*pre, queries, *post, [filter_words])``
+    — matching each family's serving function signature."""
 
     key: tuple
     fn: Callable
@@ -262,15 +255,13 @@ class _Plan:
     pre: tuple = ()
     post: tuple = ()
     use_filter: bool = False
-    has_state: bool = True
     qdtype: Any = jnp.float32
     qdim: int = 0
     # mesh-sharded (distributed) plans: abstract avals carry the index
-    # arrays' NamedShardings, padded queries and the donated state
-    # buffers are placed with these shardings before the call
+    # arrays' NamedShardings, and the padded queries (and the n_valid
+    # scalar) are placed with this sharding before the call
     sharded: bool = False
     qsharding: Any = None
-    state_sharding: Any = None
     # distributed plans carry their modeled per-shard collective
     # payload as (family, thunk returning the collective_payload_model
     # dict) — evaluated and published as gauges only on a compile miss,
@@ -317,13 +308,12 @@ class _Plan:
 
 
 class _Entry:
-    __slots__ = ("compiled", "state", "cost", "digest", "family",
+    __slots__ = ("compiled", "cost", "digest", "family",
                  "payload_model", "wire_bytes", "grouped")
 
-    def __init__(self, compiled, state, cost=None, digest="",
+    def __init__(self, compiled, cost=None, digest="",
                  family="", payload_model=None, grouped=None):
         self.compiled = compiled
-        self.state = state
         self.cost = cost or {}
         self.digest = digest
         self.family = family
@@ -502,8 +492,6 @@ class SearchExecutor:
         ragged tail padded into the bucket (all tiles dispatched before
         any result is fetched).
       max_entries: LRU capacity of the executable cache.
-      donate: donate the running top-k state buffers to each call.
-        Default: enabled on backends that implement donation (not CPU).
       mesh_trace: record graftscope-v2 mesh spans around every
         mesh-sharded dispatch — the three modeled phase spans
         (coarse select / scan / merge, bytes from the entry's
@@ -521,10 +509,10 @@ class SearchExecutor:
         accounting for the IVF families (single-chip and mesh): each
         dispatch scatter-adds its selected probe ids into a donated
         per-index int32 counter plane inside the compiled program —
-        the plane threads through calls exactly like the donated top-k
-        state, so steady state stays zero-recompile and search results
-        stay bit-identical (the results never read the plane). The
-        counters are fetched ONLY at scrape time
+        the plane threads through calls, so steady state stays
+        zero-recompile and search results stay bit-identical (the
+        results never read the plane). The counters are fetched ONLY
+        at scrape time
         (:meth:`probe_frequencies` / :meth:`publish_probe_gauges` —
         one device fetch per plane per scrape, never per dispatch).
         Default off: enabling changes the compiled signature, so it is
@@ -546,8 +534,7 @@ class SearchExecutor:
 
     def __init__(self, res: Optional[Resources] = None, *,
                  min_bucket: int = 8, max_bucket: int = 4096,
-                 max_entries: int = 64, donate: Optional[bool] = None,
-                 mesh_trace: bool = False,
+                 max_entries: int = 64, mesh_trace: bool = False,
                  probe_accounting: bool = False,
                  ragged_tile: int = 256,
                  ragged_tile_small: Optional[int] = None):
@@ -563,9 +550,6 @@ class SearchExecutor:
         buckets.append(max_bucket)
         self.buckets: Tuple[int, ...] = tuple(buckets)
         self.max_entries = max_entries
-        if donate is None:
-            donate = jax.default_backend() not in ("cpu",)
-        self.donate = donate
         expect(ragged_tile > 0, "ragged_tile must be > 0")
         expect(ragged_tile_small is None
                or 0 < ragged_tile_small < ragged_tile,
@@ -609,8 +593,8 @@ class SearchExecutor:
         # JSON-snapshot view of the per-executable cost gauges
         self._cost_table: dict = {}  # guarded-by: _lock
         # multi-threaded frontends share one executor: the cache and
-        # the donated per-entry state buffers must hand off atomically
-        # (two threads donating the same state would hit jax's
+        # the donated probe planes must hand off atomically (two
+        # threads donating the same plane would hit jax's
         # deleted-array error). Dispatch is async, so holding the lock
         # through the executable call serializes only enqueueing.
         self._lock = threading.RLock()
@@ -873,12 +857,11 @@ class SearchExecutor:
         results back per block. Serves every raggable family through
         the same locked dispatch core: single-chip IVF flat/PQ/BQ and
         the list-sharded mesh families (whose packed tile and budget
-        plane place replicated, with the donated per-shard top-k
-        state and the list-sharded probe plane threaded exactly as
-        bucketed mesh plans thread them; ``kw`` carries the mesh wire
-        knobs). ``mesh_trace`` span recording is a bucketed-dispatch
-        feature — ragged mesh dispatches skip it (the batcher's stage
-        spans still cover the packed call).
+        plane place replicated, with the list-sharded probe plane
+        threaded exactly as bucketed mesh plans thread it; ``kw``
+        carries the mesh wire knobs). ``mesh_trace`` span recording is
+        a bucketed-dispatch feature — ragged mesh dispatches skip it
+        (the batcher's stage spans still cover the packed call).
 
         ``blocks`` is a sequence of (m_j, dim) query arrays; ``ks``
         and ``params_list`` give each block's ``k`` / search params (a
@@ -904,9 +887,7 @@ class SearchExecutor:
         resurrecting through the back door the shape churn the ONE
         packed executable exists to kill. The serving batcher hands
         every result to a caller that reads it on the host, so the
-        fetch costs what the caller was about to pay anyway; like the
-        bucketed path, a donated-state tile is fetched before anything
-        else can re-donate its outputs."""
+        fetch costs what the caller was about to pay anyway."""
         expect(len(blocks) > 0, "search_ragged needs at least one block")
         # the prepare stage ends inside the locked core, where the
         # first tile's enqueue begins
@@ -1012,18 +993,10 @@ class SearchExecutor:
                         f"serving.execute.rows.{split}": q_real,
                         f"serving.execute.padded_rows.{split}": tile,
                     })
-                    if plan.has_state:
-                        # donated-state (xla) engine: the outputs ARE the
-                        # state the next chunk (or the next caller)
-                        # immediately re-donates, so they are fetched
-                        # before the lock releases
-                        ((out_d, out_i),) = _search_results(
-                            [(out_d, out_i, q_real)], [q_real])
-                    # a stateless (pallas) engine's outputs alias
-                    # nothing, so only ENQUEUE happens under the lock —
-                    # every tile dispatches before anything is fetched,
-                    # and concurrent searches/scrapes are not blocked
-                    # for a device execution
+                    # only ENQUEUE happens under the lock — every tile
+                    # dispatches before anything is fetched, and
+                    # concurrent searches/scrapes are not blocked for a
+                    # device execution
                     outs.append((out_d, out_i, q_real))
         # per-request k: a column slice of the class-cap top-k — the
         # merge is a total order, so the first k_j columns ARE the solo
@@ -1224,7 +1197,7 @@ class SearchExecutor:
         deletion this PR exists for: the plan DERIVES from the
         family's bucketed plan at the params-class caps (same arrays,
         same statics minus the pinned-exact ``coarse_algo``, same
-        probe plumbing, same shardings/donation/payload model), with
+        probe plumbing, same shardings/payload model), with
         the serving fn swapped for the family's ragged twin and the
         family tag marked ``_ragged``. No per-family ragged plan code
         paths remain — a family change lands in ONE builder and both
@@ -1305,11 +1278,9 @@ class SearchExecutor:
         """One bucketed dispatch of ``blocks`` (one batch of at most
         the top bucket's rows): the output tile ``(distances, indices,
         q)``, whose first ``q`` rows are the blocks' results. With
-        ``host``, outputs that alias donated state come back fetched
-        to the host and others as the device arrays, possibly still
-        computing (:func:`_search_results` reads them); without, both
-        come back cut on the device to the ``q`` rows."""
-        ret = None
+        ``host``, the outputs come back as the device arrays, possibly
+        still computing (:func:`_search_results` reads them); without,
+        cut on the device to the ``q`` rows."""
         # the prepare stage ends inside the locked core, where the
         # enqueue begins: waiting for the lock is part of preparing
         with tracing.host_span(PREPARE_SPAN, hist=PREPARE) as prepare:
@@ -1337,20 +1308,6 @@ class SearchExecutor:
             with self._lock:
                 entry, out_d, out_i, t0 = self._execute_entry_locked(
                     plan, rows, k, args, q, prepare=prepare)
-                if plan.has_state and self.donate:
-                    # outputs alias the donated state storage: the
-                    # fetch (or, on the device, the result slice or at
-                    # full bucket a copy — the un-padded slice would BE
-                    # the state arrays) must happen before the lock
-                    # releases, or a concurrent dispatch of the same
-                    # plan could re-donate the buffers first
-                    if host:
-                        ((d, i),) = _search_results(
-                            [(out_d, out_i, q)], [q])
-                    else:
-                        d, i = _device_cut(out_d, out_i, q,
-                                           copy=q == rows)
-                    ret = (d, i, q)
         # mesh recording AFTER the lock releases: the readiness poll
         # lasts as long as the slowest shard, and holding the executor
         # lock through it would stall OTHER threads — concurrent
@@ -1362,8 +1319,6 @@ class SearchExecutor:
         if plan.sharded and self.mesh_trace:
             self._record_mesh_dispatch(entry, out_d, out_i, t0,
                                        trace_ids)
-        if ret is not None:
-            return ret
         if host:
             return out_d, out_i, q
         return _device_cut(out_d, out_i, q) + (q,)
@@ -1371,17 +1326,12 @@ class SearchExecutor:
     def _execute_entry_locked(self, plan: _Plan, rows: int, k: int,
                               args, q_real: int, prepare=None):
         """Shared locked dispatch core of the bucketed and ragged
-        paths: entry fetch/compile, donated top-k state + graftgauge
-        probe-plane threading, and the modeled-work counters. The
-        caller holds ``self._lock`` (RLock) and has assembled ``args``
-        up to (but not including) the donated state; its open
+        paths: entry fetch/compile, graftgauge probe-plane threading,
+        and the modeled-work counters. The caller holds ``self._lock``
+        (RLock) and has assembled the positional ``args``; its open
         ``prepare`` span ends where the enqueue span begins. Returns
-        ``(entry, out_d, out_i, t0)``; with ``plan.has_state`` the
-        outputs ARE the next call's donated state — the caller must
-        fetch, slice or copy them before anything re-donates."""
+        ``(entry, out_d, out_i, t0)``."""
         entry = self._get_entry_locked(plan, rows, k)
-        if plan.has_state:
-            args = list(args) + list(entry.state)
         kwargs = {}
         eager = 0
         if plan.probe is not None:
@@ -1389,8 +1339,7 @@ class SearchExecutor:
             # plane + the valid-row count (traced scalar — inert
             # bucket-pad rows must not pollute the histogram).
             # Created lazily on first dispatch; the lock serializes
-            # the donate-and-replace handoff exactly like the
-            # top-k state's.
+            # the donate-and-replace handoff.
             pkey, n_lists, csharding, family, label = plan.probe[:5]
             counts = self._probe_state.get(pkey)
             if counts is None:
@@ -1414,8 +1363,8 @@ class SearchExecutor:
                     pass
             nv = jnp.asarray(q_real, jnp.int32)
             eager += 1
-            if plan.state_sharding is not None:
-                nv = jax.device_put(nv, plan.state_sharding)
+            if plan.qsharding is not None:
+                nv = jax.device_put(nv, plan.qsharding)
             kwargs = {"probe_counts": counts, "n_valid": nv}
         elif plan.valid_rows:
             # a host scalar: its transfer rides the call itself
@@ -1465,10 +1414,6 @@ class SearchExecutor:
             # sync, no traced op, degrades to a counter bump on
             # backends without live stats
             self._memwatch.sample_dispatch()
-        if plan.has_state:
-            # outputs alias the donated state storage; keep them as
-            # the next call's state
-            entry.state = (out_d, out_i)
         return entry, out_d, out_i, t0
 
     def _record_mesh_dispatch(self, entry, out_d, out_i, t0: float,
@@ -1486,18 +1431,10 @@ class SearchExecutor:
         Arrival times come from the shared non-blocking poll
         (:func:`raft_tpu.core.tracing.poll_shard_timings` — see there
         for why sequential blocking would hide early-ordinal
-        stragglers, and for the donated-buffer tolerance the
-        outside-the-lock poll needs)."""
-        try:
-            shards = [(sd.data, si.data)
-                      for sd, si in zip(out_d.addressable_shards,
-                                        out_i.addressable_shards)]
-        except RuntimeError:
-            # donated-state plans: a concurrent re-dispatch consumed
-            # the output buffers before we could even enumerate the
-            # shards — nothing left to time, skip this dispatch's
-            # recording rather than failing the caller's search
-            return
+        stragglers)."""
+        shards = [(sd.data, si.data)
+                  for sd, si in zip(out_d.addressable_shards,
+                                    out_i.addressable_shards)]
         timings = tracing.poll_shard_timings(shards, t0,
                                              poll_s=_MESH_POLL_S)
         phases = None
@@ -1545,19 +1482,12 @@ class SearchExecutor:
         # events correlate back to exactly this entry (graftflight)
         digest = hashlib.sha1(repr(plan.key).encode()).hexdigest()[:12]
         t0 = time.perf_counter()
-        compiled = self._compile(plan, bucket, k,
+        compiled = self._compile(plan, bucket,
                                  module=f"rt_{plan.key[0]}_{digest}")
         dt = time.perf_counter() - t0
         self.stats.compile_count += 1
         tracing.inc_counter("serving.compile_count")
         tracing.inc_counter("serving.compile_seconds", dt)
-        state = None
-        if plan.has_state:
-            state = (jnp.zeros((bucket, k), jnp.float32),
-                     jnp.zeros((bucket, k), jnp.int32))
-            if plan.state_sharding is not None:
-                state = tuple(jax.device_put(s, plan.state_sharding)
-                              for s in state)
         # cost introspection happens HERE — compile time, once per
         # executable — so the per-dispatch accounting below is a plain
         # dict read with zero device interaction
@@ -1589,7 +1519,7 @@ class SearchExecutor:
         grouped = None
         if plan.key[0].removesuffix("_ragged") in _GROUPED_FAMILIES:
             grouped = plan.grouped_scan is not None
-        ent = _Entry(compiled, state, cost=cost, digest=digest,
+        ent = _Entry(compiled, cost=cost, digest=digest,
                      family=plan.key[0], payload_model=payload_model,
                      grouped=grouped)
         self._cache[plan.key] = ent
@@ -1633,29 +1563,22 @@ class SearchExecutor:
 
     def memory_reservations(self) -> dict:
         """The executor-owned terms of graftledger's reservation
-        forecast, per device ordinal: the donated running top-k state
-        buffers of every cached entry, the graftgauge probe planes,
-        and the max compile-time ``temp_bytes`` over the resident
+        forecast, per device ordinal: the graftgauge probe planes, and
+        the max compile-time ``temp_bytes`` over the resident
         executables (any dispatch may be the one that peaks). Pure
         host-side metadata read under the executor lock — shapes,
         dtypes and the compile-time cost table; no device fetch."""
         from raft_tpu.core.memwatch import per_device_bytes
 
-        donated: dict = {}
         planes: dict = {}
         with self._lock:
-            for ent in self._cache.values():
-                if ent.state is not None:
-                    for arr in ent.state:
-                        per_device_bytes(arr, donated)
             for arr in self._probe_state.values():
                 per_device_bytes(arr, planes)
             max_temp = max(
                 (float(info.get("temp_bytes", 0.0))
                  for info in self._cost_table.values()), default=0.0)
             n = len(self._cache)
-        return {"donated_state_bytes": donated,
-                "probe_plane_bytes": planes,
+        return {"probe_plane_bytes": planes,
                 "max_temp_bytes": max_temp,
                 "executables": n}
 
@@ -1682,14 +1605,13 @@ class SearchExecutor:
                                        info["collective_payload"])
         tracing.set_gauges(vals)
 
-    def _compile(self, plan: _Plan, bucket: int, k: int,
+    def _compile(self, plan: _Plan, bucket: int,
                  module: Optional[str] = None):
+        # the probe plane is donated where the backend implements
+        # donation (CPU does not)
         donate = ()
-        if self.donate:
-            if plan.has_state:
-                donate += ("init_d", "init_i")
-            if plan.probe is not None:
-                donate += ("probe_counts",)
+        if plan.probe is not None and jax.default_backend() != "cpu":
+            donate = ("probe_counts",)
         fn = plan.fn if module is None else _named_fn(plan.fn, module)
         jitted = jax.jit(fn, static_argnames=tuple(plan.static),
                          donate_argnames=donate)
@@ -1710,25 +1632,20 @@ class SearchExecutor:
                 _, ndim, width, dt = fw_spec
                 shape = (bucket, width) if ndim == 2 else (width,)
                 args.append(jax.ShapeDtypeStruct(shape, np.dtype(dt)))
-        if plan.has_state:
-            args.append(jax.ShapeDtypeStruct((bucket, k), jnp.float32,
-                                             sharding=plan.state_sharding))
-            args.append(jax.ShapeDtypeStruct((bucket, k), jnp.int32,
-                                             sharding=plan.state_sharding))
         kwargs = {}
         if plan.probe is not None:
             # graftgauge counter plane + valid-row scalar ride as
-            # KEYWORD avals: several plans skip the optional init_d /
-            # init_i positionals, so a positional plane would slide
-            # into the wrong parameter slot
+            # KEYWORD avals: the mesh plans take no filter_words
+            # positional, so a positional plane would slide into
+            # another parameter slot
             _, n_lists, csharding = plan.probe[:3]
             kwargs["probe_counts"] = jax.ShapeDtypeStruct(
                 (n_lists,), jnp.int32, sharding=csharding)
             kwargs["n_valid"] = jax.ShapeDtypeStruct(
-                (), jnp.int32, sharding=plan.state_sharding)
+                (), jnp.int32, sharding=plan.qsharding)
         elif plan.valid_rows:
             kwargs["n_valid"] = jax.ShapeDtypeStruct(
-                (), jnp.int32, sharding=plan.state_sharding)
+                (), jnp.int32, sharding=plan.qsharding)
         return jitted.lower(*args, **kwargs, **plan.static).compile()
 
     # -- graftgauge probe-frequency surface ---------------------------------
@@ -1949,7 +1866,7 @@ class SearchExecutor:
         """ONE plan builder for the three list-sharded families —
         they share everything but the per-family statics/arrays, so
         the shared mesh plumbing (probe budget, mesh key, replicated
-        query/state shardings, list-sharded probe plane, payload
+        query sharding, list-sharded probe plane, payload
         model) lives exactly once. The ragged plan family derives
         from this same builder (:meth:`_plan_ragged`), which is what
         retired the per-family bucketed/ragged plan-path copies."""
@@ -1982,11 +1899,6 @@ class SearchExecutor:
             extra, key_extra = {}, ()
             arrays = (index.centers, index.data, index.data_norms,
                       index.indices)
-            # same engine/donation split as the single-chip plans: the
-            # rank and XLA list-major scans thread the donated
-            # per-shard (q, k) state through HBM; the grouped Pallas
-            # scan carries none
-            has_state = engine != "pallas"
         elif isinstance(index, DistributedIvfPq):
             family, fn = "dist_ivf_pq", dist_ivf._dist_search_pq_fn
             params = params or ivf_pq.IvfPqSearchParams()
@@ -2000,9 +1912,6 @@ class SearchExecutor:
             key_extra = ()
             arrays = (index.centers, index.rotation, index.codebooks,
                       index.codes, index.indices)
-            # both PQ scan engines build their carry from the donated
-            # init buffers
-            has_state = True
         else:
             from raft_tpu.ops.bq_scan import resolve_bq_engine
 
@@ -2019,7 +1928,6 @@ class SearchExecutor:
             arrays = (index.centers, index.rotation, index.codes,
                       index.rnorm, index.cfac, index.errw,
                       index.indices, index.data, index.data_norms)
-            has_state = engine != "pallas"
         rows = bucket
         if query_axis is not None:
             # the padded query block must divide the whole 2-D grid:
@@ -2054,9 +1962,7 @@ class SearchExecutor:
             qsharding = comms.sharding(query_axis, None)
         return _Plan(key=key, fn=fn, static=static, post=arrays,
                      qdim=index.dim, sharded=True, probe=probe,
-                     has_state=has_state, grouped_scan=grouped,
-                     qsharding=qsharding,
-                     state_sharding=qsharding,
+                     grouped_scan=grouped, qsharding=qsharding,
                      rows=rows if query_axis is not None else None,
                      payload=(family,
                               lambda: dist_ivf.collective_payload_model(
@@ -2076,7 +1982,7 @@ class SearchExecutor:
                    tuple(sorted(static.items())), _filter_spec(None))
             return _Plan(key=key, fn=_fused_entry_fn, static=static,
                          post=(index.dataset, index.norms),
-                         has_state=False, qdtype=index.dataset.dtype,
+                         qdtype=index.dataset.dtype,
                          qdim=index.dim)
         db_tile = int(kw.get("db_tile", 32768))
         budget_cols = max(
@@ -2106,7 +2012,7 @@ class SearchExecutor:
         # the resolved engine is part of the static set and therefore of
         # the AOT cache key: switching engines compiles a new executable
         # instead of silently reusing the wrong one, and bucketing /
-        # warmup / donation behave per engine
+        # warmup behave per engine
         engine = resolve_scan_engine(params.scan_engine, data=index.data,
                                      filter_words=fw, k=k)
         static = {"n_probes": n_probes, "k": k, "metric": index.metric,
@@ -2117,16 +2023,12 @@ class SearchExecutor:
                tuple(sorted((n, str(v)) for n, v in static.items())),
                _filter_spec(fw))
         key, probe = self._probe_plumbing(index, "ivf_flat", key)
-        # the rank-major and XLA list-major scans thread the donated
-        # (q, k) running state through HBM; the grouped Pallas scan
-        # carries no running state, so donated buffers would go unused
         grouped = None
         if engine == "pallas":
             grouped = (n_probes, index.data.shape, index.data.dtype, 1)
         return _Plan(key=key, fn=m._search_impl_fn, static=static,
                      post=arrays, use_filter=True, qdim=index.dim,
-                     has_state=engine != "pallas", probe=probe,
-                     grouped_scan=grouped)
+                     probe=probe, grouped_scan=grouped)
 
     def _plan_tiered(self, index, params, k, bucket, fw, kw) -> _Plan:
         from raft_tpu.neighbors import tiered as m
@@ -2163,8 +2065,7 @@ class SearchExecutor:
         # survive into the lowered avals (see _Plan.keep_sharding)
         return _Plan(key=key, fn=m._tiered_search_fn, static=static,
                      post=arrays, use_filter=True, qdim=index.dim,
-                     has_state=engine != "pallas", probe=probe,
-                     keep_sharding=True)
+                     probe=probe, keep_sharding=True)
 
     def _plan_tiered_pq(self, index, params, k, bucket, fw,
                         kw) -> _Plan:
@@ -2254,8 +2155,6 @@ class SearchExecutor:
                tuple(sorted((n, str(v)) for n, v in static.items())),
                _filter_spec(fw))
         key, probe = self._probe_plumbing(index, "ivf_pq", key)
-        # both PQ scan engines build their lax.scan carry from the
-        # donated init buffers — keep PR 1's donation on either path
         return _Plan(key=key, fn=m._search_impl_fn, static=static,
                      post=arrays, use_filter=True, qdim=index.dim,
                      probe=probe)
@@ -2288,11 +2187,9 @@ class SearchExecutor:
                tuple(sorted((n, str(v)) for n, v in static.items())),
                _filter_spec(fw))
         key, probe = self._probe_plumbing(index, "ivf_bq", key)
-        # the rank and xla engines thread the donated (q, k) running
-        # state through HBM; the Pallas kernel keeps it in VMEM scratch
         return _Plan(key=key, fn=m._search_impl_fn, static=static,
                      post=arrays, use_filter=True, qdim=index.dim,
-                     has_state=engine != "pallas", probe=probe)
+                     probe=probe)
 
     def _plan_cagra(self, index, params, k, bucket, fw, kw) -> _Plan:
         from raft_tpu.neighbors import cagra as m
@@ -2328,4 +2225,4 @@ class SearchExecutor:
                _filter_spec(fw if not use_kernel else None))
         return _Plan(key=key, fn=m._serving_fn, static=static,
                      post=arrays, use_filter=not use_kernel,
-                     has_state=False, qdim=index.dim)
+                     qdim=index.dim)

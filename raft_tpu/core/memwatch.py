@@ -27,12 +27,12 @@ mesh scale) makes operational:
   that don't expose it (CPU): the model keeps working, the live
   column reads absent rather than fake.
 - **Reservation forecast** (:meth:`MemoryLedger.forecast`) — resident
-  indexes + the executor's donated top-k state and probe planes +
-  the max compile-time ``temp_bytes`` over its cached executables
-  (any dispatch may be the one that peaks) → a per-device modeled
-  peak. The divergence gauge (live in-use minus modeled resident) is
-  the fragmentation/untracked-allocation signal — when it grows, the
-  model is missing something real.
+  indexes + the executor's probe planes + the max compile-time
+  ``temp_bytes`` over its cached executables (any dispatch may be the
+  one that peaks) → a per-device modeled peak. The divergence gauge
+  (live in-use minus modeled resident) is the fragmentation/
+  untracked-allocation signal — when it grows, the model is missing
+  something real.
 - **Capacity planning** (:meth:`MemoryLedger.fits`, :func:`admit`) —
   "would N more bytes fit?" answered host-side, and an **opt-in**
   typed :class:`CapacityExceeded` gate on the index build/extend
@@ -59,8 +59,7 @@ Gauges (published by :meth:`MemoryLedger.publish`, scrape-time):
   — live truth per device (only when supported); rendered labeled
   (``memory_device_in_use_bytes{device="0"}``)
 - ``memory.resident.total_bytes`` / ``memory.reserved.
-  {donated_state,probe_planes,max_temp}_bytes`` — the forecast's
-  modeled terms
+  {probe_planes,max_temp}_bytes`` — the forecast's modeled terms
 - ``memory.forecast.peak_bytes`` — max per-device modeled peak
 - ``memory.hbm.headroom_bytes`` — live headroom (min over devices of
   limit − in_use); −1 when unknowable (no live stats, no configured
@@ -330,7 +329,7 @@ class MemoryLedger:
     ``executor`` (optional) wires the two dispatch-path touchpoints:
     the executor calls :meth:`sample_dispatch` after every dispatch
     (host-only watermark fold), and the forecast reads the executor's
-    donated-state / probe-plane / compile-time-temp reservations
+    probe-plane / compile-time-temp reservations
     through :meth:`~raft_tpu.core.executor.SearchExecutor
     .memory_reservations`. ``capacity_bytes`` is an explicit
     per-device capacity for backends without live ``memory_stats``
@@ -434,8 +433,8 @@ class MemoryLedger:
 
     def forecast(self, models: Optional[dict] = None) -> dict:
         """The reservation forecast: watched resident bytes + the
-        executor's donated state / probe planes + its max
-        compile-time temp, folded per device; ``peak_bytes`` is the
+        executor's probe planes + its max compile-time temp, folded
+        per device; ``peak_bytes`` is the
         worst device's modeled peak (the unit an OOM happens in).
         ``models`` lets a caller that already walked the watched
         indexes (:meth:`snapshot` does) skip a second walk."""
@@ -448,16 +447,14 @@ class MemoryLedger:
             resident_total += model["resident_bytes"]
             for o, b in model["per_device_bytes"].items():
                 per_device[o] = per_device.get(o, 0.0) + b
-        donated = probe = temp = 0.0
+        probe = temp = 0.0
         if self.executor is not None and hasattr(
                 self.executor, "memory_reservations"):
             res = self.executor.memory_reservations()
-            donated = float(sum(res["donated_state_bytes"].values()))
             probe = float(sum(res["probe_plane_bytes"].values()))
             temp = float(res["max_temp_bytes"])
-            for part in ("donated_state_bytes", "probe_plane_bytes"):
-                for o, b in res[part].items():
-                    per_device[o] = per_device.get(o, 0.0) + b
+            for o, b in res["probe_plane_bytes"].items():
+                per_device[o] = per_device.get(o, 0.0) + b
             # any dispatch may be the one that peaks: the max temp
             # reserves on EVERY device holding state (or device 0
             # when nothing is resident yet)
@@ -466,7 +463,6 @@ class MemoryLedger:
         peak = max(per_device.values()) if per_device else 0.0
         return {
             "resident_bytes": float(resident_total),
-            "donated_state_bytes": donated,
             "probe_plane_bytes": probe,
             "max_temp_bytes": temp,
             "per_device_bytes": {int(o): float(b)
@@ -634,8 +630,7 @@ class MemoryLedger:
         if live["supported"] and live["devices"]:
             in_use = sum(d["in_use_bytes"]
                          for d in live["devices"].values())
-            modeled = (fc["resident_bytes"] + fc["donated_state_bytes"]
-                       + fc["probe_plane_bytes"])
+            modeled = fc["resident_bytes"] + fc["probe_plane_bytes"]
             divergence = float(in_use - modeled)
         with self._lock:
             self._wm_forecast = max(self._wm_forecast, fc["peak_bytes"])
@@ -670,8 +665,6 @@ class MemoryLedger:
             "memory.resident.total_bytes": snap["resident_total_bytes"],
             "memory.host.resident_bytes":
                 snap["host_resident_total_bytes"],
-            "memory.reserved.donated_state_bytes":
-                snap["forecast"]["donated_state_bytes"],
             "memory.reserved.probe_planes_bytes":
                 snap["forecast"]["probe_plane_bytes"],
             "memory.reserved.max_temp_bytes":

@@ -619,19 +619,10 @@ def poll_shard_timings(parts, t0: float, *,
     ``poll_s`` bounds the timing resolution; total wall time is
     unchanged (callers block on the same results right after).
 
-    Host arrays (no ``is_ready``) are ready by definition; an
-    ``is_ready`` that raises ``RuntimeError`` (a donated-state buffer
-    consumed by a concurrent re-dispatch — the poll runs outside the
-    executor lock) caps that shard's arrival at the consumption time
-    rather than crashing the trace."""
+    Host arrays (no ``is_ready``) are ready by definition."""
     def _ready(a) -> bool:
         fn = getattr(a, "is_ready", None)
-        if fn is None:
-            return True
-        try:
-            return fn()
-        except RuntimeError:
-            return True
+        return True if fn is None else fn()
 
     timings = [0.0] * len(parts)
     # builtins.range — this module's own `range` is the profiling scope

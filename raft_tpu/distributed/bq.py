@@ -224,8 +224,8 @@ def build_bq(
 
 
 def _dist_search_bq_fn(queries, centers, rotation, codes, rnorm, cfac,
-                       errw, indices, data, data_norms, init_d=None,
-                       init_i=None, probe_counts=None, n_valid=None,
+                       errw, indices, data, data_norms,
+                       probe_counts=None, n_valid=None,
                        row_probes=None, *,
                        axis: str, mesh, n_probes: int, k: int,
                        metric: DistanceType,
@@ -240,9 +240,7 @@ def _dist_search_bq_fn(queries, centers, rotation, codes, rnorm, cfac,
     estimate scan) + O(q · merge_k) result merge. ``merge_k`` is the
     variance-corrected per-shard contribution (:func:`merge_overfetch`
     — ``wire_dtype`` compresses the gathered distances on the wire).
-    ``init_d``/``init_i`` optionally provide the (q, merge_k) running
-    top-k storage (values are reset here; the serving path donates
-    them). ``probe_counts`` optionally provides the donated
+    ``probe_counts`` optionally provides the donated
     list-sharded (n_lists,) int32 probe-frequency plane (graftgauge —
     owned probes only, returned as a third output) and the optional
     ragged ``row_probes`` budget plane (see
@@ -253,12 +251,6 @@ def _dist_search_bq_fn(queries, centers, rotation, codes, rnorm, cfac,
     pad_val = jnp.inf if select_min else -jnp.inf
     ip_metric = metric == DistanceType.InnerProduct
     ragged = row_probes is not None
-
-    if init_d is None:
-        init_d = jnp.full((queries.shape[0], k), pad_val, jnp.float32)
-    if init_i is None:
-        init_i = jnp.full((queries.shape[0], k), -1, jnp.int32)
-
     with_data = data is not None
 
     def body(centers_l, codes_l, rn_l, cf_l, ew_l, ids_l, *rest):
@@ -268,10 +260,10 @@ def _dist_search_bq_fn(queries, centers, rotation, codes, rnorm, cfac,
             rest = rest[2:]
         else:
             data_l, dn_l = None, None
-        qs, ind, ini = rest[0], rest[1], rest[2]
-        rest = rest[3:]
+        qs = rest.pop(0)
         rp = rest.pop(0) if ragged else None
         cnt, nv = rest if rest else (None, None)
+        q = qs.shape[0]
         qf = qs.astype(jnp.float32)
         n_local = centers_l.shape[0]
 
@@ -321,7 +313,7 @@ def _dist_search_bq_fn(queries, centers, rotation, codes, rnorm, cfac,
             with jax.named_scope("scan"):
                 best_d, best_i = bq_list_major_scan(
                     qf, qrot, centers_rot, codes_l, rn_l, cf_l, ew_l,
-                    ids_l, data_l, dn_l, masked, None, ind, ini,
+                    ids_l, data_l, dn_l, masked, None,
                     k=k, metric=metric, epsilon=epsilon,
                     engine=scan_engine,
                     interpret=jax.default_backend() != "tpu")
@@ -336,7 +328,8 @@ def _dist_search_bq_fn(queries, centers, rotation, codes, rnorm, cfac,
                 return merge_topk(best_d, best_i, dist, row_ids, k,
                                   select_min), None
 
-            init = (jnp.full_like(ind, pad_val), jnp.full_like(ini, -1))
+            init = (jnp.full((q, k), pad_val, jnp.float32),
+                    jnp.full((q, k), -1, jnp.int32))
             with jax.named_scope("scan"):
                 (best_d, best_i), _ = jax.lax.scan(
                     step, init, jnp.arange(local.shape[1]))
@@ -360,8 +353,8 @@ def _dist_search_bq_fn(queries, centers, rotation, codes, rnorm, cfac,
     if with_data:
         args += [data, data_norms]
         in_specs += [P(axis, None, None), P(axis, None)]
-    args += [queries, init_d, init_i]
-    in_specs += [qspec, qspec, qspec]
+    args += [queries]
+    in_specs += [qspec]
     out_specs = [qspec, qspec]
     if ragged:
         args += [row_probes]
@@ -394,8 +387,8 @@ _dist_search_bq = partial(jax.jit, static_argnames=(
 
 def _dist_search_ragged_bq_fn(queries, row_probes, centers, rotation,
                               codes, rnorm, cfac, errw, indices, data,
-                              data_norms, init_d=None, init_i=None,
-                              probe_counts=None, n_valid=None, *,
+                              data_norms, probe_counts=None,
+                              n_valid=None, *,
                               axis: str, mesh, n_probes: int, k: int,
                               metric: DistanceType, probe_mode: str,
                               scan_engine: str = "xla",
@@ -414,7 +407,7 @@ def _dist_search_ragged_bq_fn(queries, row_probes, centers, rotation,
            f"engine (pallas|xla), got {scan_engine!r}")
     return _dist_search_bq_fn(
         queries, centers, rotation, codes, rnorm, cfac, errw, indices,
-        data, data_norms, init_d, init_i, probe_counts, n_valid,
+        data, data_norms, probe_counts, n_valid,
         row_probes=row_probes, axis=axis, mesh=mesh, n_probes=n_probes,
         k=k, metric=metric, probe_mode=probe_mode, coarse_algo="exact",
         scan_engine=scan_engine, epsilon=epsilon, wire_dtype=wire_dtype,

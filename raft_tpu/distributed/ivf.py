@@ -563,8 +563,8 @@ def shard_index(comms: Comms, index) -> DistributedIvfFlat:
 
 
 def _dist_search_fn(queries, centers, data, data_norms, indices,
-                    init_d=None, init_i=None, probe_counts=None,
-                    n_valid=None, row_probes=None, *, axis: str, mesh,
+                    probe_counts=None, n_valid=None, row_probes=None, *,
+                    axis: str, mesh,
                     n_probes: int, k: int, metric: DistanceType,
                     probe_mode: str, query_axis: Optional[str] = None,
                     coarse_algo: str = "exact", scan_engine: str = "rank",
@@ -578,11 +578,8 @@ def _dist_search_fn(queries, centers, data, data_norms, indices,
     jit static, and the mesh-aware serving path keys AOT executables on
     it. The list-major engines mask not-owned probes to the sentinel id
     ``n_local`` so each shard streams only the union of lists it owns.
-    ``init_d``/``init_i`` optionally provide the (q, k) running top-k
-    storage (values are reset here; the serving path donates them —
-    the grouped Pallas engine carries no running state). ``n_valid``
-    (traced scalar, replicated; 1-D meshes) is the real row count:
-    bucket-pad rows own no probe, so they add the scan no pair.
+    ``n_valid`` (traced scalar, replicated; 1-D meshes) is the real row
+    count: bucket-pad rows own no probe, so they add the scan no pair.
 
     ``probe_counts`` (graftgauge) optionally provides the donated
     list-sharded (n_lists,) int32 probe-frequency plane: each shard
@@ -608,12 +605,7 @@ def _dist_search_fn(queries, centers, data, data_norms, indices,
     interpret = jax.default_backend() != "tpu"
     ragged = row_probes is not None
 
-    if init_d is None:
-        init_d = jnp.full((queries.shape[0], k), pad_val, jnp.float32)
-    if init_i is None:
-        init_i = jnp.full((queries.shape[0], k), -1, jnp.int32)
-
-    def body(centers_l, data_l, norms_l, ids_l, qs, ind, ini, *rest):
+    def body(centers_l, data_l, norms_l, ids_l, qs, *rest):
         rest = list(rest)
         rp = rest.pop(0) if ragged else None
         cnt = rest.pop(0) if probe_counts is not None else None
@@ -672,7 +664,7 @@ def _dist_search_fn(queries, centers, data, data_norms, indices,
             with jax.named_scope("scan"):
                 masked = jnp.where(mine, local, n_local).astype(jnp.int32)
                 best_d, best_i = list_major_scan(
-                    qf, data_l, norms_l, ids_l, masked, None, ind, ini,
+                    qf, data_l, norms_l, ids_l, masked, None,
                     k=k, metric=metric, engine=scan_engine,
                     interpret=interpret)
         else:
@@ -697,7 +689,8 @@ def _dist_search_fn(queries, centers, data, data_norms, indices,
                 return merge_topk(best_d, best_i, dist, row_ids, k,
                                   select_min), None
 
-            init = (jnp.full_like(ind, pad_val), jnp.full_like(ini, -1))
+            init = (jnp.full((q, k), pad_val, jnp.float32),
+                    jnp.full((q, k), -1, jnp.int32))
             with jax.named_scope("scan"):
                 (best_d, best_i), _ = jax.lax.scan(
                     step, init, jnp.arange(local.shape[1]))
@@ -719,9 +712,9 @@ def _dist_search_fn(queries, centers, data, data_norms, indices,
     # (``sub_comms.hpp``). Each device handles its (list-block,
     # query-block) cell; merges stay within the list axis.
     qspec = P() if query_axis is None else P(query_axis, None)
-    args = [centers, data, data_norms, indices, queries, init_d, init_i]
+    args = [centers, data, data_norms, indices, queries]
     in_specs = [P(axis, None), P(axis, None, None), P(axis, None),
-                P(axis, None), qspec, qspec, qspec]
+                P(axis, None), qspec]
     out_specs = [qspec, qspec]
     if ragged:
         args += [row_probes]
@@ -760,8 +753,8 @@ _dist_search = partial(jax.jit, static_argnames=(
 
 
 def _dist_search_ragged_fn(queries, row_probes, centers, data, data_norms,
-                           indices, init_d=None, init_i=None,
-                           probe_counts=None, n_valid=None, *, axis: str,
+                           indices, probe_counts=None, n_valid=None, *,
+                           axis: str,
                            mesh, n_probes: int, k: int,
                            metric: DistanceType, probe_mode: str,
                            scan_engine: str = "xla",
@@ -776,15 +769,15 @@ def _dist_search_ragged_fn(queries, row_probes, centers, data, data_norms,
     :func:`_dist_search_fn`'s ownership mask
     (:func:`raft_tpu.ops.ivf_scan.ragged_owned`), so the sharded body
     — probe-ownership arithmetic, sentinel-masked shard-local scan,
-    donated per-shard top-k state, list-sharded probe plane, lean
-    result merge — is char-identical to the bucketed dispatch. Exact
-    coarse select only, list-major engines only (the rank-major scan's
-    positional-tie merge is not budget-prefix-stable)."""
+    list-sharded probe plane, lean result merge — is char-identical
+    to the bucketed dispatch. Exact coarse select only, list-major
+    engines only (the rank-major scan's positional-tie merge is not
+    budget-prefix-stable)."""
     expect(scan_engine in ("pallas", "xla"),
            "mesh ragged serving needs a membership-masked list-major "
            f"engine (pallas|xla), got {scan_engine!r}")
     return _dist_search_fn(
-        queries, centers, data, data_norms, indices, init_d, init_i,
+        queries, centers, data, data_norms, indices,
         probe_counts, n_valid, row_probes=row_probes, axis=axis,
         mesh=mesh, n_probes=n_probes, k=k, metric=metric,
         probe_mode=probe_mode, coarse_algo="exact",
@@ -1132,9 +1125,8 @@ def build_pq(
 
 
 def _dist_search_pq_fn(queries, centers, rotation, codebooks, codes,
-                       indices, init_d=None, init_i=None,
-                       probe_counts=None, n_valid=None, row_probes=None,
-                       *, axis: str,
+                       indices, probe_counts=None, n_valid=None,
+                       row_probes=None, *, axis: str,
                        mesh, n_probes: int, k: int, metric: DistanceType,
                        probe_mode: str, query_axis: Optional[str] = None,
                        codebook_kind: CodebookKind = (
@@ -1160,12 +1152,7 @@ def _dist_search_pq_fn(queries, centers, rotation, codebooks, codes,
     score = ivf_pq_mod.score_fn(score_mode, codebooks.shape[1])
     ragged = row_probes is not None
 
-    if init_d is None:
-        init_d = jnp.full((queries.shape[0], k), pad_val, jnp.float32)
-    if init_i is None:
-        init_i = jnp.full((queries.shape[0], k), -1, jnp.int32)
-
-    def body(centers_l, books_l, codes_l, ids_l, qs, ind, ini, *rest):
+    def body(centers_l, books_l, codes_l, ids_l, qs, *rest):
         rest = list(rest)
         rp = rest.pop(0) if ragged else None
         cnt, nv = rest if rest else (None, None)
@@ -1247,7 +1234,8 @@ def _dist_search_pq_fn(queries, centers, rotation, codebooks, codes,
                 return _merge_smallest_id(best_d, best_i, dist, row_ids,
                                           k), None
 
-            init = (jnp.full_like(ind, jnp.inf), jnp.full_like(ini, -1))
+            init = (jnp.full((q, k), jnp.inf, jnp.float32),
+                    jnp.full((q, k), -1, jnp.int32))
             with jax.named_scope("scan"):
                 (best_d, best_i), _ = jax.lax.scan(
                     step, init, unique_lists(masked, n_local))
@@ -1265,7 +1253,8 @@ def _dist_search_pq_fn(queries, centers, rotation, codebooks, codes,
                 return merge_topk(best_d, best_i, dist, row_ids, k,
                                   select_min), None
 
-            init = (jnp.full_like(ind, pad_val), jnp.full_like(ini, -1))
+            init = (jnp.full((q, k), pad_val, jnp.float32),
+                    jnp.full((q, k), -1, jnp.int32))
             with jax.named_scope("scan"):
                 (best_d, best_i), _ = jax.lax.scan(
                     step, init, jnp.arange(local.shape[1]))
@@ -1284,9 +1273,9 @@ def _dist_search_pq_fn(queries, centers, rotation, codebooks, codes,
 
     qspec = P() if query_axis is None else P(query_axis, None)
     bspec = P(axis, None, None) if per_cluster else P(None, None, None)
-    args = [centers, codebooks, codes, indices, queries, init_d, init_i]
+    args = [centers, codebooks, codes, indices, queries]
     in_specs = [P(axis, None), bspec, P(axis, None, None), P(axis, None),
-                qspec, qspec, qspec]
+                qspec]
     out_specs = [qspec, qspec]
     if ragged:
         args += [row_probes]
@@ -1318,9 +1307,9 @@ _dist_search_pq = partial(jax.jit, static_argnames=(
 
 
 def _dist_search_ragged_pq_fn(queries, row_probes, centers, rotation,
-                              codebooks, codes, indices, init_d=None,
-                              init_i=None, probe_counts=None,
-                              n_valid=None, *, axis: str, mesh,
+                              codebooks, codes, indices,
+                              probe_counts=None, n_valid=None, *,
+                              axis: str, mesh,
                               n_probes: int, k: int,
                               metric: DistanceType, probe_mode: str,
                               codebook_kind: CodebookKind = (
@@ -1338,8 +1327,8 @@ def _dist_search_ragged_pq_fn(queries, row_probes, centers, rotation,
            "mesh ragged PQ serving needs the membership-masked "
            f"list-major engine ('xla'), got {scan_engine!r}")
     return _dist_search_pq_fn(
-        queries, centers, rotation, codebooks, codes, indices, init_d,
-        init_i, probe_counts, n_valid, row_probes=row_probes, axis=axis,
+        queries, centers, rotation, codebooks, codes, indices,
+        probe_counts, n_valid, row_probes=row_probes, axis=axis,
         mesh=mesh, n_probes=n_probes, k=k, metric=metric,
         probe_mode=probe_mode, codebook_kind=codebook_kind,
         score_mode=score_mode, lut_dtype=lut_dtype,

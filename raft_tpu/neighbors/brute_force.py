@@ -103,19 +103,13 @@ def build(
     return BruteForceIndex(dataset, norms, DistanceType(metric), metric_arg)
 
 
-def _knn_scan_fn(queries, dataset, init_d=None, init_i=None, *, k: int,
+def _knn_scan_fn(queries, dataset, *, k: int,
                  metric: DistanceType, metric_arg: float, tile: int,
                  precision: str = "highest", approx: bool = False):
     """Scan database tiles, carrying running top-k (the global-merge loop of
     ``tiled_brute_force_knn``). ``approx`` swaps the per-tile exact top-k
     for the TPU's native approximate top-k unit (the TPU-KNN-paper
-    peak-FLOP/s recipe); the cross-tile merge stays exact.
-
-    ``init_d``/``init_i`` are optional (q, k) buffers whose *storage*
-    seeds the running top-k state; their values are reset here. The
-    serving path (``core/executor.py``) passes them with buffer
-    donation so the scan state reuses one HBM allocation across calls.
-    """
+    peak-FLOP/s recipe); the cross-tile merge stays exact."""
     n, d = dataset.shape
     q = queries.shape[0]
     select_min = is_min_close(metric)
@@ -150,12 +144,8 @@ def _knn_scan_fn(queries, dataset, init_d=None, init_i=None, *, k: int,
                                   tile_gi.astype(jnp.int32), k, select_min)
         return (new_d, new_i), None
 
-    init = (
-        jnp.full((q, k), pad_val, jnp.float32) if init_d is None
-        else jnp.full_like(init_d, pad_val),
-        jnp.full((q, k), -1, jnp.int32) if init_i is None
-        else jnp.full_like(init_i, -1),
-    )
+    init = (jnp.full((q, k), pad_val, jnp.float32),
+            jnp.full((q, k), -1, jnp.int32))
     (best_d, best_i), _ = jax.lax.scan(step, init, jnp.arange(n_tiles))
     return best_d, best_i
 

@@ -691,8 +691,8 @@ def score_probe(lists, qrot, centers_rot, ip, cn, qnorm, codes, rnorm,
 
 def _search_impl_fn(queries, centers, rotation, codes, rnorm, cfac,
                     errw, indices, data, data_norms, filter_words,
-                    init_d=None, init_i=None, probe_counts=None,
-                    n_valid=None, row_probes=None, cold_planes=None,
+                    probe_counts=None, n_valid=None, row_probes=None,
+                    cold_planes=None,
                     hot_slot_map=None, cold_slot_map=None, *,
                     n_probes: int,
                     k: int, metric: DistanceType,
@@ -702,10 +702,7 @@ def _search_impl_fn(queries, centers, rotation, codes, rnorm, cfac,
     """BQ probe scan: coarse select, then either the fused
     estimate-then-rerank list-major engines (``pallas``/``xla`` —
     :mod:`raft_tpu.ops.bq_scan`, exact output distances) or the legacy
-    rank-major estimate-only scan (``rank``). ``init_d``/``init_i``
-    optionally provide the (q, k) running-state storage (values are
-    reset here); the serving path donates them (rank and xla engines —
-    the Pallas kernel's state lives in VMEM scratch). ``probe_counts``
+    rank-major estimate-only scan (``rank``). ``probe_counts``
     optionally provides the donated (n_lists,) int32 probe-frequency
     plane (graftgauge): selected probe ids scatter-add into it (rows
     past ``n_valid`` masked) and the updated plane returns as a third
@@ -773,8 +770,8 @@ def _search_impl_fn(queries, centers, rotation, codes, rnorm, cfac,
             int(cfac.shape[2]))
         best_d, best_i = bq_list_major_scan(
             qf, qrot, centers_rot, codes, rnorm, cfac, errw, indices,
-            data, data_norms, probes, filter_words, init_d, init_i,
-            cold_planes, hot_slot_map, cold_slot_map,
+            data, data_norms, probes, filter_words, cold_planes,
+            hot_slot_map, cold_slot_map,
             k=k, metric=metric, epsilon=epsilon, engine=scan_engine,
             query_bits=qb, interpret=jax.default_backend() != "tpu")
     else:
@@ -791,10 +788,8 @@ def _search_impl_fn(queries, centers, rotation, codes, rnorm, cfac,
             return merge_topk(best_d, best_i, dist, row_ids, k,
                               select_min), None
 
-        init = (jnp.full((q, k), pad_val, jnp.float32) if init_d is None
-                else jnp.full_like(init_d, pad_val),
-                jnp.full((q, k), -1, jnp.int32) if init_i is None
-                else jnp.full_like(init_i, -1))
+        init = (jnp.full((q, k), pad_val, jnp.float32),
+                jnp.full((q, k), -1, jnp.int32))
         (best_d, best_i), _ = jax.lax.scan(step, init,
                                            jnp.arange(n_probes))
 
@@ -813,8 +808,8 @@ _search_impl = partial(jax.jit, static_argnames=(
 
 def _search_ragged_fn(queries, row_probes, centers, rotation, codes,
                       rnorm, cfac, errw, indices, data, data_norms,
-                      filter_words, init_d=None, init_i=None,
-                      probe_counts=None, n_valid=None, *, n_probes: int,
+                      filter_words, probe_counts=None, n_valid=None, *,
+                      n_probes: int,
                       k: int, metric: DistanceType,
                       scan_engine: str = "xla", epsilon: float = 3.0,
                       query_bits: int = 0):
@@ -838,8 +833,8 @@ def _search_ragged_fn(queries, row_probes, centers, rotation, codes,
            f"(pallas|xla), got {scan_engine!r}")
     return _search_impl_fn(
         queries, centers, rotation, codes, rnorm, cfac, errw, indices,
-        data, data_norms, filter_words, init_d, init_i, probe_counts,
-        None, row_probes=row_probes, n_probes=n_probes, k=k,
+        data, data_norms, filter_words, probe_counts, None,
+        row_probes=row_probes, n_probes=n_probes, k=k,
         metric=metric, coarse_algo="exact", scan_engine=scan_engine,
         epsilon=epsilon, query_bits=query_bits)
 

@@ -458,17 +458,12 @@ def build_streaming(
 
 
 def _search_impl_fn(queries, centers, center_norms, data, data_norms, indices,
-                    filter_words, init_d=None, init_i=None,
-                    probe_counts=None, n_valid=None, row_probes=None, *,
+                    filter_words, probe_counts=None, n_valid=None,
+                    row_probes=None, *,
                     n_probes: int, k: int, metric: DistanceType,
                     coarse_algo: str = "exact",
                     scan_engine: str = "rank"):
     """Coarse select + probe scan with running top-k merge.
-
-    ``init_d``/``init_i`` optionally provide the (q, k) running-state
-    storage (values are reset here); the serving path donates them so
-    the scan state reuses one HBM allocation across calls (rank-major
-    engine only — the list-major engines carry their state in VMEM).
 
     ``probe_counts`` (graftgauge) optionally provides the donated
     (n_lists,) int32 cumulative probe-frequency plane: the selected
@@ -532,14 +527,13 @@ def _search_impl_fn(queries, centers, center_norms, data, data_norms, indices,
     if scan_engine != "rank":
         # ---- list-major probe scan (ops/ivf_scan): stream each unique
         # probed list once. The XLA engine scores it against the whole
-        # tile and reuses the donated running state; the grouped Pallas
-        # scan scores it against the rows that probed it and keeps no
-        # running state.
+        # tile; the grouped Pallas scan scores it against the rows that
+        # probed it.
         from raft_tpu.ops.ivf_scan import list_major_scan
 
         best_d, best_i = list_major_scan(
             qf, data, data_norms, indices, probes, filter_words,
-            init_d, init_i, k=k, metric=metric, engine=scan_engine,
+            k=k, metric=metric, engine=scan_engine,
             interpret=jax.default_backend() != "tpu")
     else:
         # ---- rank-major probe scan: one gathered list + one batched
@@ -569,12 +563,8 @@ def _search_impl_fn(queries, centers, center_norms, data, data_norms, indices,
                                       select_min)
             return (new_d, new_i), None
 
-        init = (
-            jnp.full((q, k), pad_val, jnp.float32) if init_d is None
-            else jnp.full_like(init_d, pad_val),
-            jnp.full((q, k), -1, jnp.int32) if init_i is None
-            else jnp.full_like(init_i, -1),
-        )
+        init = (jnp.full((q, k), pad_val, jnp.float32),
+                jnp.full((q, k), -1, jnp.int32))
         (best_d, best_i), _ = jax.lax.scan(step, init, jnp.arange(n_probes))
 
     if metric != DistanceType.InnerProduct:
@@ -593,8 +583,8 @@ _search_impl = partial(jax.jit, static_argnames=(
 
 
 def _search_ragged_fn(queries, row_probes, centers, center_norms, data,
-                      data_norms, indices, filter_words, init_d=None,
-                      init_i=None, probe_counts=None, n_valid=None, *,
+                      data_norms, indices, filter_words,
+                      probe_counts=None, n_valid=None, *,
                       n_probes: int, k: int, metric: DistanceType,
                       scan_engine: str = "xla"):
     """Packed ragged-batch search body — the serving executor's
@@ -630,7 +620,7 @@ def _search_ragged_fn(queries, row_probes, centers, center_norms, data,
            f"(pallas|xla), got {scan_engine!r}")
     return _search_impl_fn(
         queries, centers, center_norms, data, data_norms, indices,
-        filter_words, init_d, init_i, probe_counts, None,
+        filter_words, probe_counts, None,
         row_probes=row_probes, n_probes=n_probes, k=k, metric=metric,
         coarse_algo="exact", scan_engine=scan_engine)
 

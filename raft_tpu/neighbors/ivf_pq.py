@@ -775,21 +775,18 @@ def quantize_lut(lut, lut_dtype):
 
 
 def _search_impl_fn(queries, centers, rotation, codebooks, codes, indices,
-                    filter_words, init_d=None, init_i=None,
-                    probe_counts=None, n_valid=None, row_probes=None,
-                    cold_codes=None, hot_slot_map=None,
+                    filter_words, probe_counts=None, n_valid=None,
+                    row_probes=None, cold_codes=None, hot_slot_map=None,
                     cold_slot_map=None, *,
                     n_probes: int, k: int, metric: DistanceType,
                     codebook_kind: CodebookKind, lut_dtype,
                     score_mode: str = "gather", packed: bool = False,
                     coarse_algo: str = "exact", scan_engine: str = "rank"):
-    """ADC probe scan. ``init_d``/``init_i`` optionally provide the
-    (q, k) running-state storage (values are reset here); the serving
-    path donates them so the scan state reuses one HBM allocation.
-    ``probe_counts`` optionally provides the donated (n_lists,) int32
-    probe-frequency plane (graftgauge): selected probe ids scatter-add
-    into it (rows past ``n_valid`` masked) and the updated plane
-    returns as a third output — the results never read it.
+    """ADC probe scan. ``probe_counts`` optionally provides the
+    donated (n_lists,) int32 probe-frequency plane (graftgauge):
+    selected probe ids scatter-add into it (rows past ``n_valid``
+    masked) and the updated plane returns as a third output — the
+    results never read it.
     ``row_probes`` (the ragged front — see :func:`_search_ragged_fn`)
     optionally provides a packed batch's per-row probe budgets: the
     coarse stage selects at the class cap and masks each row's slots
@@ -927,12 +924,8 @@ def _search_impl_fn(queries, centers, rotation, codebooks, codes, indices,
             return _merge_smallest_id(best_d, best_i, dist, row_ids,
                                       k), None
 
-        init = (
-            jnp.full((q, k), jnp.inf, jnp.float32) if init_d is None
-            else jnp.full_like(init_d, jnp.inf),
-            jnp.full((q, k), -1, jnp.int32) if init_i is None
-            else jnp.full_like(init_i, -1),
-        )
+        init = (jnp.full((q, k), jnp.inf, jnp.float32),
+                jnp.full((q, k), -1, jnp.int32))
         (best_d, best_i), _ = jax.lax.scan(step, init,
                                            unique_lists(probes, n_lists))
         if not select_min:
@@ -953,12 +946,8 @@ def _search_impl_fn(queries, centers, rotation, codebooks, codes, indices,
                                       select_min)
             return (new_d, new_i), None
 
-        init = (
-            jnp.full((q, k), pad_val, jnp.float32) if init_d is None
-            else jnp.full_like(init_d, pad_val),
-            jnp.full((q, k), -1, jnp.int32) if init_i is None
-            else jnp.full_like(init_i, -1),
-        )
+        init = (jnp.full((q, k), pad_val, jnp.float32),
+                jnp.full((q, k), -1, jnp.int32))
         (best_d, best_i), _ = jax.lax.scan(step, init,
                                            jnp.arange(n_probes))
 
@@ -976,8 +965,8 @@ _search_impl = partial(jax.jit, static_argnames=(
 
 
 def _search_ragged_fn(queries, row_probes, centers, rotation, codebooks,
-                      codes, indices, filter_words, init_d=None,
-                      init_i=None, probe_counts=None, n_valid=None, *,
+                      codes, indices, filter_words, probe_counts=None,
+                      n_valid=None, *,
                       n_probes: int, k: int, metric: DistanceType,
                       codebook_kind: CodebookKind, lut_dtype,
                       score_mode: str = "gather", packed: bool = False,
@@ -1000,7 +989,7 @@ def _search_ragged_fn(queries, row_probes, centers, rotation, codebooks,
            f"engine ('xla'), got {scan_engine!r}")
     return _search_impl_fn(
         queries, centers, rotation, codebooks, codes, indices,
-        filter_words, init_d, init_i, probe_counts, None,
+        filter_words, probe_counts, None,
         row_probes=row_probes, n_probes=n_probes, k=k, metric=metric,
         codebook_kind=codebook_kind, lut_dtype=lut_dtype,
         score_mode=score_mode, packed=packed, coarse_algo="exact",

@@ -780,8 +780,8 @@ def apply_plan(tiered, promotions, demotions,
 
 def _tiered_search_fn(queries, centers, center_norms, hot_data,
                       cold_data, hot_slot_map, cold_slot_map,
-                      data_norms, indices, filter_words, init_d=None,
-                      init_i=None, probe_counts=None, n_valid=None,
+                      data_norms, indices, filter_words,
+                      probe_counts=None, n_valid=None,
                       row_probes=None, *,
                       n_probes: int, k: int, metric: DistanceType,
                       coarse_algo: str = "exact",
@@ -823,7 +823,7 @@ def _tiered_search_fn(queries, centers, center_norms, hot_data,
 
     best_d, best_i = tiered_list_major_scan(
         qf, hot_data, cold_data, hot_slot_map, cold_slot_map,
-        data_norms, indices, probes, filter_words, init_d, init_i,
+        data_norms, indices, probes, filter_words,
         k=k, metric=metric, engine=scan_engine,
         interpret=jax.default_backend() != "tpu")
 
@@ -847,8 +847,7 @@ _tiered_search = partial(jax.jit, static_argnames=(
 def _tiered_search_ragged_fn(queries, row_probes, centers,
                              center_norms, hot_data, cold_data,
                              hot_slot_map, cold_slot_map, data_norms,
-                             indices, filter_words, init_d=None,
-                             init_i=None, probe_counts=None,
+                             indices, filter_words, probe_counts=None,
                              n_valid=None, *, n_probes: int, k: int,
                              metric: DistanceType,
                              scan_engine: str = "xla"):
@@ -871,7 +870,7 @@ def _tiered_search_ragged_fn(queries, row_probes, centers,
     return _tiered_search_fn(
         queries, centers, center_norms, hot_data, cold_data,
         hot_slot_map, cold_slot_map, data_norms, indices,
-        filter_words, init_d, init_i, probe_counts, None,
+        filter_words, probe_counts, None,
         row_probes=row_probes, n_probes=n_probes, k=k, metric=metric,
         coarse_algo="exact", scan_engine=scan_engine)
 
@@ -879,8 +878,8 @@ def _tiered_search_ragged_fn(queries, row_probes, centers,
 def _tiered_pq_search_fn(queries, centers, rotation, codebooks,
                          hot_codes, cold_codes, hot_slot_map,
                          cold_slot_map, indices, filter_words,
-                         init_d=None, init_i=None, probe_counts=None,
-                         n_valid=None, row_probes=None, *,
+                         probe_counts=None, n_valid=None,
+                         row_probes=None, *,
                          n_probes: int, k: int, metric: DistanceType,
                          codebook_kind, lut_dtype,
                          score_mode: str = "gather",
@@ -897,7 +896,7 @@ def _tiered_pq_search_fn(queries, centers, rotation, codebooks,
 
     return _search_impl_fn(
         queries, centers, rotation, codebooks, hot_codes, indices,
-        filter_words, init_d, init_i, probe_counts, n_valid,
+        filter_words, probe_counts, n_valid,
         row_probes=row_probes, cold_codes=cold_codes,
         hot_slot_map=hot_slot_map, cold_slot_map=cold_slot_map,
         n_probes=n_probes, k=k, metric=metric,
@@ -916,7 +915,6 @@ def _tiered_pq_search_ragged_fn(queries, row_probes, centers,
                                 rotation, codebooks, hot_codes,
                                 cold_codes, hot_slot_map,
                                 cold_slot_map, indices, filter_words,
-                                init_d=None, init_i=None,
                                 probe_counts=None, n_valid=None, *,
                                 n_probes: int, k: int,
                                 metric: DistanceType, codebook_kind,
@@ -932,8 +930,8 @@ def _tiered_pq_search_ragged_fn(queries, row_probes, centers,
            f"got {scan_engine!r}")
     return _tiered_pq_search_fn(
         queries, centers, rotation, codebooks, hot_codes, cold_codes,
-        hot_slot_map, cold_slot_map, indices, filter_words, init_d,
-        init_i, probe_counts, None, row_probes=row_probes,
+        hot_slot_map, cold_slot_map, indices, filter_words,
+        probe_counts, None, row_probes=row_probes,
         n_probes=n_probes, k=k, metric=metric,
         codebook_kind=codebook_kind, lut_dtype=lut_dtype,
         score_mode=score_mode, packed=packed, coarse_algo="exact",
@@ -945,8 +943,8 @@ def _tiered_bq_search_fn(queries, centers, rotation, hot_codes,
                          cold_codes, cold_rnorm, cold_cfac, cold_errw,
                          cold_data, hot_slot_map, cold_slot_map,
                          indices, data_norms, filter_words,
-                         init_d=None, init_i=None, probe_counts=None,
-                         n_valid=None, row_probes=None, *,
+                         probe_counts=None, n_valid=None,
+                         row_probes=None, *,
                          n_probes: int, k: int, metric: DistanceType,
                          coarse_algo: str = "exact",
                          scan_engine: str = "xla",
@@ -962,7 +960,7 @@ def _tiered_bq_search_fn(queries, centers, rotation, hot_codes,
     return _search_impl_fn(
         queries, centers, rotation, hot_codes, hot_rnorm, hot_cfac,
         hot_errw, indices, hot_data, data_norms, filter_words,
-        init_d, init_i, probe_counts, n_valid,
+        probe_counts, n_valid,
         row_probes=row_probes,
         cold_planes=(cold_codes, cold_rnorm, cold_cfac, cold_errw,
                      cold_data),
@@ -983,8 +981,7 @@ def _tiered_bq_search_ragged_fn(queries, row_probes, centers,
                                 cold_codes, cold_rnorm, cold_cfac,
                                 cold_errw, cold_data, hot_slot_map,
                                 cold_slot_map, indices, data_norms,
-                                filter_words, init_d=None,
-                                init_i=None, probe_counts=None,
+                                filter_words, probe_counts=None,
                                 n_valid=None, *, n_probes: int,
                                 k: int, metric: DistanceType,
                                 scan_engine: str = "xla",
@@ -1003,7 +1000,7 @@ def _tiered_bq_search_ragged_fn(queries, row_probes, centers,
         queries, centers, rotation, hot_codes, hot_rnorm, hot_cfac,
         hot_errw, hot_data, cold_codes, cold_rnorm, cold_cfac,
         cold_errw, cold_data, hot_slot_map, cold_slot_map, indices,
-        data_norms, filter_words, init_d, init_i, probe_counts, None,
+        data_norms, filter_words, probe_counts, None,
         row_probes=row_probes, n_probes=n_probes, k=k, metric=metric,
         coarse_algo="exact", scan_engine=scan_engine,
         epsilon=epsilon, query_bits=query_bits)

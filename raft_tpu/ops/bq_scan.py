@@ -309,8 +309,8 @@ def unpack_bq_records(records, n: int, words: int, bits: int):
 
 def bq_list_major_scan(qf, qrot, centers_rot, codes, rnorm, cfac, errw,
                        indices, data, data_norms, probes,
-                       filter_words=None, init_d=None, init_i=None,
-                       cold_planes=None, hot_slot_map=None,
+                       filter_words=None, cold_planes=None,
+                       hot_slot_map=None,
                        cold_slot_map=None, *,
                        k: int, metric: DistanceType, epsilon: float,
                        engine: str = "xla", query_bits: int = _QUERY_BITS,
@@ -322,10 +322,7 @@ def bq_list_major_scan(qf, qrot, centers_rot, codes, rnorm, cfac, errw,
 
     Both engines break distance ties by smallest dataset id (the
     ``_extract_topk`` order) and share one estimate/margin/prune code
-    path, so their output ids are bit-identical. ``init_d``/``init_i``
-    optionally provide the (q, k) running-state storage for the XLA
-    engine (values are reset; the serving path donates them); the
-    Pallas kernel keeps its state in VMEM scratch and ignores them.
+    path, so their output ids are bit-identical.
 
     Probe slots carrying the sentinel value ``n_lists`` are masked
     probes (ragged rows, shard-unowned lists); both engines ignore
@@ -359,9 +356,8 @@ def bq_list_major_scan(qf, qrot, centers_rot, codes, rnorm, cfac, errw,
             epsilon=epsilon, query_bits=query_bits, interpret=interpret)
     return _bq_scan_xla(
         qf, qrot, centers_rot, codes, rnorm, cfac, errw, indices, data,
-        data_norms, probes, filter_words, init_d, init_i,
-        cold_planes=cold_planes, hot_slot_map=hot_slot_map,
-        cold_slot_map=cold_slot_map, k=k,
+        data_norms, probes, filter_words, cold_planes=cold_planes,
+        hot_slot_map=hot_slot_map, cold_slot_map=cold_slot_map, k=k,
         metric=metric, epsilon=epsilon, query_bits=query_bits)
 
 
@@ -372,8 +368,8 @@ def bq_list_major_scan(qf, qrot, centers_rot, codes, rnorm, cfac, errw,
 
 def _bq_scan_xla(qf, qrot, centers_rot, codes, rnorm, cfac, errw,
                  indices, data, data_norms, probes, filter_words,
-                 init_d=None, init_i=None, cold_planes=None,
-                 hot_slot_map=None, cold_slot_map=None, *, k: int,
+                 cold_planes=None, hot_slot_map=None,
+                 cold_slot_map=None, *, k: int,
                  metric: DistanceType, epsilon: float, query_bits: int):
     from raft_tpu.neighbors.filters import test_filter
 
@@ -484,12 +480,8 @@ def _bq_scan_xla(qf, qrot, centers_rot, codes, rnorm, cfac, errw,
         exact = jnp.where(cand, exact, jnp.inf)
         return _merge_smallest_id(best_d, best_i, exact, ids_b, k), None
 
-    init = (
-        jnp.full((q, k), jnp.inf, jnp.float32) if init_d is None
-        else jnp.full_like(init_d, jnp.inf),
-        jnp.full((q, k), -1, jnp.int32) if init_i is None
-        else jnp.full_like(init_i, -1),
-    )
+    init = (jnp.full((q, k), jnp.inf, jnp.float32),
+            jnp.full((q, k), -1, jnp.int32))
     (best_d, best_i), _ = jax.lax.scan(step, init, (uniq, ids_g))
     if ip_metric:
         best_d = -best_d          # inf (unfilled) -> -inf, ip exact

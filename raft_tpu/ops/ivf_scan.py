@@ -334,7 +334,7 @@ def unique_lists(probes: jax.Array, n_lists: int) -> jax.Array:
 
 
 def list_major_scan(qf, data, data_norms, indices, probes,
-                    filter_words=None, init_d=None, init_i=None, *,
+                    filter_words=None, *,
                     k: int, metric: DistanceType, engine: str = "xla",
                     interpret: bool = False):
     """Run the probe scan list-major; returns the pre-epilog running
@@ -347,10 +347,7 @@ def list_major_scan(qf, data, data_norms, indices, probes,
 
     Both engines break distance ties by smallest dataset id (the
     ``_extract_topk`` order), so their outputs are bit-identical to
-    each other even on exact duplicates. ``init_d``/``init_i``
-    optionally provide the (q, k) running-state storage for the XLA
-    engine (values are reset; the serving path donates them); the
-    grouped Pallas engine carries no running state and ignores them.
+    each other even on exact duplicates.
 
     Probe slots carrying the sentinel value ``n_lists`` are masked
     probes (the list-sharded indexes mark not-owned probes this way);
@@ -362,7 +359,7 @@ def list_major_scan(qf, data, data_norms, indices, probes,
                             filter_words, k=k, metric=metric,
                             interpret=interpret)
     return _scan_xla(qf, data, data_norms, indices, probes, filter_words,
-                     init_d, init_i, k=k, metric=metric)
+                     k=k, metric=metric)
 
 
 # ---------------------------------------------------------------------------
@@ -388,8 +385,8 @@ def _merge_smallest_id(best_d, best_i, dist, ids, k: int):
                        jnp.concatenate([best_i, ids], axis=1), k)
 
 
-def _scan_xla(qf, data, data_norms, indices, probes, filter_words,
-              init_d=None, init_i=None, *, k: int, metric: DistanceType):
+def _scan_xla(qf, data, data_norms, indices, probes, filter_words, *,
+              k: int, metric: DistanceType):
     from raft_tpu.neighbors.filters import test_filter
 
     q = qf.shape[0]
@@ -427,12 +424,8 @@ def _scan_xla(qf, data, data_norms, indices, probes, filter_words,
         dist = jnp.where(ok, dist, jnp.inf)
         return _merge_smallest_id(best_d, best_i, dist, ids_b, k), None
 
-    init = (
-        jnp.full((q, k), jnp.inf, jnp.float32) if init_d is None
-        else jnp.full_like(init_d, jnp.inf),
-        jnp.full((q, k), -1, jnp.int32) if init_i is None
-        else jnp.full_like(init_i, -1),
-    )
+    init = (jnp.full((q, k), jnp.inf, jnp.float32),
+            jnp.full((q, k), -1, jnp.int32))
     (best_d, best_i), _ = jax.lax.scan(step, init, uniq)
     if ip_metric:
         best_d = -best_d          # inf (unfilled) -> -inf, ip exact

@@ -231,8 +231,8 @@ def tier_fetch_plan(uniq: jax.Array, hot_slot_map: jax.Array,
 
 def tiered_list_major_scan(qf, hot_data, cold_data, hot_slot_map,
                            cold_slot_map, data_norms, indices, probes,
-                           filter_words=None, init_d=None, init_i=None,
-                           *, k: int, metric: DistanceType,
+                           filter_words=None, *, k: int,
+                           metric: DistanceType,
                            engine: str = "xla",
                            interpret: bool = False):
     """Run the probe scan over a tiered index; returns the pre-epilog
@@ -262,8 +262,7 @@ def tiered_list_major_scan(qf, hot_data, cold_data, hot_slot_map,
             metric=metric, interpret=interpret)
     return _tier_scan_xla(
         qf, hot_data, cold_data, hot_slot_map, cold_slot_map,
-        data_norms, indices, probes, filter_words, init_d, init_i,
-        k=k, metric=metric)
+        data_norms, indices, probes, filter_words, k=k, metric=metric)
 
 
 # ---------------------------------------------------------------------------
@@ -272,8 +271,7 @@ def tiered_list_major_scan(qf, hot_data, cold_data, hot_slot_map,
 
 
 def _tier_scan_xla(qf, hot_data, cold_data, hot_slot_map, cold_slot_map,
-                   data_norms, indices, probes, filter_words,
-                   init_d=None, init_i=None, *, k: int,
+                   data_norms, indices, probes, filter_words, *, k: int,
                    metric: DistanceType):
     from raft_tpu.neighbors.filters import test_filter
 
@@ -310,12 +308,8 @@ def _tier_scan_xla(qf, hot_data, cold_data, hot_slot_map, cold_slot_map,
         dist = jnp.where(ok, dist, jnp.inf)
         return _merge_smallest_id(best_d, best_i, dist, ids_b, k), None
 
-    init = (
-        jnp.full((q, k), jnp.inf, jnp.float32) if init_d is None
-        else jnp.full_like(init_d, jnp.inf),
-        jnp.full((q, k), -1, jnp.int32) if init_i is None
-        else jnp.full_like(init_i, -1),
-    )
+    init = (jnp.full((q, k), jnp.inf, jnp.float32),
+            jnp.full((q, k), -1, jnp.int32))
     (best_d, best_i), _ = jax.lax.scan(step, init, uniq)
     if ip_metric:
         best_d = -best_d          # inf (unfilled) -> -inf, ip exact

@@ -133,8 +133,8 @@ Per-stage latency **histograms** (log2 buckets, p50/p95/p99 estimates):
   ``device.memory_stats()`` truth (absent on backends without it;
   ``memory.live.supported`` says which)
 - ``memory.forecast.peak_bytes`` / ``memory.reserved.*`` — the
-  reservation forecast (resident + donated state + probe planes +
-  max compile-time temp); ``memory.hbm.headroom_bytes`` the live
+  reservation forecast (resident + probe planes + max compile-time
+  temp); ``memory.hbm.headroom_bytes`` the live
   headroom (−1 when unknowable); ``memory.divergence_bytes`` the
   modeled-vs-live gap (fragmentation / untracked allocations)
 - ``memory.watermark.{in_use,forecast}_peak_bytes`` — dispatch-time

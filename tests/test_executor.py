@@ -1,9 +1,9 @@
 """Serving-path tests: SearchExecutor bucketing, AOT executable cache,
 compile-count regression (the steady-state-never-compiles guarantee),
-donated top-k state, and bit-identity with the direct search paths."""
+stateless call signatures, and bit-identity with the direct search
+paths."""
 
-import warnings
-
+import jax
 import numpy as np
 import pytest
 
@@ -197,22 +197,52 @@ class TestCacheAndState:
         ex.search(indexes["brute_force"], q[:4], 5)
         assert ex.stats.compile_count == 3
 
-    def test_donated_state_keeps_results_valid(self, data, indexes):
-        """With donation forced on, results returned from call N must
-        survive call N+1 reusing the state storage."""
+    def test_results_survive_the_next_dispatch(self, data, indexes):
+        """Results returned from call N must survive call N+1 of the
+        same executable."""
         _, q = data
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore")  # cpu ignores donation
-            ex = SearchExecutor(donate=True)
-            d1, i1 = ex.search(indexes["brute_force"], q[:16], 5)
-            d1c, i1c = np.asarray(d1).copy(), np.asarray(i1).copy()
-            d2, i2 = ex.search(indexes["brute_force"], q[:9], 5)
-            np.testing.assert_array_equal(np.asarray(d1), d1c)
-            np.testing.assert_array_equal(np.asarray(i1), i1c)
-            d0, i0 = brute_force.search(None, indexes["brute_force"],
-                                        q[:9], 5)
-            np.testing.assert_array_equal(np.asarray(i0), np.asarray(i2))
-            np.testing.assert_array_equal(np.asarray(d0), np.asarray(d2))
+        ex = SearchExecutor()
+        d1, i1 = ex.search(indexes["brute_force"], q[:16], 5)
+        d1c, i1c = np.asarray(d1).copy(), np.asarray(i1).copy()
+        d2, i2 = ex.search(indexes["brute_force"], q[:9], 5)
+        np.testing.assert_array_equal(np.asarray(d1), d1c)
+        np.testing.assert_array_equal(np.asarray(i1), i1c)
+        d0, i0 = brute_force.search(None, indexes["brute_force"],
+                                    q[:9], 5)
+        np.testing.assert_array_equal(np.asarray(i0), np.asarray(i2))
+        np.testing.assert_array_equal(np.asarray(d0), np.asarray(d2))
+
+    @pytest.mark.parametrize("plan", ["pallas", "xla", "rank", "bf_scan"])
+    def test_call_carries_no_running_state(self, data, indexes, plan):
+        """Each plan's executable takes the queries and the index arrays
+        only: no (bucket, k) operand is among its inputs, since the
+        serving function allocates its own top-k carry. The results
+        equal the family's direct search."""
+        _, q = data
+        k, rows, bucket = 7, 11, 16
+        ex = SearchExecutor()
+        if plan == "bf_scan":
+            index, params = indexes["brute_force"], None
+            d0, i0 = brute_force.search(None, index, q[:rows], k)
+        else:
+            index = indexes["ivf_flat"]
+            params = ivf_flat.IvfFlatSearchParams(n_probes=4,
+                                                  scan_engine=plan)
+            d0, i0 = ivf_flat.search(None, params, index, q[:rows], k)
+        d, i = ex.search(index, q[:rows], k, params=params)
+        (info,) = ex.executable_costs().values()
+        assert info["bucket"] == bucket
+        if plan == "bf_scan":
+            assert info["family"] == "bf_scan"
+        else:
+            assert info["family"] == "ivf_flat" and info["engine"] == plan
+        (entry,) = ex._cache.values()
+        shapes = [tuple(a.shape) for a in
+                  jax.tree_util.tree_leaves(entry.compiled.in_avals)]
+        assert (bucket, 16) in shapes           # the padded queries
+        assert (bucket, k) not in shapes
+        np.testing.assert_array_equal(np.asarray(i), np.asarray(i0))
+        np.testing.assert_array_equal(np.asarray(d), np.asarray(d0))
 
     def test_empty_batch(self, indexes):
         ex = SearchExecutor()

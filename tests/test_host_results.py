@@ -1,9 +1,9 @@
 """The serving entry's results on the host: ``search_blocks`` fetches a
 call's outputs in one batch and cuts each block's rows in numpy. Every
 block's result is a numpy array bit-identical to the library entry
-``search()`` of that block alone — for a stateless plan and one whose
-outputs alias donated state, short of the bucket and filling it, tiled
-past the top bucket, and on a mesh through the batcher."""
+``search()`` of that block alone — for each IVF-Flat scan engine, short
+of the bucket and filling it, tiled past the top bucket, and on a mesh
+through the batcher."""
 
 import jax
 import numpy as np
@@ -45,19 +45,18 @@ def _assert_solo(ex, index, blocks, got, params):
         np.testing.assert_array_equal(d, np.asarray(want_d))
 
 
-# (scan engine, donate): the Pallas scan keeps no state; the XLA scan's
-# outputs alias its donated top-k state when donation is on, and are
-# then fetched before the executor lock releases
-PLANS = [("pallas", False), ("xla", True), ("xla", False)]
+# the scan engines of an IVF-Flat plan: the grouped Pallas list scan,
+# the XLA list scan, and the rank-major scan
+PLANS = ("pallas", "xla", "rank")
 
 
-@pytest.mark.parametrize("engine,donate", PLANS)
+@pytest.mark.parametrize("engine", PLANS)
 class TestBucketed:
-    def test_short_and_full_bucket(self, index, data, engine, donate):
+    def test_short_and_full_bucket(self, index, data, engine):
         _, q = data
         params = ivf_flat.IvfFlatSearchParams(n_probes=4,
                                               scan_engine=engine)
-        ex = SearchExecutor(donate=donate, max_bucket=16)
+        ex = SearchExecutor(max_bucket=16)
         for sizes in ([2, 3], [3, 1, 4], [16]):     # 5 of 8, 8, 16
             edges = np.cumsum([0] + sizes)
             blocks = [q[a:b] for a, b in zip(edges[:-1], edges[1:])]
@@ -67,14 +66,13 @@ class TestBucketed:
             _assert_solo(ex, index, blocks, got, params)
 
     def test_back_to_back_dispatches_of_one_plan(self, index, data,
-                                                 engine, donate):
-        """The second dispatch re-donates the first's output buffers
-        (one executable, one state): the first results, already on the
-        host, stay as they were."""
+                                                 engine):
+        """A second dispatch of the same executable leaves the first's
+        results, already on the host, as they were."""
         _, q = data
         params = ivf_flat.IvfFlatSearchParams(n_probes=4,
                                               scan_engine=engine)
-        ex = SearchExecutor(donate=donate, max_bucket=16)
+        ex = SearchExecutor(max_bucket=16)
         first_blocks = [q[:3], q[3:6]]
         first = ex.search_blocks(index, first_blocks, K, params=params)
         kept = [(d.copy(), i.copy()) for d, i in first]
@@ -87,13 +85,13 @@ class TestBucketed:
         _assert_solo(ex, index, second_blocks, second, params)
 
     def test_oversized_batch_tiles_at_the_top_bucket(self, index, data,
-                                                     engine, donate):
+                                                     engine):
         """40 rows over a top bucket of 16: three tiles of one
         executable, joined and cut on the host."""
         _, q = data
         params = ivf_flat.IvfFlatSearchParams(n_probes=4,
                                               scan_engine=engine)
-        ex = SearchExecutor(donate=donate, max_bucket=16)
+        ex = SearchExecutor(max_bucket=16)
         blocks = [q[:7], q[7:20], q[20:21], q[21:40]]
         metrics.reset()
         got = ex.search_blocks(index, blocks, K, params=params)

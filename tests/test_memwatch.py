@@ -270,10 +270,10 @@ class TestForecast:
         ex.search(flat_index, q, 5,
                   ivf_flat.IvfFlatSearchParams(scan_engine="xla"))
         res = ex.memory_reservations()
-        # ONE xla-engine entry at bucket 16, k 5: the donated (16, 5)
-        # f32 + i32 state pair
-        assert sum(res["donated_state_bytes"].values()) == \
-            16 * 5 * (4 + 4)
+        # ONE xla-engine entry at bucket 16, k 5; no probe plane
+        assert set(res) == {"probe_plane_bytes", "max_temp_bytes",
+                            "executables"}
+        assert res["probe_plane_bytes"] == {}
         assert res["executables"] == 1
         costs = ex.executable_costs()
         max_temp = max(c.get("temp_bytes", 0.0) for c in costs.values())
@@ -281,12 +281,11 @@ class TestForecast:
         fc = ledger.forecast()
         model = memwatch.index_memory_model(flat_index)
         assert fc["resident_bytes"] == model["resident_bytes"]
-        assert fc["donated_state_bytes"] == 16 * 5 * 8
+        assert fc["probe_plane_bytes"] == 0.0
         assert fc["max_temp_bytes"] == max_temp
         # single chip: everything lands on device 0, and the peak is
-        # exactly the sum of the three terms
-        assert fc["peak_bytes"] == (model["resident_bytes"]
-                                    + 16 * 5 * 8 + max_temp)
+        # exactly the sum of the resident bytes and the max temp
+        assert fc["peak_bytes"] == model["resident_bytes"] + max_temp
 
     def test_probe_plane_term(self, data, flat_index):
         _, q = data
